@@ -1,0 +1,471 @@
+#!/usr/bin/env python3
+"""Chip smoke: serve TPC-H over Spark Connect from the accelerator.
+
+One process is both the server (``SparkConnectServer`` in threads) and
+the client (``SparkConnectClient`` over gRPC on localhost); no child
+process touches JAX. It generates TPC-H at ``--sf`` from ``--seed``,
+writes it as Parquet, registers the files through the reader the
+README shows (``spark.read.parquet(...).createOrReplaceTempView``, in
+its wire form), runs a handful of queries twice each over the wire and
+compares every answer with the pandas oracle in ``tests/tpch_oracle``.
+
+    python chip_smoke.py             # one chip: q1 q6 q3 q5 q18 q13
+    python chip_smoke.py --chips 4   # four chips: q1 q3 q18 through
+                                     # MeshExecutor (mesh=force), only
+
+Every step prints one JSON line; the last line is the verdict the
+driver reads. Any failure is a traceback and a non-zero exit: there is
+no ``ok`` line unless every step passed. The per-query seconds are
+print-outs for the builder, not metrics.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import shutil
+import sys
+import tempfile
+import time
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.join(ROOT, "tests"))   # tpch_oracle
+sys.path.insert(0, ROOT)
+
+#: scan-aggregate (q1 takes the chip-only masked branch), joins with a
+#: high-cardinality group-by and sort, and a LIKE through the host
+#: dictionaries
+ONE_CHIP_QUERIES = (1, 6, 3, 5, 18, 13)
+#: not q5: its reordered plan joins suppliers to customers on nationkey
+#: alone (x6000 rows at SF1), more than MeshExecutor's static expansion
+#: multipliers reach — it leaves the mesh with "capacity overflow"
+MESH_QUERIES = (1, 3, 18)
+#: q18 groups lineitem by l_orderkey: 375,000 groups a shard at SF1,
+#: where the executor's default group capacity tops out at 4096 x 16
+MESH_CONF = {18: {"spark.sail.mesh.maxGroups": "524288"}}
+#: ties in the sort keys: compared as row sets (tests/test_tpch.py)
+UNORDERED = frozenset({13, 18})
+#: the tolerance tests/test_tpch.py holds decimal/double columns to
+RTOL, ATOL = 1e-6, 1e-4
+
+
+def emit(step: str, **fields) -> None:
+    print(json.dumps({"step": step, **fields}, default=str), flush=True)
+
+
+# ---------------------------------------------------------------------------
+# device
+# ---------------------------------------------------------------------------
+
+class JaxCacheCounter:
+    """Counts XLA compile requests and persistent-cache hits through
+    jax.monitoring, so a second run in one call shows the cache
+    working."""
+
+    def __init__(self):
+        import jax.monitoring
+        self.requests = 0
+        self.hits = 0
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            self.requests += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+
+    def snapshot(self) -> dict:
+        return {"xla_compile_requests": self.requests,
+                "xla_persistent_cache_hits": self.hits}
+
+
+def device_step(chips: int, require_platform: str = "tpu") -> dict:
+    """The device JAX gives this process; anything but
+    ``require_platform`` x ``chips`` ends the run before data is made."""
+    import jax
+    import jaxlib
+    devices = jax.devices()
+    platform = devices[0].platform
+    if platform != require_platform:
+        raise SystemExit(
+            f"chip_smoke: needs a {require_platform} device, JAX gave "
+            f"{platform!r} ({devices[0].device_kind} x{len(devices)})")
+    if len(devices) != chips:
+        raise SystemExit(
+            f"chip_smoke: needs {chips} device(s), JAX gave {len(devices)}")
+    try:
+        import libtpu
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = None
+    import sail_tpu  # noqa: F401 — x64 on, as every entry point has it
+    from sail_tpu.exec import pcache
+    device = {"platform": platform, "kind": devices[0].device_kind,
+              "count": len(devices)}
+    emit("device", **device, jax=jax.__version__,
+         jaxlib=jaxlib.__version__, libtpu=libtpu_version,
+         x64=bool(jax.config.jax_enable_x64),
+         jax_cache_dir=pcache.place_jax_cache())
+    return device
+
+
+# ---------------------------------------------------------------------------
+# data
+# ---------------------------------------------------------------------------
+
+def _oracle_frame(table):
+    """Arrow → pandas the way tests/test_tpch.py feeds the oracle:
+    decimals as float64, dates as datetime64."""
+    import pyarrow as pa
+    cols = []
+    for col in table.columns:
+        if pa.types.is_decimal(col.type):
+            col = col.cast(pa.float64())
+        elif pa.types.is_date(col.type):
+            col = col.cast(pa.timestamp("us"))
+        cols.append(col)
+    return pa.table(cols, names=table.column_names).to_pandas()
+
+
+#: what q1 and q6 read; all the oracle needs of a lineitem-only run
+SCAN_AGGREGATE_COLUMNS = ["l_returnflag", "l_linestatus", "l_quantity",
+                          "l_extendedprice", "l_discount", "l_tax",
+                          "l_shipdate"]
+
+
+def data_step(sf: float, seed: int, out_dir: str):
+    """Generate TPC-H, write one Parquet file per table under
+    ``out_dir``; returns ({table: path}, {table: pandas frame}). Above
+    SF1 only lineitem is made, by the bench's vectorized generator (the
+    eight-table one is too slow there), for q1 and q6."""
+    import pyarrow.parquet as pq
+    t0 = time.perf_counter()
+    if sf > 1:
+        import bench
+        generated = {"lineitem": bench.generate_lineitem_sf(sf, seed=seed)}
+    else:
+        from sail_tpu.benchmarks.tpch_data import generate_tpch
+        generated = generate_tpch(sf=sf, seed=seed)
+    gen_s = time.perf_counter() - t0
+    paths, frames = {}, {}
+    for name, table in generated.items():
+        paths[name] = os.path.join(out_dir, name)
+        os.makedirs(paths[name], exist_ok=True)
+        pq.write_table(table, os.path.join(paths[name], "part-0.parquet"))
+        frames[name] = _oracle_frame(
+            table.select(SCAN_AGGREGATE_COLUMNS) if sf > 1 else table)
+    emit("data", sf=sf, seed=seed,
+         rows={name: t.num_rows for name, t in generated.items()},
+         arrow_bytes=sum(t.nbytes for t in generated.values()),
+         parquet_bytes=sum(
+             os.path.getsize(os.path.join(p, "part-0.parquet"))
+             for p in paths.values()),
+         generate_s=gen_s, total_s=time.perf_counter() - t0)
+    return paths, frames
+
+
+# ---------------------------------------------------------------------------
+# server + clients
+# ---------------------------------------------------------------------------
+
+def serve():
+    from sail_tpu.spark_connect.service import SparkConnectServer
+    return SparkConnectServer("127.0.0.1", 0).start()
+
+
+def connect(server, paths, conf=None):
+    """A new client with a session of its own, the Parquet tables
+    registered as ``spark.read.parquet(path).createOrReplaceTempView(
+    name)`` sends it: a CreateDataFrameViewCommand over a Read.
+    Returns (client, server-side session)."""
+    from sail_tpu.spark_connect.client import SparkConnectClient
+    from spark.connect import base_pb2 as bpb
+    client = SparkConnectClient(f"127.0.0.1:{server.port}")
+    if conf:
+        client.config_set(conf)
+    for name, path in paths.items():
+        plan = bpb.Plan()
+        view = plan.command.create_dataframe_view
+        view.name = name
+        view.replace = True
+        view.input.read.data_source.format = "parquet"
+        view.input.read.data_source.paths.append(path)
+        list(client.execute_plan(plan))
+    return client, server.sessions.get_or_create(client.session_id)
+
+
+# ---------------------------------------------------------------------------
+# queries + answers
+# ---------------------------------------------------------------------------
+
+def _timed_sql(client, q: int):
+    from sail_tpu.benchmarks.tpch_queries import QUERIES
+    t0 = time.perf_counter()
+    table = client.sql(QUERIES[q])
+    return table, time.perf_counter() - t0
+
+
+def _profile_counts(session, seen: set) -> dict:
+    """What the profiler kept for this session's queries since the last
+    look: compiles, cache hits, routing, host-side fallbacks."""
+    from sail_tpu import profiler
+    profiles = [p for p in profiler.FLIGHT_RECORDER.profiles()
+                if p.session == session._session_id
+                and p.query_id not in seen]
+    seen.update(p.query_id for p in profiles)
+    return {
+        "compiled_programs": sum(p.compiled_programs for p in profiles),
+        "compile_s": sum(p.compile_ms for p in profiles) / 1000.0,
+        "op_cache_hits": sum(p.compile_cache_hits for p in profiles),
+        "op_cache_misses": sum(p.compile_cache_misses for p in profiles),
+        "aot_store_hits": sum(p.persistent_hits for p in profiles),
+        "fusion_fallbacks": sum(p.fusion_fallbacks for p in profiles),
+        "result_cache": [p.cache_status for p in profiles
+                         if p.cache_status],
+        "routes": sorted({r["backend"] for p in profiles
+                          for r in p.backend_routes}),
+    }
+
+
+def run_queries(server, paths, queries, frames, conf=None,
+                concurrent=True, after_first=None) -> list:
+    """Each query from a client session of its own (``conf(q)`` set on
+    it), twice, each answer held to the oracle; one printed record per
+    query.
+
+    The FIRST calls go out together when ``concurrent``: on the chip a
+    first call is mostly XLA compilation (tens of seconds for every
+    program with a large sort in it, one thread each), and the
+    compiles of different requests overlap while the device serialises
+    what runs. Their ``first_s`` is then wall time under that load.
+    The second calls go one by one."""
+    from concurrent.futures import ThreadPoolExecutor
+    conns = {q: connect(server, paths, conf(q) if conf else None)
+             for q in queries}
+    seen: set = set()
+    if concurrent:
+        with ThreadPoolExecutor(len(queries)) as pool:
+            futures = {q: pool.submit(_timed_sql, conns[q][0], q)
+                       for q in queries}
+            firsts = {q: f.result() for q, f in futures.items()}
+    records = []
+    for q in queries:
+        client, session = conns[q]
+        first, first_s = firsts[q] if concurrent else _timed_sql(client, q)
+        record = {"q": q, "rows": first.num_rows, "first_s": first_s,
+                  "first_calls_concurrent": concurrent,
+                  "first": _profile_counts(session, seen)}
+        if after_first is not None:
+            record.update(after_first(q, session))
+        second, record["second_s"] = _timed_sql(client, q)
+        record["second"] = _profile_counts(session, seen)
+        # a result-cache hit is not device work; say so
+        record["second_from_result_cache"] = \
+            "hit" in record["second"]["result_cache"]
+        expected = oracle_answer(q, frames)
+        record["worst_rel_err"] = max(answer_step(q, first, expected),
+                                      answer_step(q, second, expected))
+        record["equals_oracle"] = True
+        emit("query", **record)
+        records.append(record)
+        client.close()
+    return records
+
+
+def _normalize(df):
+    import numpy as np
+    import pandas as pd
+    out = df.copy()
+    out.columns = [f"c{i}" for i in range(len(out.columns))]
+    for c in out.columns:
+        kind = out[c].dtype.kind
+        if kind == "M":
+            out[c] = pd.to_datetime(out[c]).astype("datetime64[us]")
+        elif kind in "iu":
+            out[c] = out[c].astype(np.int64)
+        elif kind == "f":
+            out[c] = out[c].astype(np.float64)
+    return out.reset_index(drop=True)
+
+
+def oracle_answer(q: int, frames):
+    from tpch_oracle import ORACLES
+    return _normalize(ORACLES[q](frames))
+
+
+def answer_step(q: int, got_table, exp) -> float:
+    """Compare one result with the pandas oracle's (``oracle_answer``):
+    integer, date and string columns exactly, decimal and double
+    columns to (RTOL, ATOL). Returns the worst relative error seen over
+    the float columns."""
+    import numpy as np
+    got = _normalize(_oracle_frame(got_table))
+    if len(got) != len(exp):
+        raise AssertionError(f"q{q}: {len(got)} rows, oracle {len(exp)}")
+    if list(got.dtypes.map(lambda d: d.kind)) != \
+            list(exp.dtypes.map(lambda d: d.kind)):
+        raise AssertionError(
+            f"q{q}: column kinds {list(got.dtypes)} vs {list(exp.dtypes)}")
+    if q in UNORDERED:
+        # ties in the sort keys: order both by a rounded copy so float
+        # noise cannot reorder rows, then compare positionally
+        def canon(df):
+            key = df.copy()
+            for c in key.columns:
+                if key[c].dtype.kind == "f":
+                    key[c] = key[c].round(2)
+            order = key.sort_values(list(key.columns)).index
+            return df.loc[order].reset_index(drop=True)
+        got, exp = canon(got), canon(exp)
+    worst = 0.0
+    for c in got.columns:
+        g, e = got[c], exp[c]
+        if g.dtype.kind == "f":
+            gv, ev = g.to_numpy(), e.to_numpy()
+            ok = np.isclose(gv, ev, rtol=RTOL, atol=ATOL, equal_nan=True)
+            if not ok.all():
+                bad = np.flatnonzero(~ok)[:5]
+                raise AssertionError(
+                    f"q{q} col {c}: got {gv[bad]} oracle {ev[bad]}")
+            big = np.abs(ev) > ATOL     # below it the absolute bound rules
+            if big.any():
+                rel = np.abs(gv[big] - ev[big]) / np.abs(ev[big])
+                worst = max(worst, float(rel.max()))
+        else:
+            same = (g == e) | (g.isna() & e.isna())
+            if not same.all():
+                raise AssertionError(
+                    f"q{q} col {c}:\n{g[~same].head()}\nvs\n"
+                    f"{e[~same].head()}")
+    return worst
+
+
+# ---------------------------------------------------------------------------
+# the chip did it
+# ---------------------------------------------------------------------------
+
+def lineitem_fragment_devices(platform: str) -> dict:
+    """Platforms of the device arrays the lineitem scans uploaded (the
+    fragment cache pins them); all must be ``platform``."""
+    from sail_tpu.exec import result_cache as rc
+    with rc.FRAGMENT_CACHE._lock:
+        entries = [e for e in rc.FRAGMENT_CACHE._entries.values()
+                   if e.table_key and "lineitem" in e.table_key]
+    if not entries:
+        raise AssertionError("no lineitem scan fragment is resident")
+    platforms, nbytes = set(), 0
+    for e in entries:
+        dev = e.batch.device
+        for arr in [dev.sel] + [c.data for c in dev.columns.values()]:
+            platforms.update(d.platform for d in arr.devices())
+            nbytes += arr.nbytes
+    if platforms != {platform}:
+        raise AssertionError(
+            f"lineitem arrays live on {platforms}, not {platform}")
+    return {"lineitem_fragments": len(entries),
+            "lineitem_device_bytes": nbytes,
+            "lineitem_platforms": sorted(platforms)}
+
+
+def chip_did_it_step(records, platform: str) -> None:
+    import jax
+    from sail_tpu.native import native_active
+    if native_active():
+        raise AssertionError("the native C++ host path is active")
+    if jax.default_backend() != platform:
+        raise AssertionError(
+            f"default backend is {jax.default_backend()}, not {platform}")
+    routes = sorted({b for r in records for b in r["first"]["routes"]}
+                    | {b for r in records for b in r["second"]["routes"]})
+    if routes != ["xla"]:
+        raise AssertionError(f"backend_route decisions were {routes}")
+    stats = jax.devices()[0].memory_stats() or {}
+    emit("chip_did_it", native_active=False,
+         default_backend=jax.default_backend(), routes=routes,
+         **lineitem_fragment_devices(platform),
+         fusion_fallbacks=sum(r["first"]["fusion_fallbacks"]
+                              for r in records),
+         peak_bytes_in_use=stats.get("peak_bytes_in_use"),
+         bytes_in_use=stats.get("bytes_in_use"),
+         bytes_limit=stats.get("bytes_limit"))
+
+
+# ---------------------------------------------------------------------------
+# four chips: MeshExecutor, forced
+# ---------------------------------------------------------------------------
+
+def mesh_run(server, paths, frames, n_devices: int) -> list:
+    """q1, q3, q18 through MeshExecutor with execution.mesh=force,
+    one after another: one process drives all the chips, and two SPMD
+    programs launched at once could interleave their collectives."""
+
+    def after_first(q, session):
+        ex = session._last_mesh_executor
+        if ex is None:
+            raise AssertionError(f"q{q} did not run through MeshExecutor")
+        # code that has only seen one real device may have put the
+        # whole leaf on the first
+        per_dev = ex.last_leaf_shard_bytes
+        if len(per_dev) != n_devices or min(per_dev.values()) == 0:
+            raise AssertionError(
+                f"q{q}: leaf is not spread over {n_devices} devices: "
+                f"{per_dev}")
+        return {"mesh_exchanges": ex.last_exchanges,
+                "mesh_retries": ex.last_retries,
+                "leaf_bytes_per_device": per_dev}
+
+    return run_queries(
+        server, paths, MESH_QUERIES, frames,
+        conf=lambda q: {"spark.sail.execution.mesh": "force",
+                        **MESH_CONF.get(q, {})},
+        concurrent=False, after_first=after_first)
+
+
+# ---------------------------------------------------------------------------
+# main
+# ---------------------------------------------------------------------------
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--sf", type=float, default=1.0,
+                    help="TPC-H scale factor (default 1; above 1 only "
+                         "lineitem is generated and q1, q6 run)")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
+                    help="4 = only the MeshExecutor path, mesh=force")
+    ap.add_argument("--out", default=None,
+                    help="directory for the generated Parquet "
+                         "(default: a fresh temp dir, removed at exit)")
+    args = ap.parse_args(argv)
+    if args.sf < 1:
+        ap.error("--sf may raise the scale, nothing lowers it below 1")
+
+    t_start = time.perf_counter()
+    device = device_step(args.chips)
+    jax_cache = JaxCacheCounter()
+    out_dir = args.out or tempfile.mkdtemp(prefix="chip_smoke_")
+    server = None
+    try:
+        paths, frames = data_step(args.sf, args.seed, out_dir)
+        server = serve()
+        if args.chips > 1:
+            mesh_run(server, paths, frames, args.chips)
+        else:
+            records = run_queries(
+                server, paths,
+                ONE_CHIP_QUERIES if args.sf <= 1 else (1, 6), frames)
+            chip_did_it_step(records, device["platform"])
+        emit("compile_cache", **jax_cache.snapshot())
+    finally:
+        if server is not None:
+            server.stop(grace=1.0)
+        if args.out is None:
+            shutil.rmtree(out_dir, ignore_errors=True)
+    emit("done", seconds=time.perf_counter() - t_start)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

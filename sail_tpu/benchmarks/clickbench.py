@@ -52,7 +52,7 @@ def generate_hits(n_rows: int = 100_000, seed: int = 0):
     search_phrase = phrases[rng.integers(0, len(phrases), n)]
 
     # near-unique URLs: the high-cardinality string cliff the engine must
-    # survive (VERDICT round-4 weak point #7)
+    # survive
     host_ids = rng.integers(0, 500, n)
     page_ids = rng.integers(0, max(n // 2, 10), n)
     url = np.char.add(

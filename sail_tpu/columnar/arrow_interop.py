@@ -260,9 +260,9 @@ def to_arrow(batch: HostBatch) -> pa.Table:
     """Download a HostBatch to a pyarrow Table (live rows only, in order).
 
     All device arrays (sel + every column's data/validity) are fetched in
-    ONE ``jax.device_get`` call: on a remote accelerator each blocking
-    fetch pays a full round trip, so per-column ``np.asarray`` loops are
-    O(columns) round trips while a batched get overlaps the transfers."""
+    ONE ``jax.device_get`` call: each blocking fetch is a device sync,
+    so per-column ``np.asarray`` loops are O(columns) syncs while a
+    batched get overlaps the transfers."""
     import jax
 
     dev = batch.device

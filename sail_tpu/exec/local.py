@@ -1949,8 +1949,7 @@ class LocalExecutor:
                                      make_builder(max_groups),
                                      fused=bool(chain))
         gk, aggs_out, gsel, n_groups, overflow = fn(self._cols(child), dev.sel)
-        # one batched fetch: each blocking scalar read is a full round trip
-        # on a remote accelerator
+        # one batched fetch: each blocking scalar read is a device sync
         n_groups, overflow = jax.device_get((n_groups, overflow))
         if p.max_groups_hint and bool(overflow):
             key2 = self._op_key("agg2", stage_key, dev.capacity)

@@ -110,40 +110,43 @@ def _load_conf() -> Tuple[bool, str, int]:
     return enabled and bool(d), d, max(1, max_mb)
 
 
-_XLA_DIR: Optional[str] = None
+#: where jax's own persistent compilation cache goes when the
+#: environment does not place it: one fixed directory in the checkout
+#: (the path is part of what a later process must find again, so it
+#: never comes from tempfile, a pid or the time)
+JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+_JAX_CACHE_PLACED = False
 
 
-def _sync_xla_cache(conf: Tuple[bool, str, int]) -> None:
-    """Point jax's own persistent compilation cache at ``<dir>/xla``
-    (or detach it when the store is off): it covers every XLA program
-    OUTSIDE the AOT store — the many small eager-op dispatches and
-    stray jits a cold process otherwise compiles one by one.
-    Thresholds drop to zero because exactly those small programs are
-    the cold-start long tail. Best-effort: an older jax without these
-    knobs just skips them."""
-    global _XLA_DIR
-    target = os.path.join(conf[1], "xla") if conf[0] else None
-    if target == _XLA_DIR:
-        return
+def place_jax_cache() -> str:
+    """The one rule for jax's own persistent compilation cache — it
+    covers every XLA program OUTSIDE the AOT store, the many small
+    eager-op dispatches and stray jits a cold process otherwise
+    compiles one by one. ``JAX_COMPILATION_CACHE_DIR`` set: jax already
+    reads it, and this program never touches the setting. Unset: the
+    cache goes to :data:`JAX_CACHE_DIR`, whatever ``compile_cache.dir``
+    says. Either way the two thresholds drop to zero, because exactly
+    those small programs are the cold-start long tail. Returns the
+    directory in use."""
+    global _JAX_CACHE_PLACED
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if _JAX_CACHE_PLACED:
+        return env_dir or JAX_CACHE_DIR
+    _JAX_CACHE_PLACED = True
     import jax
-    updates = [("jax_compilation_cache_dir", target)]
-    if target is not None:
-        updates += [("jax_persistent_cache_min_compile_time_secs", 0.0),
-                    ("jax_persistent_cache_min_entry_size_bytes", 0)]
-    for opt, value in updates:
-        try:
-            jax.config.update(opt, value)
-        except Exception:  # noqa: BLE001 — knob unavailable: skip
-            pass
-    try:
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    if not env_dir:
+        from jax.experimental.compilation_cache import \
+            compilation_cache as cc
+        jax.config.update("jax_compilation_cache_dir", JAX_CACHE_DIR)
         # jax latches the cache decision at the FIRST compile; module
-        # imports usually compile something before the config layer is
-        # consulted, so the latch must be reset for the dir to take
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — internal API moved: best effort
-        pass
-    _XLA_DIR = target
+        # imports usually compile something before this runs, so the
+        # latch must be reset for the dir to take
+        cc.reset_cache()
+    return env_dir or JAX_CACHE_DIR
 
 
 def _conf() -> Tuple[bool, str, int]:
@@ -154,7 +157,7 @@ def _conf() -> Tuple[bool, str, int]:
             c = _CONF
             if c is None:
                 c = _CONF = _load_conf()
-        _sync_xla_cache(c)
+        place_jax_cache()
     return c
 
 
@@ -173,9 +176,8 @@ def max_bytes() -> int:
 
 
 def reload() -> None:
-    """Re-read ``compile_cache.*`` and re-sync jax's compilation-cache
-    binding eagerly (tests, bench A/B knobs, cluster entry points
-    after env changes)."""
+    """Re-read ``compile_cache.*`` eagerly (tests, bench A/B knobs,
+    cluster entry points after env changes)."""
     global _CONF, _APPROX_BYTES, _PREWARM_STARTED, _TALLY_LAST_FLUSH
     with _LOCK:
         _CONF = None
@@ -333,6 +335,18 @@ def _poison(digest: str) -> None:
         pass
 
 
+def _devices_by_id(ids) -> Optional[List]:
+    """The devices an entry was compiled for (``store`` records their
+    ids), or None when this process cannot match them all: jax would
+    otherwise bind the program to EVERY device of the backend, and a
+    single-device program then dies on its first call."""
+    import jax
+    by_id = {d.id: d for d in jax.devices()}
+    if not ids or any(i not in by_id for i in ids):
+        return None
+    return [by_id[i] for i in ids]
+
+
 def load(digest: str, site: str = "op"):
     """Fetch + deserialize one entry; returns a callable executing the
     stored program, or None (miss / any failure, counted). Corrupt
@@ -380,10 +394,15 @@ def _load(digest: str, site: str = "op", _tally: bool = True):
                 header.get("env") != list(env_fingerprint()):
             reason = "skew"
             raise ValueError("entry/key skew")
+        devices = _devices_by_id(header.get("devices"))
+        if devices is None:
+            reason = "skew"
+            raise ValueError("entry compiled for devices not present")
         from jax.experimental import serialize_executable as se
         payload, in_tree, out_tree = pickle.loads(blob[nl + 1:])
         intact = True     # bytes parsed; only the runtime load remains
-        loaded = se.deserialize_and_load(payload, in_tree, out_tree)
+        loaded = se.deserialize_and_load(payload, in_tree, out_tree,
+                                         execution_devices=devices)
     except Exception:  # noqa: BLE001 — corrupt/truncated/skewed: JIT instead
         _count("execution.compile.persistent_load_error_count")
         _count("execution.compile.persistent_miss_count")
@@ -440,10 +459,13 @@ def store(digest: str, compiled, compile_s: float,
         from jax.experimental import serialize_executable as se
         triple = se.serialize(compiled)
         payload = pickle.dumps(triple)
+        device_ids = [d.id for d in
+                      compiled.runtime_executable().local_devices()]
     except Exception:  # noqa: BLE001 — unserializable program: skip
         return False
     header = {"v": FORMAT_VERSION, "digest": digest,
               "env": list(env_fingerprint()),
+              "devices": device_ids,
               "compile_s": round(float(compile_s), 6),
               "site": site, "created": time.time()}
     path = _entry_path(digest)
@@ -486,9 +508,7 @@ def _note_written(nbytes: int) -> None:
 
 def _scan_entries() -> List[Tuple[str, int, float, float, dict]]:
     """[(path, size, mtime, compile_s, header)] for every complete
-    entry currently in the store — the AOT ``.sailpc`` entries plus
-    jax's own compilation-cache files under ``xla/`` (those carry no
-    compile-time header; they evict first, cheapest assumed)."""
+    ``.sailpc`` entry currently in the store."""
     out = []
     try:
         names = os.listdir(cache_dir())
@@ -518,20 +538,6 @@ def _scan_entries() -> List[Tuple[str, int, float, float, dict]]:
         header = _read_header(path) or {}
         out.append((path, st.st_size, st.st_mtime,
                     float(header.get("compile_s", 0.0)), header))
-    xla_dir = os.path.join(cache_dir(), "xla")
-    try:
-        xla_names = os.listdir(xla_dir)
-    except OSError:
-        xla_names = []
-    for name in xla_names:
-        path = os.path.join(xla_dir, name)
-        try:
-            st = os.stat(path)
-            if not os.path.isfile(path):
-                continue
-        except OSError:
-            continue
-        out.append((path, st.st_size, st.st_mtime, 0.0, {}))
     return out
 
 

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from ..columnar.batch import Column, DeviceBatch
 from ..spec import data_type as dt
 from .hash import can_pack, pack_keys
-from .sort import order_bits
+from .sort import sort_pass
 
 
 def _group_sort_perm(key_cols: Sequence[Column], sel) -> jnp.ndarray:
@@ -50,8 +50,7 @@ def _group_sort_perm(key_cols: Sequence[Column], sel) -> jnp.ndarray:
         return jnp.argsort(packed, stable=True).astype(jnp.int32)
     perm = jnp.arange(n, dtype=jnp.int32)
     for c in reversed(list(key_cols)):
-        bits = order_bits(c.data, c.dtype)
-        perm = perm[jnp.argsort(bits[perm], stable=True)]
+        perm = sort_pass(perm, c.data, c.dtype)
         if c.validity is not None:
             perm = perm[jnp.argsort(c.validity[perm].astype(jnp.uint8), stable=True)]
     dead = (~sel).astype(jnp.uint8)
@@ -198,11 +197,8 @@ _MASKED_BACKENDS = ("tpu",)
 
 
 def _masked_max_segments() -> int:
-    try:
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover — backend init failure
-        backend = "cpu"
-    return _MASKED_SEGMENTS_MAX if backend in _MASKED_BACKENDS else 0
+    return _MASKED_SEGMENTS_MAX \
+        if jax.default_backend() in _MASKED_BACKENDS else 0
 
 
 def _seg_reduce(vals, seg_ids, num_segments: int, kind: str, identity):

@@ -52,6 +52,25 @@ def order_bits(data, d: dt.DataType, ascending: bool = True) -> jnp.ndarray:
     return bits if ascending else ~bits
 
 
+def sort_pass(perm, data, d: dt.DataType, ascending: bool = True):
+    """One stable pass of a lexicographic sort: ``perm`` reordered by
+    ``data``. Doubles sort by VALUE, not by order bits: the TPU compiler
+    has no float64→uint64 bitcast (UNIMPLEMENTED in its 64-bit
+    rewriting), and jax's sort comparator is already the total order
+    wanted — -0.0 equals 0.0, NaNs together at the end. Spark's NaN is
+    the LARGEST value, so a descending pass moves the NaNs to the
+    front with a second stable pass."""
+    if d.physical_dtype != "float64":
+        bits = order_bits(data, d, ascending)
+        return perm[jnp.argsort(bits[perm], stable=True)]
+    vals = data[perm]
+    if ascending:
+        return perm[jnp.argsort(vals, stable=True)]
+    perm = perm[jnp.argsort(-vals, stable=True)]
+    not_nan = (~jnp.isnan(data[perm])).astype(jnp.uint8)
+    return perm[jnp.argsort(not_nan, stable=True)]
+
+
 def lexsort_perm(keys, sel=None) -> jnp.ndarray:
     """Stable lexicographic sort permutation.
 
@@ -63,8 +82,7 @@ def lexsort_perm(keys, sel=None) -> jnp.ndarray:
     n = keys[0][0].shape[0] if keys else sel.shape[0]
     perm = jnp.arange(n, dtype=jnp.int32)
     for data, validity, d, asc, nf in reversed(list(keys)):
-        bits = order_bits(data, d, asc)
-        perm = perm[jnp.argsort(bits[perm], stable=True)]
+        perm = sort_pass(perm, data, d, asc)
         if validity is not None:
             nulls_first = asc if nf is None else nf
             null_rank = (validity if nulls_first else ~validity).astype(jnp.uint8)

@@ -55,6 +55,24 @@ class MeshUnsupported(Exception):
     """Plan shape the SPMD compiler cannot express; caller falls back."""
 
 
+#: rows of one shard are indexed with int32
+_MAX_SHARD_ROWS = (1 << 31) - 1
+
+
+def _scaled_capacity(rows: int, copies: int = 1) -> int:
+    """``bucket_capacity`` for a capacity a retry multiplier scaled up.
+    Multipliers compound along a chain of expanding joins (q5 at SF1
+    reaches 2^31 rows on the third attempt); a capacity int32 cannot
+    index is the executor's declared "capacity overflow", not an
+    OverflowError from deep inside a trace. ``copies`` is how many such
+    buffers one array holds (the P send buckets of an exchange)."""
+    cap = bucket_capacity(rows)
+    if cap * copies > _MAX_SHARD_ROWS:
+        raise MeshUnsupported(
+            f"capacity overflow: {cap} x {copies} rows per shard")
+    return cap
+
+
 # Cols are positional lists of (data, validity-or-None); a fragment maps an
 # environment of stage outputs to its own (cols, sel, retry_flags,
 # fatal_flags).
@@ -132,6 +150,9 @@ class MeshExecutor:
         self.config = config or {}
         self._subquery_cache: Dict[int, object] = {}
         self.last_exchanges = 0       # collective edges in the last program
+        self.last_retries = 0         # attempts the last run had to redo
+        #: {device: bytes} of the largest leaf array the last run placed
+        self.last_leaf_shard_bytes: Dict[str, int] = {}
         self.last_hlo: Optional[str] = None
         self._group_cap = int(self.config.get(
             "spark.sail.mesh.maxGroups", _DEFAULT_GROUPS))
@@ -229,6 +250,7 @@ class MeshExecutor:
                 cache_key, dict_objs)
             if result is None:
                 continue  # retryable overflow: scale capacities and redo
+            self.last_retries = idx - start
             if idx > 0:
                 _ATTEMPT_HINT[base_key] = idx
                 while len(_ATTEMPT_HINT) > _PROGRAM_CACHE_MAX:
@@ -342,8 +364,8 @@ class MeshExecutor:
             if mode == jg.InputMode.SHUFFLE:
                 if stage.shuffle_keys is None:
                     raise MeshUnsupported("shuffle stage without keys")
-                bucket_cap = bucket_capacity(
-                    max(8, -(-frag.cap * 2 * bucket_mult // P)))
+                bucket_cap = _scaled_capacity(
+                    max(8, -(-frag.cap * 2 * bucket_mult // P)), copies=P)
                 ex = self._bind_shuffle(frag, stage.shuffle_keys, P,
                                         bucket_cap)
                 exchanges.append((stage.stage_id, "shuffle", ex))
@@ -470,6 +492,10 @@ class MeshExecutor:
 
         if scan.format == "__driver__":
             table = graph.scan_tables[scan.table_name]
+            if not isinstance(table, pa.Table):
+                # a user data source, (class, options): the local
+                # executor reads it at execution; nothing to shard here
+                raise MeshUnsupported("scan of a python data source")
             hb = _positional(ai.from_arrow(table))
         else:
             hb = LocalExecutor(self.config)._exec_ScanExec(scan)
@@ -518,6 +544,11 @@ class MeshExecutor:
                     flat.append(jax.device_put(v, sharding))
             flat.append(jax.device_put(ld.sel, sharding))
             ld.placed = flat
+            biggest = max(flat, key=lambda a: a.nbytes)
+            if biggest.nbytes > sum(self.last_leaf_shard_bytes.values()):
+                self.last_leaf_shard_bytes = {
+                    str(s.device): s.data.nbytes
+                    for s in biggest.addressable_shards}
         return ld.placed
 
     def _flatten_leaf_arrays(self, leaves: Dict[int, _LeafData]) -> List:
@@ -751,7 +782,7 @@ class MeshExecutor:
         em = int(getattr(self, "_expand_mult", 1))
         has_res = residual_c is not None
         expand = em > 1 and (jt in ("inner", "left") or has_res)
-        exp_cap = bucket_capacity(left.cap * em)
+        exp_cap = _scaled_capacity(left.cap * em)
         n_right = len(right.types)
         if jt in ("semi", "anti") or not expand:
             out_cap = left.cap

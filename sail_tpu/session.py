@@ -253,17 +253,19 @@ class SparkSession:
         router.record_decisions([decision])
         if decision.backend != "mesh":
             return None
+        from .parallel.mesh_exec import MeshExecutor, MeshUnsupported
+        ex = MeshExecutor(config=dict(self.conf.items()))
         try:
-            from .parallel.mesh_exec import MeshExecutor
-            ex = MeshExecutor(config=dict(self.conf.items()))
             result = ex.execute(node)
-            if result is not None:
-                self._last_mesh_executor = ex
-            return result
-        except Exception:
+        except MeshUnsupported:
+            # the executor's declared "not this graph" signal; anything
+            # else (a program the compiler refuses, a device fault) rises
             if mode == "force":
                 raise
             return None
+        if result is not None:
+            self._last_mesh_executor = ex
+        return result
 
     # -- entry points -------------------------------------------------------
     def sql(self, query: str) -> "DataFrame":

@@ -250,14 +250,21 @@ def test_mesh_shuffle_join_string_keys(spark):
     assert out.to_pandas()["c"].sum() == 3000
 
 
-def test_all_tpch_queries_use_mesh_path(spark):
+def test_all_tpch_queries_use_mesh_path(spark, monkeypatch):
     """Coverage lock: every TPC-H query routes (at least a subtree)
     through the SPMD mesh executor on the 8-device test mesh — the
     round-4 review flagged mesh op coverage as a fallback cliff.
     The session records _last_mesh_executor only when the mesh program
-    actually produced the result (session.py _try_mesh_execute)."""
+    actually produced the result (session.py _try_mesh_execute). The
+    router's row floor is held at 0: these SF0.005 tables sit under
+    it, and this test is about op coverage, not about the cost gate.
+    ``auto`` mode keeps the session's MeshUnsupported-only fallback in
+    play — any other exception from the executor fails the test."""
     from sail_tpu.benchmarks.tpch_data import register_tpch
     from sail_tpu.benchmarks.tpch_queries import QUERIES
+    from sail_tpu.exec import router
+
+    monkeypatch.setattr(router, "mesh_min_rows", lambda: 0)
 
     # Local-oracle comparison runs only for the historically
     # fallback-prone classes (dup-key expansion, global agg, scalar
@@ -289,3 +296,22 @@ def test_all_tpch_queries_use_mesh_path(spark):
         assert not fell_back, f"queries off the mesh path: {fell_back}"
     finally:
         spark.conf.reset("spark.sail.execution.mesh")
+
+
+@pytest.mark.parametrize("rows,copies,fits", [
+    (1 << 20, 4, True),
+    (1 << 30, 1, True),
+    (1 << 31, 1, False),      # q5 at SF1, third attempt
+    (1 << 29, 4, False),      # an exchange's P send buckets in one array
+])
+def test_scaled_capacity_overflow_is_the_declared_signal(rows, copies, fits):
+    """A retry multiplier that scales a capacity past int32 indexing is
+    "capacity overflow" (MeshUnsupported: the session falls back), not
+    an OverflowError from inside the trace."""
+    from sail_tpu.parallel.mesh_exec import (MeshUnsupported,
+                                             _scaled_capacity)
+    if fits:
+        assert _scaled_capacity(rows, copies) >= rows
+    else:
+        with pytest.raises(MeshUnsupported, match="capacity overflow"):
+            _scaled_capacity(rows, copies)

@@ -253,6 +253,109 @@ print("WROTE", digest, mine)
         assert pcache.load(digest, site="test") is not None
 
 
+_DEVICE_BOUND_SCRIPT = r"""
+import sys
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from sail_tpu.exec import pcache
+
+assert len(jax.devices()) == 8
+mesh = Mesh(np.array(jax.devices()), ("data",))
+x1 = jnp.arange(64, dtype=jnp.float32)
+x8 = jax.device_put(jnp.arange(64, dtype=jnp.float32),
+                    NamedSharding(mesh, P("data")))
+single = lambda x: x * 2.0 + 1.0
+spmd = jax.shard_map(lambda x: jax.lax.psum(jnp.sum(x), "data"),
+                     mesh=mesh, in_specs=P("data"), out_specs=P())
+d1 = pcache.entry_digest("one-device", "d0", pcache.signature((x1,)))
+d8 = pcache.entry_digest("eight-devices", "d0", pcache.signature((x8,)))
+if sys.argv[1] == "store":
+    assert pcache.store(d1, jax.jit(single).lower(x1).compile(), 0.1)
+    assert pcache.store(d8, jax.jit(spmd).lower(x8).compile(), 0.1)
+else:
+    f1, f8 = pcache.load(d1), pcache.load(d8)
+    assert f1 is not None and f8 is not None
+    np.testing.assert_array_equal(np.asarray(f1(x1)), np.arange(64) * 2.0 + 1)
+    assert float(f8(x8)) == float(np.arange(64).sum())
+print("OK", sys.argv[1])
+"""
+
+
+def test_single_device_and_mesh_programs_load_in_fresh_interpreter(store):
+    """jax 0.9 binds a deserialized executable to EVERY device of the
+    backend unless told otherwise; the store records the devices each
+    program was compiled for, so a one-device program and an
+    eight-device program both load in a new process and run."""
+    env = dict(os.environ)
+    env["SAIL_COMPILE_CACHE__DIR"] = store
+    env["SAIL_COMPILE_CACHE__ENABLED"] = "1"
+    for mode in ("store", "load"):
+        r = subprocess.run(
+            [sys.executable, "-c", _DEVICE_BOUND_SCRIPT, mode], env=env,
+            capture_output=True, text=True, timeout=180)
+        assert r.returncode == 0, r.stderr[-2000:]
+        assert f"OK {mode}" in r.stdout
+
+
+def test_entry_for_absent_devices_is_a_miss_not_a_crash(store):
+    import jax
+    import jax.numpy as jnp
+    x = jnp.arange(16, dtype=jnp.float32)
+    digest = pcache.entry_digest("k", "d0", pcache.signature((x,)))
+    compiled = jax.jit(lambda v: v + 1).lower(x).compile()
+    assert pcache.store(digest, compiled, 0.1)
+    path = os.path.join(store, digest + ".sailpc")
+    blob = open(path, "rb").read()
+    assert b'"devices":[0]' in blob
+    with open(path, "wb") as f:
+        f.write(blob.replace(b'"devices":[0]', b'"devices":[4096]'))
+    fn, reason = pcache._load(digest)
+    assert fn is None and reason == "skew"
+    assert not os.path.exists(path + ".bad")
+
+
+_JAX_CACHE_SCRIPT = r"""
+import os
+import jax, jax.numpy as jnp
+from sail_tpu.exec import pcache
+before = jax.config.jax_compilation_cache_dir
+placed = pcache.place_jax_cache()
+pcache.enabled()      # the executors' first consult: must not re-place
+jax.jit(lambda x: jnp.tanh(x) * 3.0)(jnp.arange(7.0)).block_until_ready()
+print("PLACED", placed)
+print("CONFIG", jax.config.jax_compilation_cache_dir)
+print("UNTOUCHED", before == jax.config.jax_compilation_cache_dir)
+print("ENTRIES", len(os.listdir(placed)))
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_jax_cache_goes_where_the_environment_says(tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR set: the program never overrides it.
+    Unset: one fixed directory in the checkout, whatever
+    compile_cache.dir says."""
+    env = dict(os.environ)
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "true"
+    env["SAIL_COMPILE_CACHE__DIR"] = str(tmp_path / "aot")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "placed")
+    r = subprocess.run([sys.executable, "-c", _JAX_CACHE_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=180)
+    assert r.returncode == 0, r.stderr[-2000:]
+    out = dict(line.split(" ", 1) for line in r.stdout.splitlines()
+               if line.split(" ", 1)[0] in
+               ("PLACED", "CONFIG", "UNTOUCHED", "ENTRIES"))
+    want = str(tmp_path / "placed") if from_env else pcache.JAX_CACHE_DIR
+    assert out["PLACED"] == want and out["CONFIG"] == want
+    assert out["UNTOUCHED"] == str(from_env)
+    assert int(out["ENTRIES"]) >= 1
+    assert not os.path.exists(tmp_path / "aot" / "xla")
+    assert pcache.JAX_CACHE_DIR == os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+
+
 def test_eviction_cheapest_compile_first(store, monkeypatch):
     monkeypatch.setenv("SAIL_COMPILE_CACHE__MAX_MB", "1")
     pcache.reload()
