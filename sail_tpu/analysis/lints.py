@@ -462,7 +462,9 @@ def lint_proto(ctx: LintContext) -> List[Violation]:
 # sync-point allowlist (host<->device round trips in exec/ and ops/)
 # ---------------------------------------------------------------------------
 
-_SYNC_ATTRS = {"device_get", "block_until_ready"}
+#: ``profiler.host_sync`` is ``device_get`` inside a ``sync`` span: its
+#: callers are the sync points, held to the same allowlist
+_SYNC_ATTRS = {"device_get", "block_until_ready", "host_sync"}
 
 
 class _QualnameVisitor(ast.NodeVisitor):

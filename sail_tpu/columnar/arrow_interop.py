@@ -271,7 +271,8 @@ def to_arrow(batch: HostBatch) -> pa.Table:
         fetch[f"d:{name}"] = col.data
         if col.validity is not None:
             fetch[f"v:{name}"] = col.validity
-    host = jax.device_get(fetch)
+    from .. import profiler
+    host = profiler.host_sync("to_arrow", fetch)
     sel = np.asarray(host["sel"])
     idx = np.nonzero(sel)[0]
     arrays = []

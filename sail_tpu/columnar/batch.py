@@ -219,30 +219,37 @@ def make_batch(columns: Dict[str, Tuple[np.ndarray, Optional[np.ndarray], dt.Dat
                bucket_key=None) -> DeviceBatch:
     import jax
 
+    from .. import tracing as tr
+    from ..profiler import note_transfer_bytes
+
     cap = capacity if capacity is not None else \
         bucket_capacity(num_rows, key=bucket_key)
-    host = {}
-    types = {}
-    for name, (values, validity, dtype) in columns.items():
-        n = len(values)
-        data = np.zeros(cap, dtype=physical_jnp_dtype(dtype))
-        data[:n] = values
-        v = None
-        if validity is not None:
-            v = np.zeros(cap, dtype=bool)
-            v[:n] = validity
-        host[name] = (data, v)
-        types[name] = dtype
-    sel = np.zeros(cap, dtype=bool)
-    sel[:num_rows] = True
-    # ONE batched transfer for all columns (a per-column jnp.asarray costs
-    # ~1 ms of dispatch each; the output of a small aggregate was paying
-    # 10+ ms in uploads alone)
-    from ..profiler import note_transfer_bytes
-    note_transfer_bytes(sel.nbytes + sum(
-        d.nbytes + (v.nbytes if v is not None else 0)
-        for d, v in host.values()))
-    dhost, dsel = jax.device_put((host, sel))
+    # padding to the capacity bucket + the transfer: the host's share of
+    # getting a table onto the device
+    with tr.span("upload") as sp:
+        host = {}
+        types = {}
+        for name, (values, validity, dtype) in columns.items():
+            n = len(values)
+            data = np.zeros(cap, dtype=physical_jnp_dtype(dtype))
+            data[:n] = values
+            v = None
+            if validity is not None:
+                v = np.zeros(cap, dtype=bool)
+                v[:n] = validity
+            host[name] = (data, v)
+            types[name] = dtype
+        sel = np.zeros(cap, dtype=bool)
+        sel[:num_rows] = True
+        # ONE batched transfer for all columns (a per-column jnp.asarray
+        # costs ~1 ms of dispatch each; the output of a small aggregate
+        # was paying 10+ ms in uploads alone)
+        nbytes = sel.nbytes + sum(
+            d.nbytes + (v.nbytes if v is not None else 0)
+            for d, v in host.values())
+        sp.attributes["bytes"] = nbytes
+        note_transfer_bytes(nbytes)
+        dhost, dsel = jax.device_put((host, sel))
     cols = {name: Column(dhost[name][0], dhost[name][1], types[name])
             for name in host}
     return DeviceBatch(cols, dsel)

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 
+from .. import profiler
 from ..columnar import arrow_interop as ai
 from ..metrics import record as _record_metric
 from ..columnar.batch import (Column, DeviceBatch, HostBatch,
@@ -367,14 +368,20 @@ def _compile_timed(fn, key, fused=False):
     When the introspection hook is absent, only the first call is timed
     (the pre-forensics behavior). ``fused`` marks whole-stage programs:
     their compile time additionally rides
-    ``execution.fusion.compile_time``."""
+    ``execution.fusion.compile_time``.
+
+    Every call is a ``dispatch`` span (attribute ``program``, the name
+    the XLA module runs under: ``pcache.program_name(key)``); a call
+    that compiled holds a ``compile`` span (``source`` ``trace``,
+    ``cause`` as ``retrace.attribute`` typed it) covering it."""
     import time as _time
 
-    from .. import profiler
-    from . import retrace
+    from .. import tracing as tr
+    from . import pcache, retrace
 
     cache_size = getattr(fn, "_cache_size", None)
     pending = [True]
+    name = pcache.program_name(key)
 
     def _charge(elapsed_s: float, args) -> None:
         key_repr = repr(key[0]) if isinstance(key, tuple) and key \
@@ -386,31 +393,35 @@ def _compile_timed(fn, key, fused=False):
                                elapsed_s)
             except Exception:  # noqa: BLE001 — timing must never raise
                 pass
-        profiler.note_compile_time(elapsed_s, key=key_repr)
-        from . import pcache
-        retrace.attribute(key, pcache.signature(args), elapsed_s,
-                          site="memory")
+        # known to have compiled only now that the call is over: the
+        # span starts where the call did
+        with tr.span("compile", {"program": name, "source": "trace"},
+                     backdate_ns=int(elapsed_s * 1e9)) as sp:
+            profiler.note_compile_time(elapsed_s, key=key_repr)
+            sp.attributes["cause"] = retrace.attribute(
+                key, pcache.signature(args), elapsed_s, site="memory")
 
     def wrapper(*args, **kwargs):
-        first = bool(pending)
-        if cache_size is None:
-            if not first:
-                return fn(*args, **kwargs)
-            del pending[:]
+        with tr.span("dispatch", {"program": name}):
+            first = bool(pending)
+            if cache_size is None:
+                if not first:
+                    return fn(*args, **kwargs)
+                del pending[:]
+                t0 = _time.perf_counter()
+                out = fn(*args, **kwargs)
+                _charge(_time.perf_counter() - t0, args)
+                return out
+            n0 = cache_size()
             t0 = _time.perf_counter()
             out = fn(*args, **kwargs)
-            _charge(_time.perf_counter() - t0, args)
-            return out
-        n0 = cache_size()
-        t0 = _time.perf_counter()
-        out = fn(*args, **kwargs)
-        if cache_size() > n0:
-            if first:
+            if cache_size() > n0:
+                if first:
+                    del pending[:]
+                _charge(_time.perf_counter() - t0, args)
+            elif first:
                 del pending[:]
-            _charge(_time.perf_counter() - t0, args)
-        elif first:
-            del pending[:]
-        return out
+            return out
 
     return wrapper
 
@@ -511,7 +522,6 @@ class LocalExecutor:
         """Stage-split accounting + the fused-stage invariant walk (the
         splitter's output drives this query's fusion decisions, so a bad
         split must surface here, not as a wrong answer)."""
-        from .. import profiler
         from ..analysis.invariants import (VALIDATE_OFF,
                                            validate_stage_split,
                                            validation_mode)
@@ -543,7 +553,6 @@ class LocalExecutor:
     def _note_fusion_fallback(self, site: str) -> None:
         """One pipeline declined whole-stage fusion at execution time
         (host-only expressions etc.) and ran per-op instead."""
-        from .. import profiler
         _record_metric("execution.fusion.fallback_count", 1, site=site)
         profiler.note_fusion(fallbacks=1)
 
@@ -552,7 +561,6 @@ class LocalExecutor:
         """Run a plan to an Arrow table with the plan's output names."""
         import contextlib
 
-        from .. import profiler
         # a nested executor (scalar subquery, command sub-plan) runs
         # entirely inside the outer "execute" timer — recording its
         # fetch separately would overlap the phases
@@ -574,16 +582,18 @@ class LocalExecutor:
         if method is None:
             raise ExecutionError(f"no executor for {type(plan).__name__}")
         from .. import telemetry as tel
-        if tel.current_collector() is None:
-            return method(plan)
         detail = ""
         if isinstance(plan, pn.ScanExec):
             detail = plan.table_name or ",".join(plan.paths)
+        # the one wrapper: an op.<PlanNode> span always, and under
+        # EXPLAIN ANALYZE (m is not None) the operator's metrics
         with tel.operator_span(type(plan).__name__, detail) as m:
             out = method(plan)
-            # rows/capacity force a device sync — only under EXPLAIN ANALYZE
-            m.output_rows = int(out.device.num_rows())
-            m.capacity = out.capacity
+            if m is not None:
+                # rows/capacity force a device sync — only under
+                # EXPLAIN ANALYZE
+                m.output_rows = int(out.device.num_rows())
+                m.capacity = out.capacity
             return out
 
     # ------------------------------------------------------------------
@@ -687,7 +697,6 @@ class LocalExecutor:
         contract the entry digest verifies."""
         import jax
 
-        from .. import profiler
 
         if key is None:
             # unhashable plan key: uncached eager build — still a miss
@@ -696,10 +705,13 @@ class LocalExecutor:
             return fn, aux
 
         def build():
+            from . import pcache
             missed.append(True)
             fn, aux = builder()
+            # the XLA module, the dispatch span and the device trace's
+            # operations all carry this name (pcache.program_name)
+            fn = pcache.named(fn, pcache.program_name(key))
             if self._pcache_on():
-                from . import pcache
                 site = key[0] if isinstance(key, tuple) and key \
                     and isinstance(key[0], str) else "op"
                 wrapped = pcache.wrap(fn, key, dict_objs, fused=fused,
@@ -733,7 +745,6 @@ class LocalExecutor:
                 table = table.select(list(p.projection))
             return _positional(ai.from_arrow(table))
         from . import result_cache as rc
-        from .. import profiler
         rtf_preds = p.runtime_predicates
         if p.source is not None:
             cache_key = ("mem", id(p.source), p.projection, rtf_preds)
@@ -897,7 +908,6 @@ class LocalExecutor:
         pruned = before - after
         if pruned <= 0:
             return
-        from .. import profiler
         from .. import telemetry as tel
         _record_metric("execution.runtime_filter.rows_pruned", pruned,
                        site="scan")
@@ -1550,7 +1560,8 @@ class LocalExecutor:
 
         comp = self._compiler(child, p.input.schema)
         interp = HostInterpreter(self, comp, child)
-        sel = np.asarray(jax.device_get(child.device.sel))
+        sel = np.asarray(profiler.host_sync("sort.host_fallback",
+                                            child.device.sel))
         frame: Dict[str, object] = {"__dead": ~sel}
         by = ["__dead"]          # dead rows sort to the end
         asc = [True]
@@ -1950,14 +1961,15 @@ class LocalExecutor:
                                      fused=bool(chain))
         gk, aggs_out, gsel, n_groups, overflow = fn(self._cols(child), dev.sel)
         # one batched fetch: each blocking scalar read is a device sync
-        n_groups, overflow = jax.device_get((n_groups, overflow))
+        n_groups, overflow = profiler.host_sync(
+            "agg.n_groups", (n_groups, overflow))
         if p.max_groups_hint and bool(overflow):
             key2 = self._op_key("agg2", stage_key, dev.capacity)
             fn2, top_dicts = self._jitted(key2, self._dict_objs(child),
                                           make_builder(dev.capacity),
                                           fused=bool(chain))
             gk, aggs_out, gsel, n_groups, overflow = fn2(self._cols(child), dev.sel)
-            n_groups = jax.device_get(n_groups)
+            n_groups = profiler.host_sync("agg2.n_groups", n_groups)
         out_cols: Dict[str, Column] = {}
         out_dicts: Dict[str, pa.Array] = {}
         for j, gi in enumerate(p.group_indices):
@@ -2376,7 +2388,6 @@ class LocalExecutor:
 
         import jax
 
-        from .. import profiler
         from ..ops import hash as hashk
         from ..ops import runtime_filter as rtfk
         from ..plan import runtime_filters as rtfp
@@ -2459,7 +2470,7 @@ class LocalExecutor:
         bundle = [res.n_build, res.ndv, bounds]
         if fetch_values:
             bundle.append((datas, usable))
-        fetched = jax.device_get(tuple(bundle))
+        fetched = profiler.host_sync("rtf_build", tuple(bundle))
         n_build, ndv = int(fetched[0]), int(fetched[1])
         host_bounds = fetched[2]
         if n_build < conf.min_build_rows:
@@ -2526,7 +2537,6 @@ class LocalExecutor:
         """Post-join accounting: probe-mask pruning + adaptive history
         (scan-site pruning for this join's fids folds in, so an effective
         scan push does not read as a useless probe mask)."""
-        from .. import profiler
         from .. import telemetry as tel
 
         pruned = before - after
@@ -2663,7 +2673,8 @@ class LocalExecutor:
             # one batched fetch for every host decision scalar (each
             # separate blocking read is a device round trip)
             (has_dup_a, ambiguous, inner_total, exact, rtf_before,
-             rtf_after) = jax.device_get(
+             rtf_after) = profiler.host_sync(
+                "join_phase",
                 (has_dup_a, ambiguous, inner_total, exact, rtf_before,
                  rtf_after))
             if exact or not bool(ambiguous):
@@ -2750,7 +2761,8 @@ class LocalExecutor:
             # skip the per-join device round trip entirely
             return None
         import jax
-        n_left, n_right = jax.device_get(  # ONE round trip, not two
+        n_left, n_right = profiler.host_sync(  # ONE round trip, not two
+            "join.spill_decision",
             (jnp.sum(left.device.sel), jnp.sum(right.device.sel)))
         n_left, n_right = int(n_left), int(n_right)
         if n_left + n_right <= threshold:
@@ -2794,7 +2806,6 @@ class LocalExecutor:
         tmpdir = tempfile.mkdtemp(prefix="sail_join_spill_")
         self._last_join_spill_dir = tmpdir  # observable in tests
         _record_metric("execution.spill_count", 1, kind="join")
-        from .. import profiler
         spill_bytes = 0
         sides = []
         for name, table, h in (("l", lt, lh), ("r", rt, rh)):
@@ -2912,7 +2923,8 @@ class LocalExecutor:
             # skip the device round trip the exact count would cost
             return None
         import jax
-        n = int(jax.device_get(jnp.sum(child.device.sel)))
+        n = int(profiler.host_sync("sort.spill_decision",
+                                   jnp.sum(child.device.sel)))
         if n <= threshold:
             return None
 
@@ -3003,7 +3015,6 @@ class LocalExecutor:
                     perm = perm[:p.limit]
                 paths = list(pf)
             del table
-            from .. import profiler
             profiler.note_spill_bytes(
                 sum(os.path.getsize(fp) for fp in paths))
             tel.note("SpillSortPrefetch", f"{len(paths)} runs",
@@ -3135,8 +3146,9 @@ class LocalExecutor:
     def _cross_join(self, p: pn.JoinExec, left: HostBatch, right: HostBatch) -> HostBatch:
         import jax
         n_left_rows, n_right_rows = (
-            int(x) for x in jax.device_get((left.device.num_rows(),
-                                            right.device.num_rows())))
+            int(x) for x in profiler.host_sync(
+                "cross_join.capacity", (left.device.num_rows(),
+                                        right.device.num_rows())))
         total = n_left_rows * n_right_rows
         cap = bucket_capacity(max(total, 1),
                               key=("cross-join", pst.node_fingerprint(p)))

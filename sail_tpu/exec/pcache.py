@@ -49,6 +49,7 @@ import json
 import logging
 import os
 import pickle
+import re
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -208,6 +209,37 @@ def env_fingerprint() -> Tuple:
         platform, count = "none", 0
     return (FORMAT_VERSION, jax.__version__, jaxlib.__version__,
             platform, count, bool(jax.config.jax_enable_x64))
+
+
+_ADDRESS = re.compile(r" at 0x[0-9a-fA-F]+")
+
+
+def program_name(key) -> str:
+    """``sail_<site>_<8 hex>``: the name a stage's jitted program runs
+    under, so that its XLA module (``jit_sail_join_phase_1a2b3c4d``), the
+    executor's ``dispatch`` span and the device trace's operations name
+    the same thing. ``site`` is ``key[0]`` as the call sites write it;
+    the digest is of the structural key's repr alone — no ``id()``, no
+    dictionary identity, no seed, no process — because JAX's persistent
+    cache keys the module name: a name that moved between processes
+    would make every start a cold one."""
+    site = key[0] if isinstance(key, tuple) and key \
+        and isinstance(key[0], str) else "op"
+    digest = hashlib.sha256(
+        _ADDRESS.sub("", repr(key)).encode()).hexdigest()[:8]
+    return f"sail_{site}_{digest}"
+
+
+def named(fn, name: str):
+    """``fn`` under ``name`` (what ``jax.jit`` calls the module)."""
+    try:
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+    except (AttributeError, TypeError):
+        def program(*args, **kwargs):
+            return fn(*args, **kwargs)
+        program.__name__ = program.__qualname__ = name
+        return program
 
 
 def signature(args) -> Optional[Tuple]:
@@ -809,10 +841,11 @@ class PersistentProgram:
 
     __slots__ = ("_fn", "_key", "_key_repr", "_dict_objs", "_fused",
                  "_site", "_per_sig", "_dict_digest", "_jit_fallback",
-                 "_fast")
+                 "_fast", "_name")
 
     def __init__(self, fn, key, dict_objs: Tuple, fused: bool = False,
                  site: str = "op"):
+        self._name = program_name(key)   # the callers named fn so
         self._fn = fn
         self._key = key
         self._key_repr = repr(key)
@@ -847,6 +880,7 @@ class PersistentProgram:
         import jax
 
         from .. import profiler
+        from .. import tracing as tr
         from ..metrics import timer as _metric_timer
         from . import retrace
 
@@ -872,7 +906,11 @@ class PersistentProgram:
                 retrace.LEDGER.note_digest(digest)
                 retrace.LEDGER.note_bound(self._key, sig)
                 return pre
-            loaded, reason = _load(digest, site=self._site)
+            with tr.span("compile", {"program": self._name,
+                                     "source": "persistent"}) as sp:
+                loaded, reason = _load(digest, site=self._site)
+                sp.attributes["cause"] = "loaded" if loaded is not None \
+                    else str(reason)
             if loaded is not None:
                 # bound without compiling: remember the signature (and
                 # that this process held the digest) so a later
@@ -885,21 +923,30 @@ class PersistentProgram:
             # count the consult so hit ratios stay honest
             _count("execution.compile.persistent_miss_count")
             _note_profile(False)
-        with _metric_timer("execution.fusion.compile_time"
-                           if self._fused else None) as tm:
-            lowered = jax.jit(self._fn).lower(*args)
-            compiled = lowered.compile()
-        key_repr = repr(self._key[0]) if isinstance(self._key, tuple) \
-            and self._key else self._key_repr
-        profiler.note_compile_time(tm.elapsed_s, key=key_repr)
-        retrace.attribute(self._key, sig, tm.elapsed_s, site="pcache",
-                          pcache_reason=reason, digest=digest)
+        with tr.span("compile", {"program": self._name,
+                                 "source": "trace"}) as sp:
+            with _metric_timer("execution.fusion.compile_time"
+                               if self._fused else None) as tm:
+                lowered = jax.jit(self._fn).lower(*args)
+                compiled = lowered.compile()
+            key_repr = repr(self._key[0]) \
+                if isinstance(self._key, tuple) and self._key \
+                else self._key_repr
+            profiler.note_compile_time(tm.elapsed_s, key=key_repr)
+            sp.attributes["cause"] = retrace.attribute(
+                self._key, sig, tm.elapsed_s, site="pcache",
+                pcache_reason=reason, digest=digest)
         if digest is not None and not _has_host_callback(lowered):
             if store(digest, compiled, tm.elapsed_s, site=self._site):
                 retrace.LEDGER.note_digest(digest)
         return compiled
 
     def __call__(self, *args):
+        from .. import tracing as tr
+        with tr.span("dispatch", {"program": self._name}):
+            return self._dispatch(*args)
+
+    def _dispatch(self, *args):
         fast = self._fast
         if fast is not None:
             try:

@@ -62,6 +62,12 @@ def infer_schema(fmt: str, paths: Sequence[str], options: Dict[str, str]) -> dt.
     if not files:
         raise FileNotFoundError(f"no files found for {paths}")
     table = read_table(fmt, files[:1], options, limit=1000)
+    from .. import tracing as tr
+    tr.set_attribute("files", len(files))
+    try:  # the read decodes the whole first file, whatever the limit
+        tr.set_attribute("bytes_read", os.path.getsize(files[0]))
+    except OSError:
+        pass
     return dt.StructType(tuple(
         dt.StructField(n, arrow_type_to_spec(c.type), True)
         for n, c in zip(table.column_names, table.columns)))

@@ -27,7 +27,11 @@ Contract:
 - Overlap observability: producer-wait (blocked on a full queue: IO is
   ahead, compute is the bottleneck) and consumer-wait (blocked on an
   empty queue: IO is the bottleneck) accumulate per pipeline and flush
-  into the metrics registry on close.
+  into the metrics registry on close. In the statement's span tree
+  (tracing.py) a ``Prefetcher`` leaves ``<kind>.decode`` per item on the
+  producer thread, child of the span the consumer had open when it
+  built the pipeline, and ``<kind>.wait`` per blocked ``__next__``;
+  consumer-wait IS the summed ``<kind>.wait``, read off the span.
 """
 
 from __future__ import annotations
@@ -38,6 +42,7 @@ import time
 from dataclasses import dataclass
 from typing import Callable, Iterable, Iterator, Optional
 
+from .. import tracing as tr
 from ..metrics import record as _record_metric
 
 _SENTINEL = object()
@@ -100,18 +105,26 @@ def _bounded_put(q: queue.Queue, cancel: threading.Event, obj,
 
 def _produce(source: Iterator, transform: Optional[Callable],
              q: queue.Queue, cancel: threading.Event,
-             stats: PrefetchStats) -> None:
+             stats: PrefetchStats, parent) -> None:
     """Producer thread body. Module-level on purpose: a bound-method
     target would hold a strong reference to the Prefetcher, so an
     abandoned (never-closed) instance could never be collected and its
-    ``__del__`` safety net could never cancel this thread."""
+    ``__del__`` safety net could never cancel this thread. ``parent``
+    is the consumer's span context: each item's ``<kind>.decode`` span
+    (the source's ``next`` + the transform) is its child."""
+    name = stats.kind + ".decode"
     try:
-        for item in source:
-            if cancel.is_set():
-                return
-            out = item if transform is None else transform(item)
+        while not cancel.is_set():
+            with tr.span(name, parent=parent):
+                try:
+                    item = next(source)
+                except StopIteration:
+                    break
+                out = item if transform is None else transform(item)
             if not _bounded_put(q, cancel, out, stats):
                 return
+        else:
+            return
     except BaseException as exc:  # noqa: BLE001 — relayed, not dropped
         _bounded_put(q, cancel, _ProducerError(exc), None)
         return
@@ -138,7 +151,7 @@ class Prefetcher(Iterator):
         self._thread = threading.Thread(
             target=_produce,
             args=(self._source, self._transform, self._q, self._cancel,
-                  self.stats),
+                  self.stats, tr.current_context()),
             name=f"sail-prefetch-{kind}", daemon=True)
         self._thread.start()
 
@@ -149,25 +162,29 @@ class Prefetcher(Iterator):
     def __next__(self):
         if self._done:
             raise StopIteration
+        wait = self.stats.kind + ".wait"
         if self._thread is None:  # synchronous passthrough (depth 0)
-            t0 = time.perf_counter()
-            try:
-                item = next(self._source)
-            except BaseException:  # noqa: BLE001 — close on exhaustion
-                self.close()      # AND source errors, then re-raise:
-                raise             # every exit path flushes stats
-            try:
-                out = item if self._transform is None \
-                    else self._transform(item)
-            except BaseException as exc:  # noqa: BLE001 — relayed below
-                self.close()
-                raise self._wrap_stop(exc)
-            self.stats.consumer_wait_s += time.perf_counter() - t0
+            failed = None
+            with tr.span(wait) as sp:
+                try:
+                    item = next(self._source)
+                except BaseException as exc:  # noqa: BLE001 — exhaustion
+                    failed = exc              # AND source errors
+                else:
+                    try:
+                        out = item if self._transform is None \
+                            else self._transform(item)
+                    except BaseException as exc:  # noqa: BLE001
+                        failed = self._wrap_stop(exc)
+            if failed is not None:
+                self.close()      # every exit path flushes stats
+                raise failed
+            self.stats.consumer_wait_s += sp.ms / 1000.0
             self.stats.chunks += 1
             return out
-        t0 = time.perf_counter()
-        obj = self._q.get()
-        self.stats.consumer_wait_s += time.perf_counter() - t0
+        with tr.span(wait) as sp:
+            obj = self._q.get()
+        self.stats.consumer_wait_s += sp.ms / 1000.0
         if obj is _SENTINEL:
             self.close()
             raise StopIteration

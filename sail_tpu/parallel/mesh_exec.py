@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 
+from .. import profiler
 from ..columnar import arrow_interop as ai
 from ..columnar.batch import (Column, DeviceBatch, HostBatch,
                               bucket_capacity)
@@ -420,7 +421,12 @@ class MeshExecutor:
                     fatal_total[None])
 
         from jax.sharding import PartitionSpec as Pspec
+        from ..exec import pcache
         spec = Pspec(DATA_AXIS)
+        # sail_mesh_<digest of the structural key>: the module's name in
+        # a device trace, as the local executor names its stages
+        program = pcache.named(program,
+                               pcache.program_name(("mesh", cache_key)))
         wrapped = jax.shard_map(
             program, mesh=mesh,
             in_specs=tuple(spec for _ in range(n_flat)),
@@ -471,8 +477,8 @@ class MeshExecutor:
     def _run_program(self, jitted, leaves, stage_out, top_id):
         flat_in = self._flatten_leaf_arrays(leaves)
         flat_out, out_sel, retry_tot, fatal_tot = jitted(*flat_in)
-        retry_tot, fatal_tot = jax.device_get(
-            (np.asarray(retry_tot), np.asarray(fatal_tot)))
+        retry_tot, fatal_tot = profiler.host_sync(
+            "mesh.flags", (retry_tot, fatal_tot))
         if int(np.max(fatal_tot)) > 0:
             raise MeshUnsupported("fatal flag raised in mesh program")
         if int(np.max(retry_tot)) > 0:
@@ -500,13 +506,13 @@ class MeshExecutor:
         else:
             hb = LocalExecutor(self.config)._exec_ScanExec(scan)
         dev = hb.device
-        host = jax.device_get(
-            {"sel": dev.sel,
-             **{f"d{i}": dev.columns[_positional_name(i)].data
-                for i in range(len(dev.columns))},
-             **{f"v{i}": dev.columns[_positional_name(i)].validity
-                for i in range(len(dev.columns))
-                if dev.columns[_positional_name(i)].validity is not None}})
+        host = profiler.host_sync("mesh.leaf", {
+            "sel": dev.sel,
+            **{f"d{i}": dev.columns[_positional_name(i)].data
+               for i in range(len(dev.columns))},
+            **{f"v{i}": dev.columns[_positional_name(i)].validity
+               for i in range(len(dev.columns))
+               if dev.columns[_positional_name(i)].validity is not None}})
         sel = np.asarray(host["sel"])
         n = int(sel.sum())  # from_arrow keeps live rows as a prefix
         from ..exec.local import _scan_cap_key
@@ -986,11 +992,10 @@ class MeshExecutor:
     def _assemble(self, out_cols, out_sel, frag: _Frag) -> pa.Table:
         """One batched device fetch, then build arrow directly from the
         host buffers (no device re-upload)."""
-        host = jax.device_get({"sel": out_sel,
-                               **{f"d{i}": d for i, (d, v)
-                                  in enumerate(out_cols)},
-                               **{f"v{i}": v for i, (d, v)
-                                  in enumerate(out_cols)}})
+        host = profiler.host_sync("mesh.assemble", {
+            "sel": out_sel,
+            **{f"d{i}": d for i, (d, v) in enumerate(out_cols)},
+            **{f"v{i}": v for i, (d, v) in enumerate(out_cols)}})
         idx = np.nonzero(np.asarray(host["sel"]).reshape(-1))[0]
         arrays = []
         names = []

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import threading
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from ..functions import registry as freg
@@ -87,11 +88,18 @@ def _qual_suffix_match(field_quals: Tuple[str, ...], ref_quals: Tuple[str, ...])
     return field_quals[len(field_quals) - len(ref_quals):] == ref_quals
 
 
-_FRESH = itertools.count()
+#: per thread: a statement resolves on one thread, and two sessions
+#: resolving at once must not draw from one counter (generated names key
+#: the operator cache and, through the program's name, JAX's persistent
+#: cache: a name that depends on who else was resolving is a cold compile)
+_FRESH = threading.local()
 
 
 def _fresh(prefix: str) -> str:
-    return f"__{prefix}{next(_FRESH)}"
+    count = getattr(_FRESH, "count", None)
+    if count is None:       # a caller below Resolver.resolve's reset
+        count = _FRESH.count = itertools.count()
+    return f"__{prefix}{next(count)}"
 
 
 class Resolver:
@@ -104,8 +112,7 @@ class Resolver:
         # Deterministic generated names: identical queries resolve to
         # structurally-equal plans, which keys the executor's compiled-
         # operator cache.
-        global _FRESH
-        _FRESH = itertools.count()
+        _FRESH.count = itertools.count()
         node, _ = self.resolve_query(plan, None)
         return node
 
@@ -367,7 +374,11 @@ class Resolver:
             fields = [ScopeField(f.name, (), f.dtype, f.nullable)
                       for f in out]
             return node, Scope(fields, outer, {})
-        schema = plan.schema or infer_schema(plan.format, plan.paths, dict(plan.options))
+        from .. import tracing as tr
+        with tr.span("resolve.read_source",
+                     {"format": plan.format, "files": len(plan.paths)}):
+            schema = plan.schema or infer_schema(
+                plan.format, plan.paths, dict(plan.options))
         out = tuple(pn.Field(f.name, f.data_type, f.nullable) for f in schema.fields)
         node = pn.ScanExec(out, None, tuple(plan.paths), plan.format,
                            tuple(plan.options))

@@ -6,10 +6,17 @@ query engine (PAPERS.md), grafted onto sail's telemetry surface. One
 ``QueryProfile`` is threaded from the session entry point through the
 planner and both executors, recording
 
+- the statement's span tree (``spans``): ``profile_query`` opens the
+  root ``query`` span with the profile as its sink, and every
+  ``tracing.span`` opened beneath it, on this thread or handed to
+  another, is kept here when it ends (see tracing.py: the same span
+  also lies on the xplane while a ``jax.profiler`` session runs, and
+  goes to OTLP). ``span_ms`` / ``span_count`` / ``self_ms`` read it;
 - phase wall times in execution order: parse, resolve, optimize,
-  compile, execute, fetch. Parse/resolve/optimize/execute/fetch are
-  disjoint; compile is accounted *inside* execute — it is the JIT wall
-  time of operator cache misses — so it does not sum with the others;
+  compile, execute, fetch, each the summed duration of the phase's
+  spans. Parse/resolve/optimize/execute/fetch are disjoint; compile
+  is accounted *inside* execute — it is the JIT wall time of operator
+  cache misses — so it does not sum with the others;
 - JIT accounting from the compiled-operator cache: hits, misses, and
   per-key compile wall time (also exported through the registry as
   ``execution.compile.{cache_hit_count,cache_miss_count,compile_time}``);
@@ -21,8 +28,9 @@ Completed profiles land in a bounded flight-recorder ring (newest N),
 plus a slow-query log that retains queries above
 ``spark.sail.telemetry.slowQueryMs`` even after the ring evicts them.
 Both surfaces are SQL-queryable via ``system.telemetry.query_profiles``
-and ``system.telemetry.active_queries`` and ride the OTLP exporter as a
-``query`` span with the phase breakdown as attributes.
+and ``system.telemetry.active_queries``; the OTLP exporter receives the
+``query`` span with the phase breakdown as attributes, and the phase
+spans as its children.
 """
 
 from __future__ import annotations
@@ -46,6 +54,8 @@ logger = logging.getLogger("sail_tpu.profiler")
 PHASES = ("parse", "resolve", "optimize", "compile", "execute", "fetch")
 
 _STATEMENT_MAX = 4096
+#: spans one profile keeps (QueryProfile.admit_span)
+_SPANS_MAX = 512
 
 
 @dataclass
@@ -174,6 +184,19 @@ class QueryProfile:
     # {stage, partition} of the last distributed job
     tasks: List[dict] = field(default_factory=list)
     trace_id: Optional[str] = None
+    # the statement's span tree (tracing.Span, in order of completion):
+    # one root ``query`` (under ``spark_connect:execute_plan`` when the
+    # statement came over the wire), a child per phase, and below them
+    # the executor's ``op.*``, ``dispatch``, ``compile``, ``sync``,
+    # ``upload``, ``scan.decode`` and ``scan.wait``. At most _SPANS_MAX
+    # are admitted, parents before children; the rest are counted
+    spans: List = field(default_factory=list, repr=False)
+    spans_dropped: int = 0
+    # blocking device->host fetches on the execute path (host_sync) and
+    # the wall time the host spent blocked in them
+    host_syncs: int = 0
+    sync_wait_ms: float = 0.0
+    _spans_admitted: int = field(default=0, repr=False)
     _lock: threading.Lock = field(default_factory=threading.Lock,
                                   repr=False)
     # stack of phases currently OPEN on this profile (nested executors
@@ -187,26 +210,99 @@ class QueryProfile:
 
     @contextmanager
     def phase(self, name: str):
+        """Time a phase: a child span of that name, its duration added
+        to ``phases[name]``."""
         with self._lock:
             reentered = name in self._open
             if not reentered:
                 self._open.append(name)
         if reentered:
             # a nested executor re-opened the same phase (e.g. a scalar
-            # subquery executing inside "execute"): the outer timer
+            # subquery executing inside "execute"): the outer span
             # already covers this wall time
             yield
             return
-        from .metrics import timer as _metric_timer
-        tm = None
+        from . import tracing as tr
+        sp = None
         try:
-            with _metric_timer() as tm:  # measure-only handle
+            with tr.span(name) as sp:
                 yield
         finally:
             with self._lock:
                 if name in self._open:
                     self._open.remove(name)
-            self.add_phase(name, tm.elapsed_s * 1000.0 if tm else 0.0)
+            self.add_phase(name, sp.ms if sp is not None else 0.0)
+
+    # -- the span tree ---------------------------------------------------
+    def admit_span(self, parent_recorded: bool = True) -> bool:
+        """A span is opening under this profile: room for it? A span
+        whose parent was dropped is dropped too, so every span kept has
+        its parent."""
+        with self._lock:
+            if parent_recorded and self._spans_admitted < _SPANS_MAX:
+                self._spans_admitted += 1
+                return True
+            self.spans_dropped += 1
+            return False
+
+    def add_span(self, span) -> None:
+        with self._lock:
+            self.spans.append(span)
+
+    def _spans_named(self, name: str, under: Optional[str] = None) -> List:
+        with self._lock:
+            spans = list(self.spans)
+        named = [s for s in spans if s.name == name]
+        if under is None:
+            return named
+        by_id = {s.span_id: s for s in spans}
+
+        def below(s) -> bool:
+            parent = by_id.get(s.parent_id)
+            while parent is not None:
+                if parent.name == under:
+                    return True
+                parent = by_id.get(parent.parent_id)
+            return False
+
+        return [s for s in named if below(s)]
+
+    def span_ms(self, name: str, under: Optional[str] = None) -> float:
+        """Summed duration of the spans called ``name`` (those with an
+        ancestor called ``under``, where given)."""
+        return sum(s.ms for s in self._spans_named(name, under))
+
+    def span_count(self, name: str, under: Optional[str] = None) -> int:
+        return len(self._spans_named(name, under))
+
+    def self_ms(self, name: str) -> float:
+        """Summed self time of the spans called ``name``: a span's
+        duration minus what its children on the same thread cover (a
+        child on another thread runs beside its parent, not in it)."""
+        with self._lock:
+            spans = list(self.spans)
+        children = {}
+        for s in spans:
+            children.setdefault(s.parent_id, []).append(s)
+        total = 0.0
+        for s in spans:
+            if s.name != name:
+                continue
+            covered, at = 0, s.start_ns
+            for c in sorted((c for c in children.get(s.span_id, ())
+                             if c.thread_id == s.thread_id),
+                            key=lambda c: c.start_ns):
+                lo, hi = max(c.start_ns, at), min(c.end_ns, s.end_ns)
+                if hi > lo:
+                    covered += hi - lo
+                    at = hi
+            total += (s.end_ns - s.start_ns - covered) / 1e6
+        return total
+
+    def note_host_sync(self, ms: float) -> None:
+        with self._lock:
+            self.host_syncs += 1
+            self.sync_wait_ms += ms
 
     def is_open(self, name: str) -> bool:
         with self._lock:
@@ -509,6 +605,10 @@ class QueryProfile:
             "operators": list(self.operators),
             "tasks": list(self.tasks),
             "trace_id": self.trace_id,
+            "host_syncs": self.host_syncs,
+            "sync_wait_ms": round(self.sync_wait_ms, 3),
+            "spans": [sp.to_dict() for sp in list(self.spans)],
+            "spans_dropped": self.spans_dropped,
         }
 
     def render(self) -> str:
@@ -754,40 +854,61 @@ def profile_query(statement: str = "", session: str = "", conf=None,
         statement=(statement or "")[:_STATEMENT_MAX],
         session=session, tenant=tenant, start_time=time.time())
     from . import tracing as tr
-    profile.trace_id = tr.current_trace_id()
-    _local.profile = profile
-    FLIGHT_RECORDER.start(profile)
+    # the statement's root span: child of spark_connect:execute_plan
+    # when there is one; the profile keeps it and all beneath it
+    with tr.span("query", {"query.id": profile.query_id,
+                           "rss_mb_start": _rss_mb()},
+                 sink=profile) as root:
+        profile.trace_id = root.trace_id
+        _local.profile = profile
+        FLIGHT_RECORDER.start(profile)
+        try:
+            from . import events as _events
+            _events.emit(_events.EventType.QUERY_START,
+                         query_id=profile.query_id,
+                         trace_id=profile.trace_id,
+                         statement=profile.statement[:200],
+                         session=profile.session, tenant=profile.tenant)
+        except Exception:  # noqa: BLE001 — telemetry must never break queries
+            pass
+        try:
+            yield profile
+        except BaseException as e:
+            profile.status = "failed"
+            profile.error = f"{type(e).__name__}: {e}"[:512]
+            raise
+        else:
+            profile.status = "succeeded"
+        finally:
+            _local.profile = None
+            profile.end_time = time.time()
+            threshold = _slow_threshold_ms(conf)
+            profile.slow = bool(threshold > 0
+                                and profile.total_ms >= threshold)
+            FLIGHT_RECORDER.finish(profile)
+            root.attributes["rss_mb_end"] = _rss_mb()
+            with tr.span("finalize"):
+                _finalize(profile, threshold, root)
+
+
+def _rss_mb() -> float:
+    """This process's resident set now (one read of /proc/self/status);
+    0.0 where the platform has no such file."""
     try:
-        from . import events as _events
-        _events.emit(_events.EventType.QUERY_START,
-                     query_id=profile.query_id,
-                     trace_id=profile.trace_id,
-                     statement=profile.statement[:200],
-                     session=profile.session, tenant=profile.tenant)
-    except Exception:  # noqa: BLE001 — telemetry must never break queries
+        with open("/proc/self/status") as f:
+            for line in f:
+                if line.startswith("VmRSS:"):
+                    return int(line.split()[1]) / 1024.0
+    except (OSError, ValueError, IndexError):
         pass
-    try:
-        yield profile
-    except BaseException as e:
-        profile.status = "failed"
-        profile.error = f"{type(e).__name__}: {e}"[:512]
-        raise
-    else:
-        profile.status = "succeeded"
-    finally:
-        _local.profile = None
-        profile.end_time = time.time()
-        threshold = _slow_threshold_ms(conf)
-        profile.slow = bool(threshold > 0
-                            and profile.total_ms >= threshold)
-        FLIGHT_RECORDER.finish(profile)
-        _finalize(profile, threshold)
+    return 0.0
 
 
-def _finalize(profile: QueryProfile, threshold_ms: float) -> None:
+def _finalize(profile: QueryProfile, threshold_ms: float, root) -> None:
     """Post-completion export: registry counter, slow-query log line,
-    and an OTLP ``query`` span carrying the phase breakdown. Must never
-    raise into the query path."""
+    and the phase breakdown as attributes of the ``query`` span, which
+    the OTLP exporter receives when the span ends. Must never raise
+    into the query path."""
     try:
         _record_metric("execution.query_count", 1,
                        session=profile.session or "default")
@@ -825,7 +946,7 @@ def _finalize(profile: QueryProfile, threshold_ms: float) -> None:
         # racing in from workers after the cut are excluded on BOTH
         # sides). It still observes the profile into its baseline only
         # after classifying — an outlier must not pollute the baseline
-        # it was judged against. The OTLP span below carries the
+        # it was judged against. The query span below carries the
         # verdict.
         from .analysis import anomaly as _anomaly
         _anomaly.on_profile_complete(profile)
@@ -839,30 +960,27 @@ def _finalize(profile: QueryProfile, threshold_ms: float) -> None:
                 profile.statement[:200])
         from . import tracing as tr
         if tr._exporter() is not None:
-            attrs = {"query.id": profile.query_id,
-                     "query.status": profile.status,
-                     "query.rows_out": profile.rows_out,
-                     "query.compile.cache_hits":
-                         profile.compile_cache_hits,
-                     "query.compile.cache_misses":
-                         profile.compile_cache_misses,
-                     "query.transfer_bytes": profile.transfer_bytes,
-                     "query.spill_bytes": profile.spill_bytes,
-                     "query.runtime_filter.built": profile.rtf_built,
-                     "query.runtime_filter.rows_pruned":
-                         profile.rtf_rows_pruned,
-                     "query.adaptive.coalesced":
-                         profile.adaptive_coalesced,
-                     "query.adaptive.split": profile.adaptive_split,
-                     "query.adaptive.broadcast":
-                         profile.adaptive_broadcast,
-                     "query.adaptive.reordered":
-                         profile.adaptive_reordered,
-                     "query.plan_fingerprint": profile.plan_fingerprint,
-                     "query.retrace_count": profile.retrace_count,
-                     "query.anomaly.verdict": profile.anomaly_verdict,
-                     "query.anomaly.excess_ms":
-                         round(profile.anomaly_excess_ms, 3)}
+            attrs = root.attributes
+            attrs.update({
+                "query.status": profile.status,
+                "query.rows_out": profile.rows_out,
+                "query.compile.cache_hits": profile.compile_cache_hits,
+                "query.compile.cache_misses":
+                    profile.compile_cache_misses,
+                "query.transfer_bytes": profile.transfer_bytes,
+                "query.spill_bytes": profile.spill_bytes,
+                "query.runtime_filter.built": profile.rtf_built,
+                "query.runtime_filter.rows_pruned":
+                    profile.rtf_rows_pruned,
+                "query.adaptive.coalesced": profile.adaptive_coalesced,
+                "query.adaptive.split": profile.adaptive_split,
+                "query.adaptive.broadcast": profile.adaptive_broadcast,
+                "query.adaptive.reordered": profile.adaptive_reordered,
+                "query.plan_fingerprint": profile.plan_fingerprint,
+                "query.retrace_count": profile.retrace_count,
+                "query.anomaly.verdict": profile.anomaly_verdict,
+                "query.anomaly.excess_ms":
+                    round(profile.anomaly_excess_ms, 3)})
             if profile.cache_status or profile.cache_fragments \
                     or profile.scan_share_attached:
                 attrs["query.result_cache.status"] = \
@@ -880,15 +998,6 @@ def _finalize(profile: QueryProfile, threshold_ms: float) -> None:
                 # view and the event log cross-reference
                 attrs["query.critical_path"] = json.dumps(
                     profile.critical_path, default=str)
-            start_ns = int(profile.start_time * 1e9)
-            end_ns = int((profile.end_time or profile.start_time) * 1e9)
-            span = tr.Span(
-                trace_id=profile.trace_id or uuid.uuid4().hex,
-                span_id=uuid.uuid4().hex[:16], parent_id=None,
-                name="query", start_ns=start_ns, end_ns=end_ns,
-                attributes=attrs,
-                status_ok=profile.status == "succeeded")
-            tr._exporter().add(span)
     except Exception:  # noqa: BLE001
         pass
 
@@ -906,6 +1015,25 @@ def maybe_phase(name: str):
         return
     with profile.phase(name):
         yield
+
+
+def host_sync(site: str, tree):
+    """THE blocking device->host fetch of the execute path:
+    ``jax.device_get(tree)`` inside a ``sync`` span (attrs ``site``,
+    ``bytes``), counted on the current profile (``host_syncs``,
+    ``sync_wait_ms``). The sync-point lint (analysis/lints.py) holds
+    callers of this to the same allowlist as ``device_get`` itself."""
+    import jax
+    from . import tracing as tr
+    with tr.span("sync", {"site": site}) as sp:
+        out = jax.device_get(tree)
+        sp.attributes["bytes"] = sum(
+            int(getattr(x, "nbytes", 0))
+            for x in jax.tree_util.tree_leaves(out))
+    profile = current_profile()
+    if profile is not None:
+        profile.note_host_sync(sp.ms)
+    return out
 
 
 def note_compile_cache(hit: bool) -> None:

@@ -152,9 +152,11 @@ class SparkSession:
                 deadline_ms = float(deadline) if deadline else None
             except (TypeError, ValueError):
                 deadline_ms = None
-            ticket = admission.session_gate().acquire(
-                tenant, query_id=prof.query_id,
-                deadline_ms=deadline_ms)
+            from . import tracing as tr
+            with tr.span("admission"):
+                ticket = admission.session_gate().acquire(
+                    tenant, query_id=prof.query_id,
+                    deadline_ms=deadline_ms)
             token = set_session_timezone(
                 self.conf.get("spark.sql.session.timeZone") or "UTC")
             try:
@@ -171,10 +173,12 @@ class SparkSession:
                 from .exec import result_cache as rc
                 rc_probe = None
                 if rc.result_cache_enabled(self.conf):
-                    rc_probe = rc.probe(
-                        node, self._result_cache_session_key())
+                    with tr.span("result_cache.probe"):
+                        rc_probe = rc.probe(
+                            node, self._result_cache_session_key())
+                        cached = None if rc_probe is None \
+                            else rc.RESULT_CACHE.lookup(rc_probe)
                     if rc_probe is not None:
-                        cached = rc.RESULT_CACHE.lookup(rc_probe)
                         if cached is not None:
                             prof.note_result_cache(
                                 "hit", fragment=cached.fragment_id,

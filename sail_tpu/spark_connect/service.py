@@ -37,16 +37,19 @@ _SPARK_VERSION = "4.0.0"
 def _ipc_chunks(table, chunk_rows: int = 65536) -> List[bytes]:
     import pyarrow as pa
 
+    from .. import tracing as tr
     out = []
     n = max(table.num_rows, 0)
-    for start in range(0, max(n, 1), chunk_rows):
-        chunk = table.slice(start, chunk_rows)
-        sink = pa.BufferOutputStream()
-        with pa.ipc.new_stream(sink, table.schema) as w:
-            w.write_table(chunk)
-        out.append((chunk.num_rows, sink.getvalue().to_pybytes()))
-        if n == 0:
-            break
+    with tr.span("rpc.encode", {"rows": n}) as sp:
+        for start in range(0, max(n, 1), chunk_rows):
+            chunk = table.slice(start, chunk_rows)
+            sink = pa.BufferOutputStream()
+            with pa.ipc.new_stream(sink, table.schema) as w:
+                w.write_table(chunk)
+            out.append((chunk.num_rows, sink.getvalue().to_pybytes()))
+            if n == 0:
+                break
+        sp.attributes["bytes"] = sum(len(blob) for _rows, blob in out)
     return out
 
 
@@ -146,8 +149,10 @@ class SparkConnectServer:
         try:
             which = request.plan.WhichOneof("op_type")
             if which == "root":
-                table = session._execute_query(
-                    relation_from_proto(request.plan.root))
+                from .. import tracing as tr
+                with tr.span("rpc.decode"):
+                    plan = relation_from_proto(request.plan.root)
+                table = session._execute_query(plan)
                 for rows, blob in _ipc_chunks(table):
                     op.responses.append(mk(
                         arrow_batch=bpb.ExecutePlanResponse.ArrowBatch(

@@ -104,40 +104,47 @@ def note(operator: str, detail: str = "", **extra) -> None:
 
 @contextmanager
 def operator_span(name: str, detail: str = ""):
-    """Wrap one operator execution; nests into the thread's collector."""
-    collector = current_collector()
-    if collector is None:
-        yield None
-        return
-    m = OperatorMetrics(name, detail)
-    # children recorded during this span land in a fresh list
-    parent = collector
-    own: List[OperatorMetrics] = []
-    _local.collector = own
-    t0 = time.perf_counter()
-    span_cm = _TRACER.start_as_current_span(f"op:{name}") if _TRACER else None
-    if span_cm is not None:
-        span_cm.__enter__()
-    try:
-        yield m
-    except BaseException as e:
-        # aborted spans (e.g. a fused attempt that fell back) don't record
-        # metrics, but the OTel span must carry the exception and error
-        # status — exiting with the real exc_info makes start_as_current_span
-        # record the exception and set ERROR status; exiting with
-        # (None, None, None) silently reported failed operators as OK
+    """Wrap one operator execution: always an ``op.<name>`` span in the
+    statement's span tree (tracing.py); under EXPLAIN ANALYZE, where the
+    thread has a collector, also an ``OperatorMetrics`` that the caller
+    fills and that nests into the collector. Yields that, or None."""
+    from . import tracing as tr
+    with tr.span("op." + name):
+        collector = current_collector()
+        if collector is None:
+            yield None
+            return
+        m = OperatorMetrics(name, detail)
+        # children recorded during this span land in a fresh list
+        parent = collector
+        own: List[OperatorMetrics] = []
+        _local.collector = own
+        t0 = time.perf_counter()
+        span_cm = _TRACER.start_as_current_span(f"op:{name}") \
+            if _TRACER else None
         if span_cm is not None:
-            span_cm.__exit__(type(e), e, e.__traceback__)
-        _local.collector = parent
-        raise
-    else:
-        if span_cm is not None:
-            span_cm.__exit__(None, None, None)
-        m.elapsed_ms = (time.perf_counter() - t0) * 1000
-        m.children = own
-        parent.append(m)
-        _local.collector = parent
-        _record_metric("execution.output_row_count", m.output_rows,
-                       operator=name)
-        _record_metric("execution.elapsed_compute_time",
-                       m.elapsed_ms / 1000.0, operator=name)
+            span_cm.__enter__()
+        try:
+            yield m
+        except BaseException as e:
+            # aborted spans (e.g. a fused attempt that fell back) don't
+            # record metrics, but the OTel span must carry the exception
+            # and error status — exiting with the real exc_info makes
+            # start_as_current_span record the exception and set ERROR
+            # status; exiting with (None, None, None) silently reported
+            # failed operators as OK
+            if span_cm is not None:
+                span_cm.__exit__(type(e), e, e.__traceback__)
+            _local.collector = parent
+            raise
+        else:
+            if span_cm is not None:
+                span_cm.__exit__(None, None, None)
+            m.elapsed_ms = (time.perf_counter() - t0) * 1000
+            m.children = own
+            parent.append(m)
+            _local.collector = parent
+            _record_metric("execution.output_row_count", m.output_rows,
+                           operator=name)
+            _record_metric("execution.elapsed_compute_time",
+                           m.elapsed_ms / 1000.0, operator=name)

@@ -7,7 +7,13 @@ layers propagating trace context across RPCs and the OTLP pipeline
 so this is a from-scratch implementation:
 
 - ``span(name)``: thread-local span stack; ids follow the W3C trace
-  context format.
+  context format. THE recorder of the repository: a finished span is
+  kept by the ``QueryProfile`` whose ``query`` span it lies under
+  (profiler.py), mirrored as a ``jax.profiler.TraceAnnotation``
+  ``sail:<name>`` while a profiler session is active (so it lies on the
+  xplane's host plane, on the device trace's clock), and exported to
+  OTLP when an endpoint is configured. Times are the wall clock read
+  once, when the tree's root opens, plus monotonic offsets.
 - ``inject_context()`` / ``extract_context()``: ``traceparent`` metadata
   for gRPC calls — one cluster query yields ONE connected trace across
   driver and workers.
@@ -31,6 +37,10 @@ from typing import Dict, List, Optional, Tuple
 _local = threading.local()
 _lock = threading.Lock()
 
+#: spans finished before any profile adopted their parent (``rpc.decode``
+#: under ``spark_connect:execute_plan``) wait on the parent's context
+_ORPHANS_MAX = 8
+
 
 @dataclass
 class Span:
@@ -38,16 +48,42 @@ class Span:
     span_id: str           # 16 hex chars
     parent_id: Optional[str]
     name: str
-    start_ns: int
-    end_ns: int = 0
+    start_ns: int          # the wall clock (time.time_ns()) when the
+    end_ns: int = 0        # tree's root opened + a monotonic offset
     attributes: Dict[str, object] = field(default_factory=dict)
     status_ok: bool = True
+    thread_id: int = 0
+
+    @property
+    def ms(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e6
+
+    def to_dict(self) -> dict:
+        return {"name": self.name, "span_id": self.span_id,
+                "parent_id": self.parent_id, "trace_id": self.trace_id,
+                "start_ns": self.start_ns, "end_ns": self.end_ns,
+                "thread_id": self.thread_id, "ok": self.status_ok,
+                "attributes": dict(self.attributes)}
 
 
 @dataclass
 class SpanContext:
+    """What a child needs of its open parent: the ids that cross an RPC
+    (``trace_id``, ``span_id``) and, in-process, the ``sink`` that keeps
+    finished spans (a ``QueryProfile``), whether this span was admitted
+    under the sink's bound, and the open ``span`` itself."""
+
     trace_id: str
     span_id: str
+    sink: object = None
+    recorded: bool = True
+    span: Optional[Span] = None
+    orphans: Optional[List[Span]] = None
+    #: (time.time_ns(), time.perf_counter_ns()) read together when the
+    #: tree's first in-process span opened: every span beneath it is that
+    #: wall-clock instant plus a monotonic offset, so children lie inside
+    #: their parents whatever the wall clock does meanwhile
+    anchor: Optional[Tuple[int, int]] = None
 
 
 def _current() -> Optional[SpanContext]:
@@ -55,27 +91,82 @@ def _current() -> Optional[SpanContext]:
     return stack[-1] if stack else None
 
 
+def current_context() -> Optional[SpanContext]:
+    """The thread's open span, to hand to another thread as ``parent``."""
+    return _current()
+
+
 def current_trace_id() -> Optional[str]:
     ctx = _current()
     return ctx.trace_id if ctx else None
 
 
+def set_attribute(key: str, value) -> None:
+    """Attach an attribute to the thread's open span, if there is one."""
+    ctx = _current()
+    if ctx is not None and ctx.span is not None:
+        ctx.span.attributes[key] = value
+
+
+def _annotation(name: str, sink, span_id: str):
+    """The span on the xplane's host plane, on the device trace's own
+    clock, while a ``jax.profiler`` session is active; a flag check
+    otherwise."""
+    from jax.profiler import TraceAnnotation
+    if not TraceAnnotation.is_enabled():
+        return None
+    ann = TraceAnnotation("sail:" + name, span_id=span_id,
+                          query_id=getattr(sink, "query_id", "") or "")
+    ann.__enter__()
+    return ann
+
+
 @contextmanager
 def span(name: str, attributes: Optional[Dict] = None,
-         parent: Optional[SpanContext] = None):
+         parent: Optional[SpanContext] = None, sink=None,
+         backdate_ns: int = 0):
     """Open a span; nests under the thread's current span (or an explicit
-    remote ``parent`` extracted from RPC metadata)."""
+    ``parent``: one extracted from RPC metadata, or another thread's
+    ``current_context()``).
+
+    The one recorder: a finished span is kept by its ``sink`` (the
+    ``QueryProfile`` that opened the enclosing ``query`` span passes
+    itself; descendants inherit it), lies on the xplane as a
+    ``sail:<name>`` annotation while a profiler session is active, and
+    goes to the OTLP exporter when an endpoint is configured. ``sink``
+    also adopts the thread's open ancestors, so that the RPC span
+    around a query lands in the query's profile when it ends.
+    ``backdate_ns`` starts the span that long ago, for work known to
+    have happened only once it is over (a jit call that compiled)."""
     stack = getattr(_local, "span_stack", None)
     if stack is None:
         stack = _local.span_stack = []
     if parent is None:
         parent = stack[-1] if stack else None
+    recorded = True
+    if sink is not None:
+        for ctx in stack:
+            if ctx.sink is None:
+                ctx.sink = sink
+                for orphan in ctx.orphans or ():
+                    sink.add_span(orphan)
+                ctx.orphans = None
+    elif parent is not None and parent.sink is not None:
+        sink = parent.sink
+        recorded = sink.admit_span(parent.recorded)
     trace_id = parent.trace_id if parent else secrets.token_hex(16)
+    now = time.perf_counter_ns()
+    anchor = parent.anchor if parent is not None and parent.anchor \
+        else (time.time_ns(), now)
     s = Span(trace_id=trace_id, span_id=secrets.token_hex(8),
              parent_id=parent.span_id if parent else None,
-             name=name, start_ns=time.time_ns(),
-             attributes=dict(attributes or {}))
-    ctx = SpanContext(trace_id, s.span_id)
+             name=name,
+             start_ns=anchor[0] + (now - anchor[1]) - backdate_ns,
+             attributes=dict(attributes or {}),
+             thread_id=threading.get_ident())
+    ctx = SpanContext(trace_id, s.span_id, sink, recorded, s,
+                      anchor=anchor)
+    annotation = _annotation(name, sink, s.span_id)
     stack.append(ctx)
     try:
         yield s
@@ -84,7 +175,19 @@ def span(name: str, attributes: Optional[Dict] = None,
         raise
     finally:
         stack.pop()
-        s.end_ns = time.time_ns()
+        s.end_ns = anchor[0] + (time.perf_counter_ns() - anchor[1])
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+        if ctx.sink is not None:
+            if recorded:
+                ctx.sink.add_span(s)
+        elif parent is not None and parent.span is not None:
+            # no profile yet: the parent keeps it for the one that
+            # adopts it
+            if parent.orphans is None:
+                parent.orphans = []
+            if len(parent.orphans) < _ORPHANS_MAX:
+                parent.orphans.append(s)
         exporter = _exporter()
         if exporter is not None:
             exporter.add(s)
