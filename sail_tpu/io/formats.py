@@ -48,11 +48,18 @@ def expand_paths(paths: Sequence[str]) -> List[str]:
 
 
 def infer_schema(fmt: str, paths: Sequence[str], options: Dict[str, str]) -> dt.StructType:
-    if fmt.lower() == "delta":
+    """Column names and types of the files at ``paths``; the first file
+    decides. A format that carries its schema is asked for it (Parquet:
+    the footer; Delta, Iceberg: the table's metadata). csv, json, text,
+    binaryfile, avro and the Arrow IPC formats have their types from the
+    decoded data, and a prefix can infer differently from the whole
+    file, so their first file is decoded."""
+    fmt = fmt.lower()
+    if fmt == "delta":
         from ..lakehouse.delta import DeltaTable
         return DeltaTable(paths[0]).snapshot(
             *_delta_travel(options)).schema
-    if fmt.lower() == "iceberg":
+    if fmt == "iceberg":
         from ..lakehouse.iceberg import IcebergTable
         opts = {k.lower(): v for k, v in options.items()}
         return IcebergTable(
@@ -61,16 +68,34 @@ def infer_schema(fmt: str, paths: Sequence[str], options: Dict[str, str]) -> dt.
     files = expand_paths(paths)
     if not files:
         raise FileNotFoundError(f"no files found for {paths}")
-    table = read_table(fmt, files[:1], options, limit=1000)
     from .. import tracing as tr
     tr.set_attribute("files", len(files))
-    try:  # the read decodes the whole first file, whatever the limit
-        tr.set_attribute("bytes_read", os.path.getsize(files[0]))
-    except OSError:
-        pass
+    if fmt == "parquet":
+        schema, footer_bytes = _parquet_footer_schema(files[0], options)
+        tr.set_attribute("schema_source", "footer")
+        tr.set_attribute("bytes_read", footer_bytes)
+    else:
+        schema = read_table(fmt, files[:1], options, limit=1000).schema
+        tr.set_attribute("schema_source", "data")
+        try:
+            tr.set_attribute("bytes_read", os.path.getsize(files[0]))
+        except OSError:
+            pass
     return dt.StructType(tuple(
-        dt.StructField(n, arrow_type_to_spec(c.type), True)
-        for n, c in zip(table.column_names, table.columns)))
+        dt.StructField(f.name, arrow_type_to_spec(f.type), True)
+        for f in schema))
+
+
+def _parquet_footer_schema(path: str, options: Dict[str, str]
+                           ) -> Tuple[pa.Schema, int]:
+    """→ (Arrow schema, bytes of footer metadata) of one Parquet file,
+    local or remote, with no column decoded. ``schema_arrow`` is the
+    reader's own schema (a stored ``ARROW:schema`` honoured), so it
+    equals ``pq.read_table(path).schema``."""
+    from .object_store import resolve_filesystem
+    fsys, rel = resolve_filesystem(path, options)
+    with pq.ParquetFile(rel, filesystem=fsys) as pf:
+        return pf.schema_arrow, pf.metadata.serialized_size
 
 
 def iso_to_ms(ts: str) -> int:
@@ -161,6 +186,10 @@ def read_table(fmt: str, paths: Sequence[str], options: Dict[str, str],
                columns: Optional[Sequence[str]] = None,
                limit: Optional[int] = None,
                filter_expr=None) -> pa.Table:
+    """Decode ``paths`` to one Arrow table. ``limit`` slices the table
+    after every file has been decoded whole: it bounds what is returned,
+    not what is read (``infer_schema`` uses it for the formats whose
+    types come from the data)."""
     from .. import faults
     fmt = fmt.lower()
     faults.inject("io.read", key=fmt)

@@ -375,8 +375,10 @@ class Resolver:
                       for f in out]
             return node, Scope(fields, outer, {})
         from .. import tracing as tr
-        with tr.span("resolve.read_source",
-                     {"format": plan.format, "files": len(plan.paths)}):
+        attrs = {"format": plan.format, "files": len(plan.paths)}
+        if plan.schema:
+            attrs["schema_source"] = "declared"
+        with tr.span("resolve.read_source", attrs):
             schema = plan.schema or infer_schema(
                 plan.format, plan.paths, dict(plan.options))
         out = tuple(pn.Field(f.name, f.data_type, f.nullable) for f in schema.fields)
