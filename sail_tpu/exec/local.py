@@ -1382,11 +1382,12 @@ class LocalExecutor:
                 return spilled
             return self._sort_over(p, child)
         # pre-sort pipeline: chain + key eval + gather compile to ONE
-        # program. Out-of-core candidates (bounded by the BOTTOM batch's
-        # capacity, so no device sync decides this) materialize the
-        # chain first and keep the spill path byte-identical.
+        # program. Out-of-core candidates (decided from the BOTTOM
+        # batch's capacity and the sorted rows' widths, so no device
+        # sync decides this) materialize the chain first and keep the
+        # spill path byte-identical.
         child = self.run(node)
-        if self._sort_may_spill(p, child):
+        if self._sort_out_of_core(p, child) is not None:
             mat = self._apply_chain(chain, child, node)
             spilled = self._try_external_sort(p, mat)
             if spilled is not None:
@@ -1394,21 +1395,19 @@ class LocalExecutor:
             return self._sort_over(p, mat)
         return self._fused_sort(p, chain, child, node)
 
-    def _sort_may_spill(self, p: pn.SortExec, child: HostBatch) -> bool:
-        """Upper-bound spill check: capacity >= live rows, so a False
-        here is exact (the external sort could never engage) without
-        forcing a device sync on the hot path."""
-        from ..config import get as config_get
-        try:
-            threshold = int(config_get("execution.sort_spill_rows",
-                                       8_000_000))
-        except (TypeError, ValueError):
-            threshold = 8_000_000
-        if threshold <= 0 or not p.keys:
-            return False
-        if any(not isinstance(k.expr, rx.BoundRef) for k in p.keys):
-            return False
-        return child.device.capacity > threshold
+    def _sort_out_of_core(self, p: pn.SortExec,
+                          child: HostBatch) -> Optional[OutOfCore]:
+        """``out_of_core`` for a sort whose rows have ``p.input``'s
+        columns at ``child``'s capacity (``child`` may be the batch under
+        a Filter/Project chain still to be applied: the capacity stays).
+        Expression keys stay on the in-memory path."""
+        if not p.keys or \
+                any(not isinstance(k.expr, rx.BoundRef) for k in p.keys):
+            return None
+        return out_of_core(
+            "execution.sort_spill_rows", child.device.capacity,
+            sort_working_set(child.device.capacity,
+                             _row_bytes(p.input.schema)))
 
     def _fused_sort(self, p: pn.SortExec, chain: List[pn.PlanNode],
                     child: HostBatch, bottom: pn.PlanNode) -> HostBatch:
@@ -2734,73 +2733,76 @@ class LocalExecutor:
         spilling hash join via memory pools + temp files, application.yaml
         runtime.* — SURVEY.md §5 long-context analogue).
 
-        When the inputs exceed ``execution.join_spill_rows``, both sides
-        hash-partition on the join keys into temp parquet files; each
-        partition pair joins independently (equal keys land in the same
-        partition, so inner/left/full/semi/anti are all partition-wise
-        exact), bounding the join step's peak memory to one pair plus its
-        expansion. NULL keys hash to one partition, preserving outer/anti
-        semantics."""
-        from ..config import get as config_get
-
-        try:
-            threshold = int(config_get("execution.join_spill_rows",
-                                       8_000_000))
-        except (TypeError, ValueError):
-            threshold = 8_000_000
-        if threshold <= 0 or not p.left_keys:
+        When ``out_of_core`` says the join's working set does not fit the
+        device (or the inputs exceed an explicit
+        ``execution.join_spill_rows``), both sides hash-partition on the
+        join keys into temp parquet files; each partition pair joins
+        independently (equal keys land in the same partition, so
+        inner/left/full/semi/anti are all partition-wise exact), bounding
+        the join step's peak memory to one pair plus its expansion. NULL
+        keys hash to one partition, preserving outer/anti semantics."""
+        if not p.left_keys or p.null_aware:
             return None
         if p.join_type not in ("inner", "left", "full", "semi", "anti"):
             return None
-        if p.null_aware:
-            return None
         if getattr(self, "_in_join_spill", False):
             return None  # partition pairs run the in-memory join
-        if left.device.capacity + right.device.capacity <= threshold:
-            # capacities bound live rows: the spill could never engage —
-            # skip the per-join device round trip entirely
+        if not all(isinstance(k, rx.BoundRef)
+                   for k in (*p.left_keys, *p.right_keys)):
+            # simple column refs only (the planner rewrites casts and
+            # expressions above the scan): the host hashes key COLUMNS
             return None
-        import jax
+        out_row = _row_bytes(p.left.schema) + (
+            0 if p.join_type in ("semi", "anti")
+            else _row_bytes(p.right.schema))
+        decision = out_of_core(
+            "execution.join_spill_rows",
+            left.device.capacity + right.device.capacity,
+            join_working_set(left.device.capacity, right.device.capacity,
+                             out_row))
+        if decision is None:
+            return None
         n_left, n_right = profiler.host_sync(  # ONE round trip, not two
             "join.spill_decision",
             (jnp.sum(left.device.sel), jnp.sum(right.device.sel)))
         n_left, n_right = int(n_left), int(n_right)
-        if n_left + n_right <= threshold:
+        if decision.by_rows and n_left + n_right <= decision.rows:
             return None
+        from .. import tracing as tr
+        with tr.span("spill", {"kind": "join",
+                               "rows": n_left + n_right}) as sp:
+            out = self._partitioned_join(p, left, right, decision.rows,
+                                         n_left + n_right, sp)
+        if out is not None:
+            tr.set_attribute("spilled", True)
+        return out
 
+    def _partitioned_join(self, p: pn.JoinExec, left: HostBatch,
+                          right: HostBatch, threshold: int, n_rows: int,
+                          sp) -> Optional[HostBatch]:
+        """The out-of-core work of ``_try_partitioned_join`` under its
+        ``spill`` span ``sp``; None declines (keys the host cannot
+        hash), and the join runs on the device after all."""
         import tempfile
 
         import pyarrow as pa
         import pyarrow.compute as pc
         import pyarrow.parquet as pq
 
-        nparts = max(2, min(64, (n_left + n_right) // max(threshold // 2, 1)
-                            + 1))
+        nparts = max(2, min(64, n_rows // max(threshold // 2, 1) + 1))
         lt = ai.to_arrow(left).rename_columns(
             [f.name for f in p.left.schema])
         rt = ai.to_arrow(right).rename_columns(
             [f.name for f in p.right.schema])
 
-        def key_indices(keys):
-            """Simple column refs only; anything fancier declines the
-            spill path (the planner rewrites casts/exprs above the scan)."""
-            idx = []
-            for k in keys:
-                if isinstance(k, rx.BoundRef):
-                    idx.append(k.index)
-                else:
-                    return None
-            return idx
-
-        lidx = key_indices(p.left_keys)
-        ridx = key_indices(p.right_keys)
-        if lidx is None or ridx is None:
-            return None
+        lidx = [k.index for k in p.left_keys]
+        ridx = [k.index for k in p.right_keys]
         modes = [_spill_key_mode(lt.column(li).type, rt.column(ri).type)
                  for li, ri in zip(lidx, ridx)]
         lh = _spill_partition_ids(lt, lidx, modes, nparts)
         rh = _spill_partition_ids(rt, ridx, modes, nparts)
         if lh is None or rh is None:
+            sp.attributes["declined"] = True
             return None
 
         tmpdir = tempfile.mkdtemp(prefix="sail_join_spill_")
@@ -2819,6 +2821,7 @@ class LocalExecutor:
                 paths.append(fp)
             sides.append(paths)
         profiler.note_spill_bytes(spill_bytes)
+        sp.attributes.update(bytes=spill_bytes, partitions=nparts)
         del lt, rt
 
         from .. import telemetry as tel
@@ -2898,36 +2901,35 @@ class LocalExecutor:
         ExternalSorter via memory pools + temp files — SURVEY.md §5
         out-of-core).
 
-        When the input's live rows exceed ``execution.sort_spill_rows``,
-        the wide rows spill to memory-mapped Arrow IPC runs while the
+        Taken when ``out_of_core`` (through ``_sort_out_of_core``) says
+        the sort's working set does not fit the device, or the input's
+        live rows exceed an explicit ``execution.sort_spill_rows``: the
+        wide rows spill to memory-mapped Arrow IPC runs while the
         global permutation is computed on the host from the key columns
         alone (a small fraction of the row width). The output gathers
         straight from the memory maps, so the O(n) sort workspace — the
         permuted column copies a device lexsort would materialize — never
         touches device HBM. Spark ordering semantics: nulls_first/last per
         key, NaN sorts greater than any non-null value (after +Inf)."""
-        from ..config import get as config_get
-
-        try:
-            threshold = int(config_get("execution.sort_spill_rows",
-                                       8_000_000))
-        except (TypeError, ValueError):
-            threshold = 8_000_000
-        if threshold <= 0 or not p.keys:
+        decision = self._sort_out_of_core(p, child)
+        if decision is None:
             return None
-        for k in p.keys:
-            if not isinstance(k.expr, rx.BoundRef):
-                return None  # expression keys stay on the in-memory path
-        if child.device.capacity <= threshold:
-            # capacity bounds live rows: the spill could never engage, so
-            # skip the device round trip the exact count would cost
-            return None
-        import jax
         n = int(profiler.host_sync("sort.spill_decision",
                                    jnp.sum(child.device.sel)))
-        if n <= threshold:
+        if decision.by_rows and n <= decision.rows:
             return None
+        from .. import tracing as tr
+        with tr.span("spill", {"kind": "sort", "rows": n}) as sp:
+            out = self._external_sort(p, child, decision.rows, n, sp)
+        if out is not None:
+            tr.set_attribute("spilled", True)
+        return out
 
+    def _external_sort(self, p: pn.SortExec, child: HostBatch,
+                       threshold: int, n: int, sp) -> Optional[HostBatch]:
+        """The out-of-core work of ``_try_external_sort`` under its
+        ``spill`` span ``sp``; None declines (a key type the host sort
+        does not order), and the sort runs on the device after all."""
         import shutil
         import tempfile
 
@@ -2951,6 +2953,7 @@ class LocalExecutor:
                     or pa.types.is_boolean(t) or pa.types.is_string(t)
                     or pa.types.is_large_string(t) or pa.types.is_binary(t)
                     or pa.types.is_decimal(t) or pa.types.is_temporal(t)):
+                sp.attributes["declined"] = True
                 return None
             null_mask = col.is_null().to_numpy(zero_copy_only=False)
             # nulls_first/last is independent of the key direction: the
@@ -3015,8 +3018,9 @@ class LocalExecutor:
                     perm = perm[:p.limit]
                 paths = list(pf)
             del table
-            profiler.note_spill_bytes(
-                sum(os.path.getsize(fp) for fp in paths))
+            spill_bytes = sum(os.path.getsize(fp) for fp in paths)
+            profiler.note_spill_bytes(spill_bytes)
+            sp.attributes.update(bytes=spill_bytes, partitions=len(paths))
             tel.note("SpillSortPrefetch", f"{len(paths)} runs",
                      **pf.stats.as_extra())
 
@@ -3389,6 +3393,129 @@ class LocalExecutor:
 # ---------------------------------------------------------------------------
 # helpers
 # ---------------------------------------------------------------------------
+
+# ---------------------------------------------------------------------------
+# the out-of-core decision of a join and a sort
+# ---------------------------------------------------------------------------
+
+#: share of the device's free memory an operator's working set may
+#: take. The quarter left over is headroom for what the estimates below
+#: do not count: XLA's own scratch, fragmentation of the allocator, and
+#: the operator above, which starts while this one's output is alive
+_SPILL_FREE_SHARE = 0.75
+#: what the join phase allocates per PROBE row beside its inputs
+#: (ops/join.py probe_ranges): the packed or hashed key (8), lo, hi and
+#: cnt (3 x 4), usable (1), and the two binary searches' carried bounds
+#: (2 x 8)
+_JOIN_PROBE_ROW_BYTES = 37
+#: per BUILD row (ops/join.py build_side): the key (8), its sorted copy
+#: (8), two stable argsort passes each holding operand and result of
+#: key + index (2 x 2 x 12), the permutation (4), usable (1)
+_JOIN_BUILD_ROW_BYTES = 69
+#: per row of one stable pass of a sort (ops/sort.py sort_pass): order
+#: bits (8), their gather through the permutation (8), operand and
+#: result of the argsort (2 x 12), the permutation before and after
+#: (2 x 4); the passes of a lexicographic sort run one after another
+_SORT_PASS_ROW_BYTES = 48
+
+
+def _row_bytes(schema) -> int:
+    """Bytes a copy of one row of a batch with this plan schema takes
+    on the device at most: each column's value and a validity byte."""
+    total = 0
+    for f in schema:
+        try:
+            total += physical_jnp_dtype(f.dtype).itemsize + 1
+        except TypeError:  # no device representation: a host handle
+            total += 9
+    return total
+
+
+def join_working_set(probe_capacity: int, build_capacity: int,
+                     out_row_bytes: int) -> int:
+    """Upper bound of what an equi-join allocates on the device beside
+    its inputs, from capacities alone (no device sync): the join phase
+    over both sides, and an output of the probe's capacity whose rows
+    take ``out_row_bytes``: a jitted program copies the probe's columns
+    into its result, and the build side's payload columns come with a
+    validity byte each (the unique-build path; an expanding join sizes
+    its own output after the phase's sync). Asked of the v5e's compiler
+    for Q3's lineitem join at SF10 (a 32Mi probe of four columns, a 7Mi
+    build with two payload columns): 1.33 GB for the phase and 1.31 GB
+    for the output, against 1.75 and 1.54 here."""
+    return (probe_capacity * (_JOIN_PROBE_ROW_BYTES + out_row_bytes)
+            + build_capacity * _JOIN_BUILD_ROW_BYTES)
+
+
+def sort_working_set(capacity: int, row_bytes: int) -> int:
+    """Upper bound of what a device sort allocates beside its input: one
+    pass of the lexicographic sort at a time, and the gathered copy of
+    every column plus the selection."""
+    return capacity * (_SORT_PASS_ROW_BYTES + row_bytes + 1)
+
+
+def _device_memory_stats() -> Optional[dict]:
+    """The allocator's counters of the device the executor's arrays
+    live on; None on the CPU backend."""
+    import jax
+    return jax.devices()[0].memory_stats()
+
+
+def device_free_bytes() -> Optional[int]:
+    """``bytes_limit - bytes_in_use`` of the device; None where the
+    platform reports no memory."""
+    stats = _device_memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
+class OutOfCore(NamedTuple):
+    """A decision to leave the device. ``rows``: what one partition pair
+    of the join, or two runs of the sort, may hold. ``by_rows``: the
+    count was set explicitly, so an input whose LIVE rows stay under it
+    is kept on the device after all (one sync decides)."""
+
+    rows: int
+    by_rows: bool
+
+
+def out_of_core(rows_key: str, capacity: int,
+                working_set: int) -> Optional[OutOfCore]:
+    """THE out-of-core decision, for a join and a sort alike; None keeps
+    the operator on the device, and costs no device sync.
+
+    ``rows_key`` (``execution.join_spill_rows`` /
+    ``execution.sort_spill_rows``) set, in the configuration or as
+    ``SAIL_EXECUTION__JOIN_SPILL_ROWS``: spill above that many rows, 0
+    never. Unset, the default: spill when ``working_set`` (bytes, an
+    upper bound reckoned from capacities and column widths) exceeds
+    ``_SPILL_FREE_SHARE`` of the device's free memory; where the
+    platform reports no memory nothing spills. The decision's inputs
+    land on the open ``op.<PlanNode>`` span."""
+    from .. import tracing as tr
+    from ..config import get as config_get
+    tr.set_attribute("working_set_bytes", int(working_set))
+    tr.set_attribute("spilled", False)
+    try:
+        rows = int(config_get(rows_key))
+    except (TypeError, ValueError):
+        rows = None  # unset (or unreadable): by memory
+    if rows is not None:
+        if rows <= 0 or capacity <= rows:
+            # capacity bounds live rows: the spill could never engage
+            return None
+        return OutOfCore(rows, True)
+    free = device_free_bytes()
+    if free is None:
+        return None
+    tr.set_attribute("free_bytes", free)
+    budget = int(free * _SPILL_FREE_SHARE)
+    if working_set <= budget:
+        return None
+    # the rows whose share of the working set fits the budget
+    return OutOfCore(max(1, capacity * max(budget, 0) // working_set), False)
+
 
 def _spill_key_mode(lt_type: "pa.DataType", rt_type: "pa.DataType") -> str:
     """Hash family for one spill-join key PAIR, agreed by both sides:
