@@ -3403,15 +3403,22 @@ class LocalExecutor:
 #: do not count: XLA's own scratch, fragmentation of the allocator, and
 #: the operator above, which starts while this one's output is alive
 _SPILL_FREE_SHARE = 0.75
-#: what the join phase allocates per PROBE row beside its inputs
-#: (ops/join.py probe_ranges): the packed or hashed key (8), lo, hi and
-#: cnt (3 x 4), usable (1), and the two binary searches' carried bounds
-#: (2 x 8)
-_JOIN_PROBE_ROW_BYTES = 37
+#: what the join phase's merge allocates per row of build and probe
+#: keys sorted together (ops/join.py _merge_ranges): operand and result
+#: of the sort of key + row number (2 x 12), the running count of build
+#: rows (4), its value at the start of the key's run (4) and their
+#: difference (4); the sort back to probe order reuses the first one's
+#: space
+_JOIN_MERGE_ROW_BYTES = 36
+#: per PROBE row beside its inputs (ops/join.py probe_ranges): the
+#: packed or hashed key (8), lo and cnt (2 x 4), usable (1), and its
+#: row of the merge
+_JOIN_PROBE_ROW_BYTES = 17 + _JOIN_MERGE_ROW_BYTES
 #: per BUILD row (ops/join.py build_side): the key (8), its sorted copy
 #: (8), two stable argsort passes each holding operand and result of
-#: key + index (2 x 2 x 12), the permutation (4), usable (1)
-_JOIN_BUILD_ROW_BYTES = 69
+#: key + index (2 x 2 x 12), the permutation (4), usable (1), and its
+#: row of the merge
+_JOIN_BUILD_ROW_BYTES = 69 + _JOIN_MERGE_ROW_BYTES
 #: per row of one stable pass of a sort (ops/sort.py sort_pass): order
 #: bits (8), their gather through the permutation (8), operand and
 #: result of the argsort (2 x 12), the permutation before and after
@@ -3440,9 +3447,12 @@ def join_working_set(probe_capacity: int, build_capacity: int,
     into its result, and the build side's payload columns come with a
     validity byte each (the unique-build path; an expanding join sizes
     its own output after the phase's sync). Asked of the v5e's compiler
-    for Q3's lineitem join at SF10 (a 32Mi probe of four columns, a 7Mi
-    build with two payload columns): 1.33 GB for the phase and 1.31 GB
-    for the output, against 1.75 and 1.54 here."""
+    for Q3's two join phases at SF10 as the executor runs them: 1.28 GB
+    for the lineitem join (a 1.5Mi-row probe, the 32Mi-row lineitem as
+    the build: 0.87 GB of temporaries and 0.42 GB of results; 1.02 GB
+    with the two binary searches it had before) against 3.61 GB here,
+    and 0.29 GB for the orders join (a 0.3Mi-row probe, an 8Mi-row
+    build) against 0.90."""
     return (probe_capacity * (_JOIN_PROBE_ROW_BYTES + out_row_bytes)
             + build_capacity * _JOIN_BUILD_ROW_BYTES)
 

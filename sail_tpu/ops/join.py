@@ -1,9 +1,12 @@
-"""Equi-join kernels (sort + binary-search probe).
+"""Equi-join kernels (sorted build + merge probe).
 
 TPU-first replacement for DataFusion's HashJoinExec (SURVEY.md §2.4): the
-build side is sorted by key; probes binary-search the sorted keys
-(``jnp.searchsorted`` lowers to a vectorized search — no serialized
-scatter-probe hash table). Dynamic output size is handled in two phases:
+build side is sorted by key; the probe keys are merged into it by one
+more sort, and each probe row's match range falls out of two running
+scans over the merged order (``_merge_ranges``) — no serialized
+scatter-probe hash table, and no binary search per probe row, whose
+``log2(build)`` dependent full-width gathers cost four times the sorts on
+a v5e. Dynamic output size is handled in two phases:
 
   1. ``join_match``: static-shape match ranges per probe row, plus the total
      output row count as a device scalar — the *only* host sync point.
@@ -114,13 +117,41 @@ class MatchRanges(NamedTuple):
     usable: jnp.ndarray  # bool[pn] probe row alive with non-null key
 
 
+def _merge_ranges(sorted_keys, num_valid, pkeys) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(lo, cnt) per probe key from one ordered pass over build and probe
+    keys together: what ``searchsorted`` left and right (the latter
+    clipped at ``num_valid``) would give, without a loop of dependent
+    gathers per probe row.
+
+    The build keys (already sorted, first) and the probe keys are sorted
+    as one array, stably, so the build rows of a key precede its probe
+    rows. In that order the running count of live build rows is, at a
+    probe row, the number of build keys <= its key; the same count at
+    the first row of the key's run is the number of build keys < it. A
+    second sort on the carried row number brings both back to probe
+    order."""
+    bn, pn = sorted_keys.shape[0], pkeys.shape[0]
+    n = bn + pn
+    keys = jnp.concatenate([sorted_keys, pkeys])
+    row = jnp.arange(n, dtype=jnp.int32)
+    keys, row = jax.lax.sort((keys, row), num_keys=1, is_stable=True)
+    # dead build rows (the KEY_MAX suffix) count for nothing, so a real
+    # key equal to KEY_MAX matches only the live rows that hold it
+    live = (row < num_valid).astype(jnp.int32)
+    at_or_before = jnp.cumsum(live)
+    run_start = jnp.concatenate(
+        [jnp.ones(1, dtype=jnp.bool_), keys[1:] != keys[:-1]])
+    before = jax.lax.cummax(jnp.where(run_start, at_or_before - live, 0))
+    _, lo, cnt = jax.lax.sort((row, before, at_or_before - before),
+                              num_keys=1, is_stable=False)
+    return lo[bn:], cnt[bn:]
+
+
 def probe_ranges(bt: BuildTable, probe_key_cols: Sequence[Column], probe_sel,
                  build_key_cols: Optional[Sequence[Column]] = None) -> MatchRanges:
     pkeys, pusable, _ = _join_keys(probe_key_cols, probe_sel, seed=bt.seed)
-    lo = jnp.searchsorted(bt.sorted_keys, pkeys, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(bt.sorted_keys, pkeys, side="right").astype(jnp.int32)
-    hi = jnp.minimum(hi, bt.num_valid)  # clip off the KEY_MAX sentinel suffix
-    cnt = jnp.where(pusable, jnp.maximum(hi - lo, 0), 0).astype(jnp.int32)
+    lo, cnt = _merge_ranges(bt.sorted_keys, bt.num_valid, pkeys)
+    cnt = jnp.where(pusable, cnt, 0)
     if not bt.exact:
         # Hashed path: given an ambiguity-free build (see hash_ambiguous),
         # each hash range holds exactly one distinct true key, so verifying

@@ -171,9 +171,10 @@ def test_sort_group_by_on_packed_uint64_key(one_chip):
              s(jnp.float64), s(jnp.bool_))
 
 
-def test_sorted_build_binary_search_join(one_chip):
-    """ops/join: build side sorted on an int64 key, probes by
-    searchsorted, the expanding materialisation, the unique fast path."""
+def test_sorted_build_merge_probe_join(one_chip):
+    """ops/join: build side sorted on an int64 key, probe keys merged
+    into it by one more sort and two scans (no loop of gathers), the
+    expanding materialisation, the unique fast path."""
     bn, pn, out_cap = SORT_ROWS, SORT_ROWS, 2 * SORT_ROWS
 
     def fn(bkey, bpay, bsel, pkey, ppay, psel):

@@ -226,6 +226,120 @@ class TestJoin:
         np.testing.assert_array_equal(matched[:4], [True, False, True, True])
 
 
+def _key_col(values, nulls=None):
+    data = jnp.asarray(np.asarray(values, dtype=np.int64))
+    validity = None if nulls is None else jnp.asarray(~np.asarray(nulls))
+    return Column(data, validity, dt.LongType())
+
+
+def _probe_case(name, rng):
+    """(build key columns, build sel, probe key columns, probe sel) of
+    one shape the match ranges must hold on."""
+    def keys(n, lo, hi, width=1):
+        return [rng.integers(lo, hi, n) for _ in range(width)]
+
+    bn, pn = 48, 80
+    bsel = psel = bnull = pnull = None
+    if name == "duplicate_build":
+        bk, pk = keys(bn, 0, 12), keys(pn, -2, 14)
+    elif name == "unique_build":
+        bk, pk = [rng.permutation(bn)], keys(pn, -5, bn + 5)
+    elif name == "empty_build":
+        bk, pk = keys(0, 0, 1), keys(pn, 0, 5)
+    elif name == "every_build_row_dead":
+        bk, pk = keys(bn, 0, 12), keys(pn, 0, 12)
+        bsel = np.zeros(bn, dtype=bool)
+    elif name == "null_and_dead_rows":
+        bk, pk = keys(bn, 0, 12), keys(pn, 0, 12)
+        bsel, psel = rng.random(bn) < 0.7, rng.random(pn) < 0.7
+        bnull, pnull = rng.random(bn) < 0.2, rng.random(pn) < 0.2
+    elif name == "key_max_beside_the_sentinel":
+        # int64 -1 packs to the KEY_MAX bit pattern: live rows hold it in
+        # the sorted prefix, dead rows are overwritten with it behind them
+        bk, pk = keys(bn, -1, 3), keys(pn, -1, 3)
+        bsel = rng.random(bn) < 0.5
+    elif name == "all_keys_equal":
+        bk, pk = [np.full(bn, 7)], [np.full(pn, 7)]
+    elif name == "all_keys_equal_none_matching":
+        bk, pk = [np.full(bn, 7)], [np.full(pn, 8)]
+    elif name == "probe_larger_than_build":
+        bk, pk = keys(5, 0, 6), keys(300, 0, 6)
+    elif name == "probe_smaller_than_build":
+        bk, pk = keys(300, 0, 400), keys(5, 0, 400)
+    elif name == "one_probe_row":
+        bk, pk = keys(bn, 0, 4), keys(1, 0, 4)
+    elif name == "build_capacity_not_a_power_of_two":
+        bk, pk = keys(7 * 16, 0, 40), keys(128, 0, 44)
+        bsel = np.arange(7 * 16) < 100
+    elif name == "extreme_keys":
+        edge = np.array([np.iinfo(np.int64).min, -1, 0, 1,
+                         np.iinfo(np.int64).max])
+        bk, pk = [rng.choice(edge, bn)], [rng.choice(edge, pn)]
+        bsel = rng.random(bn) < 0.8
+    elif name == "hashed_three_columns":
+        bk, pk = keys(bn, 0, 4, width=3), keys(pn, 0, 5, width=3)
+        bsel, psel = rng.random(bn) < 0.8, rng.random(pn) < 0.8
+        pnull = rng.random(pn) < 0.1
+    else:
+        raise AssertionError(name)
+    bn, pn = len(bk[0]), len(pk[0])
+    bsel = np.ones(bn, dtype=bool) if bsel is None else bsel
+    psel = np.ones(pn, dtype=bool) if psel is None else psel
+    return ([_key_col(k, bnull) for k in bk], jnp.asarray(bsel),
+            [_key_col(k, pnull) for k in pk], jnp.asarray(psel))
+
+
+PROBE_CASES = [
+    "duplicate_build", "unique_build", "empty_build", "every_build_row_dead",
+    "null_and_dead_rows", "key_max_beside_the_sentinel", "all_keys_equal",
+    "all_keys_equal_none_matching", "probe_larger_than_build",
+    "probe_smaller_than_build", "one_probe_row",
+    "build_capacity_not_a_power_of_two", "extreme_keys",
+    "hashed_three_columns",
+]
+
+
+@pytest.mark.parametrize("seed", [11, 2_900_000_029])
+@pytest.mark.parametrize("case", PROBE_CASES)
+def test_probe_ranges_against_numpy_searchsorted(case, seed):
+    """``cnt`` is searchsorted right (clipped at ``num_valid``) minus
+    left over the sorted build keys for every usable probe row and 0 for
+    the others; ``lo`` is searchsorted left wherever ``cnt > 0``. On the
+    hashed path a range also has to hold the probe row's true key."""
+    bcols, bsel, pcols, psel = _probe_case(case, np.random.default_rng(seed))
+    bt = joinops.build_side(bcols, bsel)
+    assert bt.exact == (len(bcols) == 1)
+    if not bt.exact:
+        assert not bool(joinops.hash_ambiguous(bt, bcols))
+    r = joinops.probe_ranges(bt, pcols, psel,
+                             build_key_cols=None if bt.exact else bcols)
+    pkeys, pusable, _ = joinops._join_keys(pcols, psel, seed=bt.seed)
+    sorted_keys, pkeys = np.asarray(bt.sorted_keys), np.asarray(pkeys)
+    pusable, num_valid = np.asarray(pusable), int(bt.num_valid)
+    lo = np.searchsorted(sorted_keys, pkeys, side="left")
+    hi = np.minimum(np.searchsorted(sorted_keys, pkeys, side="right"),
+                    num_valid)
+    cnt = np.where(pusable, np.maximum(hi - lo, 0), 0)
+    if not bt.exact:
+        # a probe key absent from the build can share a hash with a build
+        # key only by accident; hold the count to the true keys instead
+        build = np.stack([np.asarray(c.data) for c in bcols], axis=1)
+        build = build[np.asarray(bsel)]
+        probe = np.stack([np.asarray(c.data) for c in pcols], axis=1)
+        true_cnt = (probe[:, None, :] == build[None, :, :]).all(-1).sum(1)
+        cnt = np.where(pusable, true_cnt, 0)
+    got_lo, got_cnt = np.asarray(r.lo), np.asarray(r.cnt)
+    assert got_lo.dtype == np.int32 and got_cnt.dtype == np.int32
+    np.testing.assert_array_equal(np.asarray(r.usable), pusable)
+    np.testing.assert_array_equal(got_cnt, cnt)
+    np.testing.assert_array_equal(got_lo[cnt > 0], lo[cnt > 0])
+    if not bt.exact and (cnt > 0).any():
+        first = np.asarray(bt.perm)[got_lo[cnt > 0]]
+        for bc, pc in zip(bcols, pcols):
+            np.testing.assert_array_equal(np.asarray(bc.data)[first],
+                                          np.asarray(pc.data)[cnt > 0])
+
+
 class TestReviewRegressions:
     """Regressions for the round-1 code-review findings."""
 

@@ -138,7 +138,38 @@ def test_q3_at_sf10_does_not_fit_1_gb(monkeypatch, key, capacity,
 
 def test_the_lineitem_join_is_reckoned_at_gigabytes_not_rows():
     key, _capacity, working_set = Q3_SF10[0]
-    assert key == JOIN and 4e9 < working_set < 10e9
+    assert key == JOIN and 4e9 < working_set < 10.5e9
+
+
+#: Q3's two joins as the executor runs them on the chip at SF10: probe
+#: and build capacity, the bytes a copied output row takes, and what the
+#: v5e's compiler counts for the phase (temporaries + results; PERF.md §5)
+LINEITEM_JOIN = (3 * MI // 2, 32 * MI,
+                 LINEITEM_ROW + ORDERS_ROW + CUSTOMER_ROW,
+                 865_510_400 + 416_815_104)
+ORDERS_JOIN = (327_680, 8 * MI, ORDERS_ROW + CUSTOMER_ROW,
+               183_060_480 + 103_618_560)
+
+
+@pytest.mark.parametrize("probe,build,out_row,_counted",
+                         [LINEITEM_JOIN, ORDERS_JOIN])
+def test_q3s_joins_as_they_run_on_the_chip_stay_in_hbm(monkeypatch, probe,
+                                                       build, out_row,
+                                                       _counted):
+    # 1.32 GB of resident columns between statements (PERF.md §4)
+    _fake_memory(monkeypatch, 16.9e9, 1.32e9)
+    working_set = lm.join_working_set(probe, build, out_row)
+    assert lm.out_of_core(JOIN, probe + build, working_set) is None
+
+
+@pytest.mark.parametrize("probe,build,_out_row,counted",
+                         [LINEITEM_JOIN, ORDERS_JOIN])
+def test_the_join_phase_bound_bounds_the_compilers_count(probe, build,
+                                                         _out_row, counted):
+    """The merge probe (ops/join.py _merge_ranges) sorts build and probe
+    keys together: each side's rows pay for a row of the merge, and the
+    phase alone (no output row) stays above what the compiler counts."""
+    assert lm.join_working_set(probe, build, 0) > counted
 
 
 def test_no_memory_reported_means_nothing_spills(monkeypatch):
