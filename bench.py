@@ -20,7 +20,6 @@ import json
 import os
 import sys
 import time
-from typing import Optional
 
 import numpy as np
 
@@ -140,113 +139,6 @@ def _run_q1(spark, sf: float):
     steady_profile = _profile_summary()
     profile = {"warm": warm_profile, "steady": steady_profile}
     return min(times), table.num_rows, scanned, profile
-
-
-_COLD_PROBE_SCRIPT = r"""
-import json, os, sys, time
-qn = int(sys.argv[1]); sf = float(sys.argv[2])
-from sail_tpu import SparkSession
-from sail_tpu.benchmarks.tpch_data import register_tpch
-from sail_tpu.benchmarks.tpch_queries import QUERIES
-spark = SparkSession.builder.getOrCreate()
-register_tpch(spark, sf=sf)
-sql = QUERIES[qn]
-t0 = time.perf_counter()
-spark.sql(sql).toArrow()
-cold = time.perf_counter() - t0
-from sail_tpu import profiler
-p = profiler.last_profile()
-warms = []
-for _ in range(2):
-    t0 = time.perf_counter()
-    spark.sql(sql).toArrow()
-    warms.append(time.perf_counter() - t0)
-print("COLDPROBE " + json.dumps({
-    "cold_s": round(cold, 4), "warm_s": round(min(warms), 4),
-    "persistent_hits": p.persistent_hits,
-    "persistent_misses": p.persistent_misses,
-    "compile_ms": round(p.compile_ms, 2),
-}))
-"""
-
-
-def _cold_probe(qn: int, sf: float, cache_dir: str,
-                timeout_s: float = 180.0):
-    """One fresh-subprocess execution of TPC-H q<qn>: the first run is
-    a true cold start (new process, empty in-memory caches), the next
-    two are the process's own warm runs. ``cache_dir`` = "" disables
-    the persistent program cache for the child."""
-    import subprocess
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env.pop("SAIL_BENCH_DISABLE_PCACHE", None)
-    if cache_dir:
-        env["SAIL_COMPILE_CACHE__DIR"] = cache_dir
-        env["SAIL_COMPILE_CACHE__ENABLED"] = "1"
-    else:
-        env["SAIL_COMPILE_CACHE__ENABLED"] = "0"
-        env.pop("SAIL_COMPILE_CACHE__DIR", None)
-    r = subprocess.run(
-        [sys.executable, "-c", _COLD_PROBE_SCRIPT, str(qn), str(sf)],
-        capture_output=True, text=True, timeout=timeout_s, env=env)
-    for line in (r.stdout or "").splitlines():
-        if line.startswith("COLDPROBE "):
-            return json.loads(line[len("COLDPROBE "):])
-    tail = (r.stderr or r.stdout or "").strip().splitlines()[-3:]
-    raise RuntimeError(f"cold probe q{qn} rc={r.returncode}: "
-                       + " | ".join(tail))
-
-
-def _run_cold_warm(cache_dir: str, budget_s: float,
-                   sf: Optional[float] = None) -> dict:
-    """Cold-start artifact for the headline queries (q1/q5/q18): per
-    query, a fresh-subprocess run against the POPULATED persistent
-    program cache (``cold_s``) next to the same process's steady-state
-    time (``warm_s``), plus an uncached-cold control. Acceptance
-    target: cold/warm → ~1.2x with the cache populated — the residual
-    gap is first-scan decode/upload + backend init (real data loading,
-    not compilation: ``cold_compile_ms`` records 0 on a full hit), so
-    the ratio converges toward 1 as SF grows and compute dominates.
-    ``SAIL_BENCH_COLD_SF`` overrides the scale (default 0.2)."""
-    if sf is None:
-        try:
-            sf = float(os.environ.get("SAIL_BENCH_COLD_SF", "0.2"))
-        except ValueError:
-            sf = 0.2
-    out = {"sf": sf, "cache_dir_set": bool(cache_dir),
-           "queries": {}}
-    t_start = time.perf_counter()
-    for qn in (1, 5, 18):
-        if time.perf_counter() - t_start > budget_s:
-            out["queries"][f"q{qn}"] = "skipped: budget"
-            continue
-        try:
-            rec = {}
-            if cache_dir:
-                # pass 1: empty/unseen cache — the uncached control
-                # AND the store-populating run
-                uncached = _cold_probe(qn, sf, cache_dir="")
-                rec["cold_uncached_s"] = uncached["cold_s"]
-                populate = _cold_probe(qn, sf, cache_dir=cache_dir)
-                # pass 2: fresh process against the populated store
-                probe = _cold_probe(qn, sf, cache_dir=cache_dir)
-                rec["populate_persistent_misses"] = \
-                    populate["persistent_misses"]
-            else:
-                probe = _cold_probe(qn, sf, cache_dir="")
-            rec["cold"] = probe["cold_s"]
-            rec["warm"] = probe["warm_s"]
-            rec["ratio"] = round(probe["cold_s"]
-                                 / max(probe["warm_s"], 1e-9), 3)
-            rec["persistent_hits"] = probe["persistent_hits"]
-            rec["persistent_misses"] = probe["persistent_misses"]
-            rec["cold_compile_ms"] = probe["compile_ms"]
-            out["queries"][f"q{qn}"] = rec
-        except Exception as e:  # noqa: BLE001 — a failed probe is data
-            out["queries"][f"q{qn}"] = f"error: {type(e).__name__}: {e}"
-        print(f"bench: cold/warm q{qn} = {out['queries'][f'q{qn}']}",
-              file=sys.stderr, flush=True)
-    return out
 
 
 def _run_suite(spark, sf: float, budget_s: float = 420.0):
@@ -1807,18 +1699,6 @@ def main():
     # /metrics and gets scraped every 2s by a background thread (a
     # stand-in Prometheus), so comparing the two artifacts measures
     # the telemetry plane's overhead (acceptance: ≤ 2% on q1)
-    # A/B knob: SAIL_BENCH_DISABLE_PCACHE=1 turns the persistent
-    # compiled-program cache off for the whole run (executors and
-    # cluster workers read the app-config/env layer). The store lives
-    # where SAIL_COMPILE_CACHE__DIR says and is off without it.
-    disable_pcache = _env_on("SAIL_BENCH_DISABLE_PCACHE")
-    if disable_pcache:
-        os.environ["SAIL_COMPILE_CACHE__ENABLED"] = "0"
-        pcache_dir = ""
-    else:
-        pcache_dir = os.environ.get("SAIL_COMPILE_CACHE__DIR", "")
-    from sail_tpu.exec import pcache as _pcache
-    _pcache.reload()
     disable_obs = _env_on("SAIL_BENCH_DISABLE_OBS_SERVER")
     obs_info = {"enabled": not disable_obs}
     obs_stop = None
@@ -1869,7 +1749,6 @@ def main():
         "adaptive": "disabled" if disable_aqe else "enabled",
         "anomaly": "disabled" if disable_anomaly else "enabled",
         "events": "disabled" if disable_events else "enabled",
-        "pcache": "enabled" if pcache_dir else "disabled",
         "observability": obs_info,
     }
     # the 22-query and ClickBench artifacts always record, inside the
@@ -1896,17 +1775,6 @@ def main():
                     spark, 100_000, remaining * 0.8)
         except Exception as e:  # noqa: BLE001
             result["clickbench_error"] = f"{type(e).__name__}: {e}"
-    # cold-start artifact: fresh-subprocess q1/q5/q18 against the
-    # populated persistent program cache, next to the same process's
-    # warm steady state (SAIL_BENCH_SKIP_COLD=1 skips)
-    remaining = total_budget - (time.perf_counter() - t_bench_start)
-    if remaining > 120 and not _env_on("SAIL_BENCH_SKIP_COLD"):
-        try:
-            result["cold_start"] = _run_cold_warm(
-                "" if disable_pcache else pcache_dir,
-                budget_s=remaining * 0.5)
-        except Exception as e:  # noqa: BLE001
-            result["cold_start_error"] = f"{type(e).__name__}: {e}"
     # shuffle data-plane artifact: cluster-path q5/q18/q21 wire/spill
     # bytes + fetch overlap (SAIL_BENCH_SKIP_SHUFFLE=1 skips)
     remaining = total_budget - (time.perf_counter() - t_bench_start)

@@ -219,7 +219,6 @@ def _profile_counts(session, seen: set) -> dict:
         "compile_s": sum(p.compile_ms for p in profiles) / 1000.0,
         "op_cache_hits": sum(p.compile_cache_hits for p in profiles),
         "op_cache_misses": sum(p.compile_cache_misses for p in profiles),
-        "aot_store_hits": sum(p.persistent_hits for p in profiles),
         "fusion_fallbacks": sum(p.fusion_fallbacks for p in profiles),
         "result_cache": [p.cache_status for p in profiles
                          if p.cache_status],

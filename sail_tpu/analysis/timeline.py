@@ -194,11 +194,6 @@ def _compiles_in(evs: List[dict], t0: float, t1: float,
     for e in evs:
         if e.get("type") != "compile" or e.get("ts") is None:
             continue
-        if e.get("source") == "persistent":
-            # a persistent-cache load bound a stored executable:
-            # nothing compiled, and the profile's compile phase agrees
-            # (note_compile_loaded charges no compile time)
-            continue
         stamped = e.get("task")
         if stamped is not None:
             if task is None or stamped != task:

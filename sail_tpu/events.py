@@ -61,16 +61,13 @@ RESERVED_KEYS = ("v", "seq", "ts", "type", "query_id", "trace_id",
 #: The ``slo-taxonomy`` lint enforces that every cause literal emitted
 #: in code appears here and vice versa. ``first-ever`` is the benign
 #: cold compile of a never-seen program; everything else is a RETRACE —
-#: a program the process (or pcache) had and lost, or a shape drift.
+#: a program the process had and lost, or a shape drift.
 RETRACE_CAUSES: Tuple[str, ...] = (
     "first-ever",          # fingerprint never compiled in this process
     "new-aval-signature",  # genuinely new arg structure/dtype/shape
     "capacity-bucket",     # same structure, only a leading (padded
                            # capacity) dim changed — round_capacity churn
     "eviction",            # in-memory op-cache evicted the program
-    "pcache-eviction",     # persistent store had it and lost it
-    "pcache-poison",       # persistent entry poisoned (undeserializable)
-    "env-skew",            # persistent entry refused: env fingerprint skew
 )
 
 #: ranked root-cause verdict categories the anomaly classifier
@@ -96,14 +93,13 @@ EVENT_TYPES: Dict[str, Tuple[str, ...]] = {
     "query_start": ("statement", "session", "tenant"),
     "query_end": ("status", "rows_out", "total_ms", "fingerprint",
                   "spill_bytes", "cache_status"),
-    # a stage program was bound: source=trace is a compiled-operator
-    # cache miss (JIT wall time in ms), source=persistent a stored AOT
-    # executable loaded from the cross-process cache (load wall time)
+    # a stage program was bound: a compiled-operator cache miss (JIT
+    # wall time in ms); source is always ``trace``
     "compile": ("key", "ms", "source"),
     # a compile miss attributed to a typed cause (exec/retrace.py):
     # ``fp`` is the program fingerprint the retrace ledger keys on,
     # ``cause`` ∈ RETRACE_CAUSES, ``ms`` the compile wall time,
-    # ``site`` the decision site (memory | pcache)
+    # ``site`` the decision site (memory)
     "retrace": ("key", "fp", "cause", "ms", "site"),
     # per-stage backend routing decision (exec/router.py): backend in
     # native | xla | mesh; stage -1 = the plan-level mesh-vs-local

@@ -535,10 +535,10 @@ class WorkerActor(Actor):
         # continuous streaming: resident (long-lived) stage tasks and
         # their sequenced, credit-bounded input channels
         self.continuous = cont.ContinuousWorker(self)
-        # background-prewarm the persistent program store's working set
-        # before first traffic (idempotent per process)
+        # this worker's programs land in jax's persistent compilation
+        # cache, placed before its first compile (idempotent per process)
         from . import pcache
-        pcache.start_prewarm()
+        pcache.place_jax_cache()
 
     # -- rpc service -----------------------------------------------------
     def _service(self):
@@ -3060,12 +3060,6 @@ class LocalCluster:
         ``task_slots`` default from ``cluster.worker_initial_count`` /
         ``cluster.worker_task_slots``."""
         faults.reload()  # pick up SAIL_FAULTS set after module import
-        # workers run LocalExecutor in-process, so re-reading
-        # compile_cache.* here makes every worker share the store a
-        # test/bench just configured through SAIL_COMPILE_CACHE__* env
-        # (process workers inherit it through their environment)
-        from . import pcache
-        pcache.reload()
         from ..config import get as config_get
         if num_workers is None:
             num_workers = _conf_int(

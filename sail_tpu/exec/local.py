@@ -363,10 +363,8 @@ def _compile_timed(fn, key, fused=False):
     Detection: jax's jitted callables expose ``_cache_size()`` — the
     number of compiled signatures resident in the jit cache. A call
     after which it GREW compiled; anything else ran a bound executable.
-    That sees every beyond-first-call retrace (new aval signature,
-    capacity-bucket churn) the old first-call-only timing was blind to.
-    When the introspection hook is absent, only the first call is timed
-    (the pre-forensics behavior). ``fused`` marks whole-stage programs:
+    That sees every retrace (new aval signature, capacity-bucket
+    churn), not the first call only. ``fused`` marks whole-stage programs:
     their compile time additionally rides
     ``execution.fusion.compile_time``.
 
@@ -379,8 +377,7 @@ def _compile_timed(fn, key, fused=False):
     from .. import tracing as tr
     from . import pcache, retrace
 
-    cache_size = getattr(fn, "_cache_size", None)
-    pending = [True]
+    cache_size = fn._cache_size
     name = pcache.program_name(key)
 
     def _charge(elapsed_s: float, args) -> None:
@@ -403,24 +400,11 @@ def _compile_timed(fn, key, fused=False):
 
     def wrapper(*args, **kwargs):
         with tr.span("dispatch", {"program": name}):
-            first = bool(pending)
-            if cache_size is None:
-                if not first:
-                    return fn(*args, **kwargs)
-                del pending[:]
-                t0 = _time.perf_counter()
-                out = fn(*args, **kwargs)
-                _charge(_time.perf_counter() - t0, args)
-                return out
             n0 = cache_size()
             t0 = _time.perf_counter()
             out = fn(*args, **kwargs)
             if cache_size() > n0:
-                if first:
-                    del pending[:]
                 _charge(_time.perf_counter() - t0, args)
-            elif first:
-                del pending[:]
             return out
 
     return wrapper
@@ -499,8 +483,6 @@ class LocalExecutor:
         # concurrent-scan sharing (enabled, wait_timeout_s), resolved
         # once per executor (io/prefetch.scan_share_conf)
         self._scan_share_conf: Optional[Tuple[bool, float]] = None
-        # persistent compiled-program cache gate (exec/pcache.py)
-        self._pcache: Optional[bool] = None
         # per-stage backend routing decisions of the current plan
         # (exec/router.py): stage sid -> Decision, plus the node->sid
         # map the decisions were made under
@@ -663,19 +645,6 @@ class LocalExecutor:
         except TypeError:
             return None
 
-    def _pcache_on(self) -> bool:
-        """Persistent compiled-program cache gate, resolved once per
-        executor: ``spark.sail.compileCache.enabled`` (session conf)
-        over the process-wide ``compile_cache.{enabled,dir}`` (a store
-        only exists when a directory is configured)."""
-        if self._pcache is None:
-            from ..config import truthy_value
-            from . import pcache
-            session = self.config.get("spark.sail.compileCache.enabled")
-            self._pcache = pcache.enabled() and \
-                (session is None or truthy_value(session))
-        return self._pcache
-
     def _jitted(self, key, dict_objs: Tuple, builder, fused=False):
         """Returns (fn, aux) where fn is jit-compiled and cached when the
         key is hashable, else built fresh and run eagerly.
@@ -687,16 +656,11 @@ class LocalExecutor:
         as ``execution.compile.compile_time`` (and, for whole-stage
         fused programs, ``execution.fusion.compile_time``).
 
-        With the persistent cache enabled (``compile_cache.*``), an
-        in-memory miss consults the cross-process AOT store BEFORE
-        tracing (``exec/pcache.py``): a persistent hit deserializes the
-        stored executable (no trace, no XLA compile), a persistent miss
-        AOT-compiles and stores. Builders routed here must bake only
-        key-covered structure, dictionary-derived tables, and keyed
-        subquery values into their closures — that is the persistence
-        contract the entry digest verifies."""
+        The program is ``jax.jit`` of the builder's function under its
+        stable name (``pcache.program_name``): across processes it is
+        JAX's persistent compilation cache that answers the compile
+        (``pcache.place_jax_cache``), keyed by that module."""
         import jax
-
 
         if key is None:
             # unhashable plan key: uncached eager build — still a miss
@@ -711,13 +675,6 @@ class LocalExecutor:
             # the XLA module, the dispatch span and the device trace's
             # operations all carry this name (pcache.program_name)
             fn = pcache.named(fn, pcache.program_name(key))
-            if self._pcache_on():
-                site = key[0] if isinstance(key, tuple) and key \
-                    and isinstance(key[0], str) else "op"
-                wrapped = pcache.wrap(fn, key, dict_objs, fused=fused,
-                                      site=site)
-                if wrapped is not None:
-                    return wrapped, aux
             return _compile_timed(jax.jit(fn), key, fused=fused), aux
 
         missed: list = []

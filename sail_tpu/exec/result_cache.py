@@ -1,8 +1,8 @@
 """Result + materialized-fragment cache and continuously-maintained views.
 
 The serving workload this targets is thousands of near-identical
-dashboard queries over slowly-changing tables: with the AOT program
-cache (pcache) hot, first-scan decode/upload dominates cold latency.
+dashboard queries over slowly-changing tables: with the compile
+caches hot, first-scan decode/upload dominates cold latency.
 Three reuse tiers sit above the scan path:
 
 - **result tier** (``ResultCache``): whole-query results keyed by
@@ -13,9 +13,9 @@ Three reuse tiers sit above the scan path:
   mesh and cluster paths alike).
 - **fragment tier** (``FragmentCache``): decoded, device-resident scan
   batches — the successor of exec/local.py's ``_SCAN_CACHE`` — with
-  byte-budgeted, cost-weighted eviction mirroring pcache's
-  compile-time-weighted scheme (evict ascending (decode cost, last
-  access): cheapest-to-rebuild, coldest first). Fragment stores feed
+  byte-budgeted, cost-weighted eviction (evict ascending (decode
+  cost, last access): cheapest-to-rebuild, coldest first). Fragment
+  stores feed
   ``join_reorder.note_observed_rows`` so AQE/join ordering treat cached
   fragments as grounded, observed-exact inputs.
 - **view tier** (``MaterializedViewManager``): ``CACHE MATERIALIZED``
@@ -243,7 +243,7 @@ class _ResultEntry:
 class ResultCache:
     """Whole-query results keyed by ``CacheProbe.key``. Byte-budgeted
     (``cache.result.max_mb``); eviction ascending (build cost, last
-    access) — the pcache compile-time-weighted precedent."""
+    access)."""
 
     tier = "result"
 

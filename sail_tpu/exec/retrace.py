@@ -4,10 +4,9 @@ ROADMAP item 2's question — "retraces-per-minute, by cause" — needs
 every trace+compile the process pays to say WHY it happened. Following
 Flare's thesis that compiled-program churn is the serving tail's
 dominant cost (arXiv:1703.08219), this module keeps a bounded
-per-program-fingerprint ledger fed from the two compile decision sites
-(``exec/local.py:_compile_timed`` for the plain-jit path,
-``exec/pcache.py:PersistentProgram._bind`` for the persistent store)
-and classifies each miss into one of :data:`events.RETRACE_CAUSES`:
+per-program-fingerprint ledger fed from the one compile decision site
+(``exec/local.py:_compile_timed``) and classifies each miss into one of
+:data:`events.RETRACE_CAUSES`:
 
 - ``first-ever`` — this process never compiled the program fingerprint
   (the benign cold compile; counted so rates stay honest, but EXPLAIN
@@ -18,9 +17,7 @@ and classifies each miss into one of :data:`events.RETRACE_CAUSES`:
   except in leading (padded row-capacity) dimensions: the
   ``round_capacity`` churn item 2 blames for the continuous-join p99;
 - ``eviction`` — this exact signature compiled before in-process, so
-  the in-memory operator cache (or jit cache it anchored) dropped it;
-- ``pcache-eviction`` / ``pcache-poison`` / ``env-skew`` — the
-  persistent store had (or refused) the entry, by load reason.
+  the in-memory operator cache (or jit cache it anchored) dropped it.
 
 Every attribution fans out to the flight recorder (``retrace`` event),
 the metric plane (``execution.compile.retrace_count{cause}``), and the
@@ -48,7 +45,8 @@ def program_fingerprint(key) -> str:
     """Stable (within-process) identity of one compiled program: the
     structural cache key's repr, hashed. Identity-bearing reprs
     (" at 0x") are fine here — the ledger is process-local; only the
-    pcache digest needs cross-process stability."""
+    program's NAME (``pcache.program_name``) must hold across
+    processes."""
     return hashlib.sha256(repr(key).encode()).hexdigest()[:16]
 
 
@@ -92,19 +90,16 @@ class _Program:
 
 
 class RetraceLedger:
-    """Bounded LRU of per-program compile history + the process's
-    known-pcache-digest set. All mutation under one lock — compile
-    sites run on worker threads concurrently."""
+    """Bounded LRU of per-program compile history. All mutation under
+    one lock — compile sites run on worker threads concurrently."""
 
     MAX_PROGRAMS = 512
     MAX_RECENT = 1024
-    MAX_DIGESTS = 4096
     _KEY_CHARS = 160   # key reprs can be whole plan structures
 
     def __init__(self):
         self._lock = threading.Lock()
         self._programs: "OrderedDict[str, _Program]" = OrderedDict()
-        self._digests: set = set()
         self._recent: deque = deque(maxlen=self.MAX_RECENT)
         self._totals: Dict[str, int] = {}
 
@@ -120,36 +115,6 @@ class RetraceLedger:
         else:
             self._programs.move_to_end(fp)
         return e
-
-    def note_digest(self, digest: Optional[str]) -> None:
-        """A pcache digest this process stored or loaded — its later
-        absence from the store is a pcache eviction, not a cold miss."""
-        if not digest:
-            return
-        with self._lock:
-            if len(self._digests) >= self.MAX_DIGESTS:
-                self._digests.clear()
-            self._digests.add(digest)
-
-    def digest_known(self, digest: Optional[str]) -> bool:
-        if not digest:
-            return False
-        with self._lock:
-            return digest in self._digests
-
-    def note_bound(self, key, sig) -> None:
-        """A program bound WITHOUT compiling (pcache load hit): remember
-        the signature so a later recompile of it reads as eviction, not
-        first-ever."""
-        fp = program_fingerprint(key)
-        sig_repr = repr(sig) if sig is not None else None
-        inv = sig_invariant(sig)
-        with self._lock:
-            e = self._entry(fp, repr(key))
-            if sig_repr is not None:
-                e.sigs.add(sig_repr)
-            if inv is not None:
-                e.invariants.add(inv)
 
     def note_eviction(self, key) -> None:
         """The in-memory operator cache dropped this key's entry
@@ -169,7 +134,7 @@ class RetraceLedger:
         inv = sig_invariant(sig)
         with self._lock:
             e = self._programs.get(fp)
-            if e is None or e.compiles == 0 and not e.sigs:
+            if e is None:
                 return "first-ever"
             if sig_repr is not None and sig_repr in e.sigs:
                 return "eviction"
@@ -177,34 +142,13 @@ class RetraceLedger:
                 return "capacity-bucket"
             return "new-aval-signature"
 
-    def classify_pcache(self, fp: str, sig, reason: Optional[str],
-                        digest: Optional[str]) -> str:
-        """Attribute a persistent-store miss: the load reason wins when
-        it names the store itself; an absent entry this process once
-        held is a store eviction; otherwise fall back to the in-memory
-        history (a cold store says nothing beyond it)."""
-        if reason == "poison":
-            return "pcache-poison"
-        if reason == "skew":
-            return "env-skew"
-        if reason == "error":
-            return "pcache-eviction"
-        if reason == "absent" and self.digest_known(digest):
-            return "pcache-eviction"
-        return self.classify_memory(fp, sig)
-
     # -- the one entry point compile sites call --------------------------
-    def attribute(self, key, sig, seconds: float, site: str,
-                  pcache_reason: Optional[str] = None,
-                  digest: Optional[str] = None) -> str:
+    def attribute(self, key, sig, seconds: float, site: str) -> str:
         """Classify one compile, update the ledger, and fan the
         attribution out to the event log, the metric plane, and the
         active query profile. Returns the cause."""
         fp = program_fingerprint(key)
-        if pcache_reason is not None or digest is not None:
-            cause = self.classify_pcache(fp, sig, pcache_reason, digest)
-        else:
-            cause = self.classify_memory(fp, sig)
+        cause = self.classify_memory(fp, sig)
         ts = time.time()
         sig_repr = repr(sig) if sig is not None else None
         inv = sig_invariant(sig)
@@ -271,7 +215,6 @@ class RetraceLedger:
     def clear(self) -> None:
         with self._lock:
             self._programs.clear()
-            self._digests.clear()
             self._recent.clear()
             self._totals.clear()
 
@@ -279,12 +222,9 @@ class RetraceLedger:
 LEDGER = RetraceLedger()
 
 
-def attribute(key, sig, seconds: float, site: str,
-              pcache_reason: Optional[str] = None,
-              digest: Optional[str] = None) -> str:
+def attribute(key, sig, seconds: float, site: str) -> str:
     """Module-level convenience over the process ledger."""
-    return LEDGER.attribute(key, sig, seconds, site,
-                            pcache_reason=pcache_reason, digest=digest)
+    return LEDGER.attribute(key, sig, seconds, site)
 
 
 def clear() -> None:

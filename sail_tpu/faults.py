@@ -17,7 +17,6 @@ site                      where it fires                        key
 ``shuffle.fetch``         peer/driver stream fetch              addr/sSpPcC
 ``worker.heartbeat``      worker heartbeat loop                 worker_id
 ``io.read``               ``io.formats.read_table`` entry       format
-``io.cache``              persistent program-cache load/store   load:site:digest
 ``streaming.source``      streaming trigger, pre-read           source name
 ``streaming.sink``        epoch sink stage / commit             stage:eN, commit:eN
 ``streaming.checkpoint``  state / offsets checkpoint write      state:eN, offsets:eN

@@ -290,32 +290,17 @@ def _debug_autoscaler() -> dict:
 
 
 def _debug_compile_cache() -> dict:
-    """Persistent compiled-program cache snapshot: store shape, the
-    registry's hit/miss/evict/load-error counters, and the top entries
-    by compile time saved. Serializes cache state only — never
-    configuration or environment values."""
-    from .exec import pcache
-    out = pcache.stats()
-    rows = {r["name"]: r for r in _metrics.REGISTRY.snapshot()
-            if str(r.get("name", "")).startswith(
-                ("execution.compile.persistent_",
-                 "execution.compile.prewarm_"))}
-    counters = {}
-    for short in ("hit", "miss", "evict", "load_error"):
-        name = f"execution.compile.persistent_{short}_count"
-        counters[short] = int(rows.get(name, {}).get("value", 0))
-    for short in ("prewarm_loaded", "prewarm_skipped"):
-        name = f"execution.compile.{short}_count"
-        counters[short] = int(rows.get(name, {}).get("value", 0))
-    out["counters"] = counters
-    # pinned capacity buckets ride along: the same debug surface that
-    # explains compile behavior should show why capacities are stable
-    from .exec import capacity
-    out["capacity"] = capacity.snapshot()
-    consults = counters["hit"] + counters["miss"]
-    out["hit_ratio"] = round(counters["hit"] / consults, 4) \
-        if consults else None
-    return out
+    """Compile caches as this process holds them: the directory jax's
+    persistent compilation cache is in, how many stage programs the
+    in-memory operator cache holds, and the pinned capacity buckets
+    (why capacities, and with them compiled shapes, are stable).
+    Serializes cache state only — never configuration or environment
+    values."""
+    from .exec import capacity, pcache
+    from .exec.local import _OP_CACHE
+    return {"jax_cache_dir": pcache.place_jax_cache(),
+            "op_cache_entries": len(_OP_CACHE.entries),
+            "capacity": capacity.snapshot()}
 
 
 # ---------------------------------------------------------------------------

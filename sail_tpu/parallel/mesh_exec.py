@@ -432,14 +432,6 @@ class MeshExecutor:
             in_specs=tuple(spec for _ in range(n_flat)),
             out_specs=(spec, spec, spec, spec))
         jitted = jax.jit(wrapped)
-        # persistent AOT cache (exec/pcache.py): the whole SPMD program
-        # keys by the structural graph key + dictionary CONTENT + leaf
-        # avals, so a restarted process loads the stored executable
-        # instead of re-tracing the multi-stage shard_map program.
-        # Memory-table sources (identity-keyed) make the key process-
-        # local — those programs stay jit-only.
-        jitted = self._maybe_persistent(wrapped, cache_key,
-                                        dict_objs) or jitted
         self.last_exchanges = len(exchanges)
         _record_metric("mesh.exchange_count", len(exchanges))
         self.last_hlo = None
@@ -453,26 +445,6 @@ class MeshExecutor:
         while len(_PROGRAM_CACHE) > _PROGRAM_CACHE_MAX:
             _PROGRAM_CACHE.pop(next(iter(_PROGRAM_CACHE)))
         return self._run_program(jitted, leaves, stage_out, top_id)
-
-    def _maybe_persistent(self, wrapped, cache_key, dict_objs):
-        """Swap the jitted SPMD program for a persistent-cache-aware
-        wrapper when every baked host object is content-digestable
-        (dictionary arrays only — memory-table sources are identity-
-        keyed and cannot name a cross-process entry)."""
-        from ..config import truthy_value
-        from ..exec import pcache
-        if not pcache.enabled():
-            return None
-        session = self.config.get("spark.sail.compileCache.enabled")
-        if session is not None and not truthy_value(session):
-            return None
-        if any(not isinstance(d, pa.Array) for d in dict_objs):
-            return None
-        try:
-            return pcache.wrap(wrapped, ("mesh", cache_key), dict_objs,
-                               fused=True, site="mesh")
-        except Exception:  # noqa: BLE001 — cache trouble: plain jit
-            return None
 
     def _run_program(self, jitted, leaves, stage_out, top_id):
         flat_in = self._flatten_leaf_arrays(leaves)

@@ -75,19 +75,12 @@ class QueryProfile:
     compile_cache_hits: int = 0
     compile_cache_misses: int = 0
     compile_ms: float = 0.0
-    # persistent (cross-process AOT) compiled-program cache: consults
-    # that loaded a stored executable vs fell through to a JIT compile,
-    # and the deserialization wall time the hits paid
-    persistent_hits: int = 0
-    persistent_misses: int = 0
-    persistent_load_ms: float = 0.0
     # programs that actually traced + XLA-compiled this query (every
-    # note_compile_time call) — NOT derivable from cache_misses minus
-    # persistent_hits: misses count per key, persistent hits per
-    # argument signature
+    # note_compile_time call) — NOT compile_cache_misses: those count
+    # per key, a key compiles once per argument signature
     compiled_programs: int = 0
     # per-key compile events: [{"key": str, "ms": float, "source":
-    # "trace" | "persistent"}] — one per stage program bound
+    # "trace"}] — one per stage program compiled
     compile_events: List[dict] = field(default_factory=list)
     # retrace forensics (exec/retrace.py): every compile this query paid
     # attributed by typed cause. ``retrace_count``/``retrace_ms``
@@ -315,8 +308,7 @@ class QueryProfile:
             else:
                 self.compile_cache_misses += 1
 
-    def note_compile_time(self, seconds: float, key: str = "",
-                          source: str = "trace") -> None:
+    def note_compile_time(self, seconds: float, key: str = "") -> None:
         ms = seconds * 1000.0
         with self._lock:
             self.compile_ms += ms
@@ -325,7 +317,7 @@ class QueryProfile:
             if len(self.compile_events) < 256:
                 self.compile_events.append(
                     {"key": key[:120], "ms": round(ms, 3),
-                     "source": source})
+                     "source": "trace"})
 
     def note_retrace(self, cause: str, seconds: float) -> None:
         """One attributed compile (exec/retrace.py). First-ever cold
@@ -340,24 +332,6 @@ class QueryProfile:
     def note_admission_wait(self, waited_ms: float) -> None:
         with self._lock:
             self.admission_wait_ms += float(waited_ms)
-
-    def note_persistent(self, hit: bool, seconds: float = 0.0) -> None:
-        with self._lock:
-            if hit:
-                self.persistent_hits += 1
-                self.persistent_load_ms += seconds * 1000.0
-            else:
-                self.persistent_misses += 1
-
-    def note_compile_loaded(self, seconds: float, key: str = "") -> None:
-        """A persistent-cache hit bound a stored executable: record the
-        per-stage event (source=persistent) WITHOUT charging the compile
-        phase — nothing compiled."""
-        with self._lock:
-            if len(self.compile_events) < 256:
-                self.compile_events.append(
-                    {"key": key[:120], "ms": round(seconds * 1000.0, 3),
-                     "source": "persistent"})
 
     def note_backend_routes(self, routes) -> None:
         with self._lock:
@@ -533,9 +507,6 @@ class QueryProfile:
             "compile": {
                 "cache_hits": self.compile_cache_hits,
                 "cache_misses": self.compile_cache_misses,
-                "persistent_hits": self.persistent_hits,
-                "persistent_misses": self.persistent_misses,
-                "persistent_load_ms": round(self.persistent_load_ms, 3),
                 "compiled_programs": self.compiled_programs,
                 "time_ms": round(self.compile_ms, 3),
                 "events": list(self.compile_events),
@@ -620,19 +591,12 @@ class QueryProfile:
                 extra = (f" (cache hits={self.compile_cache_hits} "
                          f"misses={self.compile_cache_misses})")
             lines.append(f"phase {name}: {ms:.1f}ms{extra}")
-        if (self.compile_cache_hits or self.compile_cache_misses
-                or self.persistent_hits):
-            # the compiled-program cache ladder per stage program:
-            # in-memory hit (nothing bound) → persistent hit (stored
-            # executable deserialized) → miss (trace + XLA compile;
-            # counted directly — key-level cache misses and
-            # signature-level persistent hits don't subtract)
-            line = (f"compile: memory_hits={self.compile_cache_hits} "
-                    f"persistent_hits={self.persistent_hits} "
-                    f"misses={self.compiled_programs}")
-            if self.persistent_hits:
-                line += f" load={self.persistent_load_ms:.1f}ms"
-            lines.append(line)
+        if self.compile_cache_hits or self.compile_cache_misses:
+            # per stage program: in-memory hit (nothing bound) or a
+            # trace + XLA compile, counted directly
+            lines.append(
+                f"compile: memory_hits={self.compile_cache_hits} "
+                f"misses={self.compiled_programs}")
         if self.retrace_causes:
             causes = " ".join(
                 f"{c}={n}"
@@ -1061,32 +1025,7 @@ def note_compile_time(seconds: float, key: str = "") -> None:
         pass
     profile = current_profile()
     if profile is not None:
-        profile.note_compile_time(seconds, key, source="trace")
-
-
-def note_persistent_cache(hit: bool, seconds: float = 0.0) -> None:
-    """One persistent compiled-program cache consult (exec/pcache.py):
-    a hit loaded a stored AOT executable, a miss fell through to JIT."""
-    profile = current_profile()
-    if profile is not None:
-        profile.note_persistent(hit, seconds)
-
-
-def note_compile_event(key: str, seconds: float,
-                       source: str = "persistent") -> None:
-    """A stage program was bound WITHOUT compiling (persistent-cache
-    load): the per-stage compile event stream and the flight recorder
-    see it, but no compile time is charged."""
-    try:
-        from . import events as _events
-        _events.emit(_events.EventType.COMPILE, key=key[:120],
-                     ms=round(float(seconds) * 1000.0, 3),
-                     source=source)
-    except Exception:  # noqa: BLE001
-        pass
-    profile = current_profile()
-    if profile is not None:
-        profile.note_compile_loaded(seconds, key)
+        profile.note_compile_time(seconds, key)
 
 
 def note_retrace(cause: str, seconds: float) -> None:

@@ -73,13 +73,10 @@ class SparkSession:
         # at most one server per process)
         from . import obs_server
         obs_server.ensure_started()
-        # resolve the persistent compiled-program cache config NOW so
-        # jax's compilation-cache dir is set before the first eager
-        # dispatch compiles anything (exec/pcache.py), and kick off the
-        # background prewarm of the manifest's top compile-time savers
+        # jax's persistent compilation cache has its directory before
+        # the first eager dispatch compiles anything
         from .exec import pcache
-        pcache.enabled()
-        pcache.start_prewarm()
+        pcache.place_jax_cache()
 
     def newSession(self) -> "SparkSession":
         """A sibling session: same catalog (tables, temp views, UDFs),

@@ -3,9 +3,8 @@
 Four planes:
 
 - retrace cause taxonomy: every cause in events.RETRACE_CAUSES is
-  provoked deliberately through the REAL compile decision sites
-  (``_compile_timed`` + ``_OpCache`` for the in-memory path, the
-  persistent store's load reasons for the pcache path);
+  provoked deliberately through the REAL compile decision site
+  (``_compile_timed`` + ``_OpCache``);
 - baselines + verdicts: per-fingerprint baseline convergence, the
   outlier gates, evidence ranking, and every verdict category;
 - SLO burn windows: fast/slow burn-rate math checked against exact
@@ -17,7 +16,6 @@ Four planes:
   faults included.
 """
 
-import glob
 import json
 import os
 import subprocess
@@ -34,7 +32,7 @@ from sail_tpu import metrics as gm
 from sail_tpu.analysis import anomaly
 from sail_tpu.events import EventType
 from sail_tpu.exec import local as xl
-from sail_tpu.exec import pcache, retrace
+from sail_tpu.exec import retrace
 from sail_tpu.exec.local import clear_caches
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -51,7 +49,6 @@ def _reset():
     clear_caches()
     faults.reset()
     events.reload()
-    pcache.reload()
 
 
 def _sig_args(rows, cols):
@@ -96,72 +93,6 @@ def test_op_cache_eviction_recompile_reads_as_eviction():
     evicted = [r for r in rows if r["cause"] == "eviction"]
     assert evicted and evicted[0]["count"] == 1
     assert evicted[0]["evictions"] >= 1
-
-
-def test_pcache_load_reasons_classify():
-    led = retrace.RetraceLedger()
-    fp = retrace.program_fingerprint(("op", "p"))
-    sig = ("td", (((8, 2), "f32", False),))
-    assert led.classify_pcache(fp, sig, "poison", "d1") == \
-        "pcache-poison"
-    assert led.classify_pcache(fp, sig, "skew", "d1") == "env-skew"
-    assert led.classify_pcache(fp, sig, "error", "d1") == \
-        "pcache-eviction"
-    # absent entry this process never held says nothing beyond the
-    # in-memory history (cold store → first-ever)
-    assert led.classify_pcache(fp, sig, "absent", "d1") == "first-ever"
-    led.note_digest("d1")
-    assert led.classify_pcache(fp, sig, "absent", "d1") == \
-        "pcache-eviction"
-
-
-def test_note_bound_makes_recompile_eviction():
-    led = retrace.RetraceLedger()
-    sig = ("td", (((8, 2), "f32", False),))
-    led.note_bound(("op", "b"), sig)  # pcache load hit: no compile
-    assert led.attribute(("op", "b"), sig, 0.01, "memory") == "eviction"
-
-
-@pytest.fixture
-def store(tmp_path, monkeypatch):
-    d = str(tmp_path / "pc")
-    monkeypatch.setenv("SAIL_COMPILE_CACHE__DIR", d)
-    monkeypatch.setenv("SAIL_COMPILE_CACHE__ENABLED", "1")
-    monkeypatch.delenv("SAIL_COMPILE_CACHE__MAX_MB", raising=False)
-    pcache.reload()
-    clear_caches()
-    return d
-
-
-def test_pcache_eviction_and_poison_end_to_end(store):
-    spark = SparkSession({"spark.sail.execution.mesh": "off"})
-    t = pa.table({"a": list(range(200)),
-                  "b": [float(i) for i in range(200)]})
-    spark.createDataFrame(t).createOrReplaceTempView("t")
-    q = "SELECT a % 3 AS g, sum(b) AS s FROM t GROUP BY a % 3 ORDER BY g"
-    spark.sql(q).collect()
-    entries = glob.glob(os.path.join(store, "*.sailpc"))
-    assert entries, "no persistent entries written"
-    # the store loses every entry (another process's eviction); the
-    # ledger still knows the digests, so the recompile is typed
-    # pcache-eviction — NOT a cold first-ever
-    for p in entries:
-        os.remove(p)
-    xl._OP_CACHE.entries.clear()  # drop in-memory programs, keep ledger
-    spark.sql(q).collect()
-    totals = retrace.LEDGER.totals()
-    assert totals.get("pcache-eviction", 0) >= 1, totals
-    # poison-mark the (re-stored) entries: next miss reads as poison
-    digests = [os.path.basename(p).split(".")[0] for p in
-               glob.glob(os.path.join(store, "*.sailpc"))]
-    assert digests
-    for d in digests:
-        pcache._poison(d)
-    xl._OP_CACHE.entries.clear()
-    spark.sql(q).collect()
-    totals = retrace.LEDGER.totals()
-    assert totals.get("pcache-poison", 0) >= 1, totals
-    spark.stop()
 
 
 # ---------------------------------------------------------------------------

@@ -1,17 +1,12 @@
-"""Zero-retrace steady state (exec/capacity.py + pcache prewarm +
-router SLO feedback).
+"""Zero-retrace steady state (exec/capacity.py + router SLO feedback).
 
-Three planes:
+Two planes:
 
 - pinned grow-only buckets: hysteresis locked through the REAL
   ``retrace.attribute`` path (oscillating batch sizes around a bucket
   boundary → capacity-bucket count flat after warmup), the grow-only
   red test (shrinking inputs never re-bucket downward), sustained
   overflow growth, and the pinning-off A/B;
-- persistent-store prewarm: the compile-time-saved tally survives a
-  simulated restart through the manifest, ``start_prewarm`` AOT-loads
-  the working set so first traffic binds without a compile OR a disk
-  read, and the counters land in the metrics registry;
 - router as SLO feedback controller: decisions are pure functions of
   (fingerprint, observation table, SLO context) — the same inputs
   produce the same decision, the ``slo-feedback`` reason appears only
@@ -19,15 +14,13 @@ Three planes:
   bit-identical with the feedback path on vs off.
 """
 
-import os
-
 import jax
 import jax.numpy as jnp
 import pytest
 
 from sail_tpu import SparkSession, events, faults
 from sail_tpu.columnar.batch import bucket_capacity, round_capacity
-from sail_tpu.exec import capacity, pcache, retrace
+from sail_tpu.exec import capacity, retrace
 from sail_tpu.exec import local as xl
 from sail_tpu.exec import router
 from sail_tpu.exec.local import clear_caches
@@ -45,7 +38,6 @@ def _reset():
     router.clear_observations()
     faults.reset()
     events.reload()
-    pcache.reload()
 
 
 # ---------------------------------------------------------------------------
@@ -169,81 +161,6 @@ def test_bit_identical_results_pinning_on_vs_off(monkeypatch):
     capacity.reload()
     off = run()
     assert on == off
-
-
-# ---------------------------------------------------------------------------
-# prewarm: manifest persistence + zero first-traffic work
-# ---------------------------------------------------------------------------
-
-@pytest.fixture()
-def _store(tmp_path, monkeypatch):
-    monkeypatch.setenv("SAIL_COMPILE_CACHE__DIR", str(tmp_path))
-    monkeypatch.setenv("SAIL_COMPILE_CACHE__ENABLED", "1")
-    pcache.reload()
-    yield str(tmp_path)
-    pcache.clear()
-    pcache.reload()
-
-
-def _bind_once(tag, rows=64):
-    """One PersistentProgram bound through the real wrap/bind path."""
-    prog = pcache.wrap(lambda x: x + 1, ("op", tag), ())
-    assert prog is not None
-    prog(jnp.zeros((rows, 2)))
-    return prog
-
-
-def test_top_by_saved_tally_survives_restart(_store):
-    _bind_once("persist-tally")          # compile + store
-    _bind_once("persist-tally")          # fresh wrapper: a store hit
-    pcache._flush_tally()
-    before = {e["digest"] for e in pcache.stats()["top_by_saved"]}
-    assert before
-    pcache.reload()                      # simulated process restart
-    after = {e["digest"] for e in pcache.stats()["top_by_saved"]}
-    assert before <= after, "ranking reset with the process"
-
-
-def test_prewarm_loads_manifest_working_set(_store):
-    _bind_once("prewarm-a")
-    _bind_once("prewarm-a")              # hit → tally entry
-    pcache._flush_tally()
-    pcache.reload()                      # restart: in-memory state gone
-    loaded, _skipped = pcache.prewarm()
-    assert loaded >= 1
-    assert pcache.stats()["prewarm_preloaded"] >= 1
-
-
-def test_prewarmed_first_traffic_needs_no_compile_and_no_disk(_store):
-    _bind_once("prewarm-b")
-    _bind_once("prewarm-b")
-    pcache._flush_tally()
-    pcache.reload()
-    retrace.clear()
-    pcache.start_prewarm(wait=True)
-    # hostile restart: wipe the .sailpc entries AFTER prewarm — first
-    # traffic must bind from the preloaded executables alone
-    removed = 0
-    for name in os.listdir(_store):
-        if name.endswith(".sailpc"):
-            os.unlink(os.path.join(_store, name))
-            removed += 1
-    assert removed >= 1
-    prog = pcache.wrap(lambda x: x + 1, ("op", "prewarm-b"), ())
-    out = prog(jnp.zeros((64, 2)))
-    assert out.shape == (64, 2)
-    # zero compiles: the retrace ledger saw nothing
-    assert retrace.LEDGER.totals() == {}
-
-
-def test_prewarm_budget_and_gating(_store, monkeypatch):
-    monkeypatch.setenv("SAIL_COMPILE_CACHE__PREWARM__ENABLED", "0")
-    pcache.reload()
-    assert pcache.prewarm() == (0, 0)
-    monkeypatch.setenv("SAIL_COMPILE_CACHE__PREWARM__ENABLED", "1")
-    monkeypatch.setenv("SAIL_COMPILE_CACHE__PREWARM__TOP_N", "0")
-    pcache.reload()
-    assert pcache.prewarm() == (0, 0)
 
 
 # ---------------------------------------------------------------------------

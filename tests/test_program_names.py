@@ -19,11 +19,26 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _SCRIPT = r"""
 import json, os, sys
+import jax.monitoring
+
+counts = {"requests": 0, "hits": 0}
+
+
+def _on_event(event, **_kw):
+    # the two events benchmark/run.py's CompileCounter reads
+    if event == "/jax/compilation_cache/compile_requests_use_cache":
+        counts["requests"] += 1
+    elif event == "/jax/compilation_cache/cache_hits":
+        counts["hits"] += 1
+
+
+jax.monitoring.register_event_listener(_on_event)
+
 import numpy as np
 import pyarrow as pa
 from sail_tpu import SparkSession, profiler
 
-seed = int(sys.argv[1])
+seed, route = int(sys.argv[1]), sys.argv[2]
 rng = np.random.default_rng(seed)
 n = 3000
 flags = np.array(["A", "N", "R"])
@@ -40,12 +55,16 @@ orders = pa.table({
     "o_orderkey": pa.array(np.arange(n // 4, dtype="int64")),
     "o_custkey": pa.array(rng.integers(0, 97, n // 4).astype("int64")),
 })
-spark = SparkSession({"spark.sail.execution.mesh": "off",
-                      "spark.sail.cache.result.enabled": "false",
-                      "spark.sail.execution.backend.force": "xla"})
+conf = {"spark.sail.cache.result.enabled": "false"}
+if route == "mesh":
+    conf["spark.sail.execution.mesh"] = "force"
+else:
+    conf.update({"spark.sail.execution.mesh": "off",
+                 "spark.sail.execution.backend.force": "xla"})
+spark = SparkSession(conf)
 spark.createDataFrame(lineitem).createOrReplaceTempView("lineitem")
 spark.createDataFrame(orders).createOrReplaceTempView("orders")
-names = set()
+names, answers, compile_spans = set(), [], 0
 for sql in (
     "SELECT l_returnflag, sum(l_quantity) q, avg(l_extendedprice) p "
     "FROM lineitem WHERE l_quantity < 40 GROUP BY l_returnflag "
@@ -53,34 +72,57 @@ for sql in (
     "SELECT o_custkey, sum(l_extendedprice) s FROM lineitem JOIN orders "
     "ON l_orderkey = o_orderkey GROUP BY o_custkey ORDER BY s DESC LIMIT 5",
 ):
-    spark.sql(sql).toArrow()
+    answers.append(spark.sql(sql).toArrow().to_pylist())
     p = profiler.last_profile()
     names |= {s.attributes["program"] for s in p.spans
               if s.name == "dispatch"}
-store = os.environ["SAIL_COMPILE_CACHE__DIR"]
+    compile_spans += p.span_count("compile")
+mesh = getattr(spark, "_last_mesh_executor", None)
 print("RESULT " + json.dumps({
-    "names": sorted(names),
-    "digests": sorted(f for f in os.listdir(store)
-                      if f.endswith(".sailpc"))}))
+    "names": sorted(names), "answers": answers,
+    "compile_spans": compile_spans, **counts,
+    "mesh_exchanges": mesh.last_exchanges if mesh is not None else 0,
+    "entries": sorted(os.listdir(os.environ["JAX_COMPILATION_CACHE_DIR"]))}))
 """
 
 
-def _in_a_fresh_interpreter(seed, store, hashseed):
+def _in_a_fresh_interpreter(seed, cache_dir, hashseed, route="local"):
+    """The two statements in a new process whose programs go to JAX's
+    persistent cache in ``cache_dir`` (tests/conftest.py keeps that
+    cache off for every other test)."""
     env = dict(os.environ)
-    env.update({"SAIL_COMPILE_CACHE__DIR": str(store),
-                "SAIL_COMPILE_CACHE__ENABLED": "1",
+    env.update({"JAX_ENABLE_COMPILATION_CACHE": "true",
+                "JAX_COMPILATION_CACHE_DIR": str(cache_dir),
                 "PYTHONHASHSEED": str(hashseed),
                 "PYTHONPATH": ROOT + os.pathsep + env.get("PYTHONPATH", "")})
-    r = subprocess.run([sys.executable, "-c", _SCRIPT, str(seed)], env=env,
-                       capture_output=True, text=True, timeout=300)
+    r = subprocess.run([sys.executable, "-c", _SCRIPT, str(seed), route],
+                       env=env, capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-2000:]
     line = [ln for ln in r.stdout.splitlines() if ln.startswith("RESULT ")]
-    return json.loads(line[-1][len("RESULT "):])
+    out = json.loads(line[-1][len("RESULT "):])
+    out["stderr"] = r.stderr
+    return out
 
 
-def test_names_and_entry_digests_hold_across_seeds_and_processes(tmp_path):
-    a = _in_a_fresh_interpreter(7, tmp_path / "a", hashseed=1)
-    b = _in_a_fresh_interpreter(2**31 + 11, tmp_path / "b", hashseed=2)
+def _stage_entries(run):
+    """The cache entries of stage programs: JAX names an entry after
+    its module, ``jit_sail_<site>_<digest>-<sha>-cache``."""
+    return [e for e in run["entries"] if e.startswith("jit_sail_")]
+
+
+@pytest.fixture(scope="module")
+def two_processes(tmp_path_factory):
+    """Other data, other hash seed, one cache directory (the directory
+    is part of JAX's key, so only a shared one can be compared)."""
+    cache = tmp_path_factory.mktemp("jax_cache")
+    first = _in_a_fresh_interpreter(7, cache, hashseed=1)
+    second = _in_a_fresh_interpreter(2**31 + 11, cache, hashseed=2)
+    return first, second
+
+
+def test_names_and_entry_digests_hold_across_seeds_and_processes(
+        two_processes):
+    a, b = two_processes
     assert a["names"] == b["names"]
     assert len(a["names"]) >= 4
     for name in a["names"]:
@@ -89,9 +131,65 @@ def test_names_and_entry_digests_hold_across_seeds_and_processes(tmp_path):
         assert len(digest) == 8 and int(digest, 16) >= 0
     sites = {n[len("sail_"):].rpartition("_")[0] for n in a["names"]}
     assert {"agg", "join_phase"} <= sites
-    # the persistent store's entries are named from the same structure
-    # (key repr + dictionary CONTENT + signature): the same files
-    assert a["digests"] and a["digests"] == b["digests"]
+    # JAX's cache keys the module, name included: every named program
+    # has its entry, and the second process's are the same files
+    assert {e.split("-")[0] for e in _stage_entries(a)} == \
+        {"jit_" + n for n in a["names"]}
+    assert _stage_entries(a) == _stage_entries(b)
+
+
+def _second_was_answered_from_the_cache(a, b):
+    """``b`` ran after ``a`` on one cache directory: it added no stage
+    program's entry and its compile requests were cache hits. Small
+    eager programs may miss (57 of 59 requests hit on the chip) and are
+    not held."""
+    assert a["hits"] == 0 and a["requests"] >= len(_stage_entries(a))
+    assert _stage_entries(b) == _stage_entries(a)
+    assert b["hits"] >= len(_stage_entries(a))
+    assert b["requests"] - b["hits"] <= 2
+    assert "Error reading persistent compilation cache" not in b["stderr"]
+
+
+def test_a_second_process_compiles_no_stage_program(two_processes):
+    """What every cell's warm ``setup_s`` rests on: a process that
+    finds the first one's cache directory is answered from it."""
+    a, b = two_processes
+    assert len(_stage_entries(a)) >= 4
+    _second_was_answered_from_the_cache(a, b)
+
+
+def test_a_second_process_compiles_no_mesh_program(tmp_path):
+    """The same for the whole-graph SPMD program on eight virtual
+    devices (``mesh=force``)."""
+    a = _in_a_fresh_interpreter(7, tmp_path, hashseed=1, route="mesh")
+    b = _in_a_fresh_interpreter(2**31 + 11, tmp_path, hashseed=2,
+                                route="mesh")
+    assert a["mesh_exchanges"] >= 1 and b["mesh_exchanges"] >= 1
+    mesh_entries = [e for e in _stage_entries(a)
+                    if e.startswith("jit_sail_mesh_")]
+    assert len(mesh_entries) == 2          # one program a statement
+    _second_was_answered_from_the_cache(a, b)
+
+
+def test_a_truncated_cache_entry_costs_a_compile_not_the_statement(
+        tmp_path):
+    """JAX reads a damaged entry as a miss (it warns, compiles, and
+    leaves the file as it found it): the statement pays the compile
+    and answers the same."""
+    a = _in_a_fresh_interpreter(7, tmp_path, hashseed=1)
+    damaged = _stage_entries(a)
+    assert len(damaged) >= 4
+    for entry in damaged:
+        path = os.path.join(tmp_path, entry)
+        os.truncate(path, os.path.getsize(path) // 2)
+    b = _in_a_fresh_interpreter(7, tmp_path, hashseed=1)
+    assert b["answers"] == a["answers"]
+    assert b["compile_spans"] == a["compile_spans"] >= len(damaged)
+    assert b["requests"] == a["requests"]
+    assert b["hits"] <= b["requests"] - len(damaged)
+    for entry in damaged:
+        name = entry.split("-")[0]
+        assert f"cache entry for '{name}'" in b["stderr"]
 
 
 @pytest.fixture()

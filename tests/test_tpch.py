@@ -12,20 +12,26 @@ import numpy as np
 import pandas as pd
 import pytest
 
-from sail_tpu import SparkSession
+from sail_tpu import SparkSession, profiler
 from sail_tpu.benchmarks.tpch_data import generate_tpch
 from sail_tpu.benchmarks.tpch_queries import QUERIES
 
 from tpch_oracle import ORACLES
 
 
+#: the default route, where on a CPU the router hands chain-absorbing
+#: aggregates to the C++ kernel, and the route the chip takes: every
+#: stage a single-device XLA program through ``_compile_timed(jax.jit)``
+#: (the force also settles the plan-level mesh gate)
+ROUTES = {"default": {},
+          "xla": {"spark.sail.execution.backend.force": "xla"}}
+
+
 @pytest.fixture(scope="module")
-def tpch():
-    spark = SparkSession({})
+def tpch_data():
     tables = generate_tpch(sf=0.005, seed=7)
     pdf = {}
     for name, table in tables.items():
-        spark.createDataFrame(table).createOrReplaceTempView(name)
         df = table.to_pandas()
         # decimals → float for the oracle
         for c in df.columns:
@@ -36,7 +42,16 @@ def tpch():
                     isinstance(df[c].iloc[0], datetime.date):
                 df[c] = pd.to_datetime(df[c])
         pdf[name] = df
-    return spark, pdf
+    return tables, pdf
+
+
+@pytest.fixture(scope="module", params=list(ROUTES))
+def tpch(request, tpch_data):
+    tables, pdf = tpch_data
+    spark = SparkSession(dict(ROUTES[request.param]))
+    for name, table in tables.items():
+        spark.createDataFrame(table).createOrReplaceTempView(name)
+    return spark, pdf, request.param
 
 
 def _normalize(df: pd.DataFrame) -> pd.DataFrame:
@@ -90,7 +105,11 @@ _UNORDERED = {2, 11, 13, 16, 18, 21}  # compare as sets (ties in sort keys)
 
 @pytest.mark.parametrize("q", list(range(1, 23)))
 def test_tpch_query(tpch, q):
-    spark, pdf = tpch
+    spark, pdf, route = tpch
     got = spark.sql(QUERIES[q]).toPandas()
     exp = ORACLES[q](pdf)
     _compare(got, exp, q, ordered=q not in _UNORDERED)
+    if route == "xla":
+        routes = profiler.last_profile().backend_routes
+        assert routes and all(r["backend"] == "xla" for r in routes), \
+            f"Q{q}: {routes}"
