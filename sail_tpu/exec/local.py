@@ -3372,9 +3372,9 @@ _JOIN_MERGE_ROW_BYTES = 36
 #: row of the merge
 _JOIN_PROBE_ROW_BYTES = 17 + _JOIN_MERGE_ROW_BYTES
 #: per BUILD row (ops/join.py build_side): the key (8), its sorted copy
-#: (8), two stable argsort passes each holding operand and result of
-#: key + index (2 x 2 x 12), the permutation (4), usable (1), and its
-#: row of the merge
+#: (8), operand and result of the one sort that carries dead flag, key
+#: and row number (2 x 13), the permutation (4) and usable (1) come to
+#: 47 of the 69 charged, the rest is margin; and its row of the merge
 _JOIN_BUILD_ROW_BYTES = 69 + _JOIN_MERGE_ROW_BYTES
 #: per row of one stable pass of a sort (ops/sort.py sort_pass): order
 #: bits (8), their gather through the permutation (8), operand and
@@ -3404,12 +3404,11 @@ def join_working_set(probe_capacity: int, build_capacity: int,
     into its result, and the build side's payload columns come with a
     validity byte each (the unique-build path; an expanding join sizes
     its own output after the phase's sync). Asked of the v5e's compiler
-    for Q3's two join phases at SF10 as the executor runs them: 1.28 GB
+    for Q3's two join phases at SF10 as the executor runs them: 1.30 GB
     for the lineitem join (a 1.5Mi-row probe, the 32Mi-row lineitem as
-    the build: 0.87 GB of temporaries and 0.42 GB of results; 1.02 GB
-    with the two binary searches it had before) against 3.61 GB here,
-    and 0.29 GB for the orders join (a 0.3Mi-row probe, an 8Mi-row
-    build) against 0.90."""
+    the build: 0.88 GB of temporaries and 0.42 GB of results) against
+    3.61 GB here, and 0.25 GB for the orders join (a 0.3Mi-row probe, an
+    8Mi-row build: 0.15 and 0.10 GB) against 0.90."""
     return (probe_capacity * (_JOIN_PROBE_ROW_BYTES + out_row_bytes)
             + build_capacity * _JOIN_BUILD_ROW_BYTES)
 

@@ -172,9 +172,11 @@ def test_sort_group_by_on_packed_uint64_key(one_chip):
 
 
 def test_sorted_build_merge_probe_join(one_chip):
-    """ops/join: build side sorted on an int64 key, probe keys merged
-    into it by one more sort and two scans (no loop of gathers), the
-    expanding materialisation, the unique fast path."""
+    """ops/join: the build side ordered by ONE sort on (dead flag, int64
+    key) that carries the row number, so sorted keys and permutation come
+    out of the sort and nothing is gathered through an index; probe keys
+    merged into it by one more sort and two scans (no loop of gathers),
+    the expanding materialisation, the unique fast path."""
     bn, pn, out_cap = SORT_ROWS, SORT_ROWS, 2 * SORT_ROWS
 
     def fn(bkey, bpay, bsel, pkey, ppay, psel):

@@ -12,6 +12,7 @@ from sail_tpu.ops import join as joinops
 from sail_tpu.ops import sort as sortops
 from sail_tpu.spec import data_type as dt
 
+import jax
 import jax.numpy as jnp
 
 
@@ -297,6 +298,49 @@ PROBE_CASES = [
     "build_capacity_not_a_power_of_two", "extreme_keys",
     "hashed_three_columns",
 ]
+
+
+@pytest.mark.parametrize("seed", [11, 2_900_000_029])
+@pytest.mark.parametrize("case", PROBE_CASES)
+def test_build_side_against_numpy_lexsort(case, seed):
+    """``perm`` puts the usable build rows first in key order, equal keys
+    in row order, the dead rows behind them in key order too;
+    ``sorted_keys`` is the keys in that order with the dead suffix set to
+    KEY_MAX; ``num_valid`` counts the usable rows. Held exactly: the order
+    of two stable argsorts, by key and then by dead flag."""
+    bcols, bsel, _, _ = _probe_case(case, np.random.default_rng(seed))
+    bt = joinops.build_side(bcols, bsel)
+    keys, usable, exact = joinops._join_keys(bcols, bsel, seed=bt.seed)
+    keys, usable = np.asarray(keys), np.asarray(usable)
+    n = keys.shape[0]
+    perm = np.lexsort((np.arange(n), keys, ~usable))
+    num_valid = int(usable.sum())
+    sorted_keys = keys[perm]
+    sorted_keys[num_valid:] = np.uint64(0xFFFFFFFFFFFFFFFF)
+    got_perm, got_keys = np.asarray(bt.perm), np.asarray(bt.sorted_keys)
+    assert got_perm.dtype == np.int32 and got_keys.dtype == np.uint64
+    assert bt.exact == exact and int(bt.num_valid) == num_valid
+    np.testing.assert_array_equal(got_perm, perm)
+    np.testing.assert_array_equal(got_keys, sorted_keys)
+
+
+@pytest.mark.parametrize("case", ["null_and_dead_rows", "hashed_three_columns"])
+def test_build_side_lowers_to_one_sort_and_no_gather(case):
+    """The build side's order comes from ONE sort that carries key and row
+    number: a gather of the keys, the flags or the permutation through a
+    sort permutation (24 ns a row at 32Mi rows on a v5e, PERF.md PR 31)
+    must not come back unnoticed."""
+    bcols, bsel, _, _ = _probe_case(case, np.random.default_rng(11))
+
+    def fn(datas, validities, sel):
+        cols = [Column(d, v, dt.LongType()) for d, v in zip(datas, validities)]
+        bt = joinops.build_side(cols, sel)
+        return bt.perm, bt.sorted_keys, bt.num_valid
+
+    text = jax.jit(fn).lower([c.data for c in bcols],
+                             [c.validity for c in bcols], bsel).as_text()
+    assert text.count("stablehlo.sort") == 1, text
+    assert "gather" not in text and "dynamic_slice" not in text, text
 
 
 @pytest.mark.parametrize("seed", [11, 2_900_000_029])
