@@ -5,13 +5,22 @@ against a server in this process, on the chip.
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Nothing here knows a cell by name. ``BENCHMARK.json`` maps the cell to
-a configuration (``configs/<name>.json``: scale, tables, session
-options, guarantees, limits, needed bytes) and a traffic mix
+a configuration (``configs/<name>.json``) and a traffic mix
 (``traffic/<name>.json``: statements, streams, loop); statements are
 ``queries/<name>.json`` + ``.sql``; each per-layer metric is
 ``metrics/<name>.json`` naming a reader under ``readers/``. A later
-cell, configuration or metric is new files and new entries in
-``BENCHMARK.json``.
+cell, configuration, statement or metric is new files and new entries
+in ``BENCHMARK.json``.
+
+What the harness reads of a configuration: ``scale_factor`` and
+``tables`` (the data), ``session_options`` (set on every client
+session), ``process_environment`` (set before JAX starts), ``trace``
+(``after_seconds``, ``seconds``: the traced part of a ``--trace 1``
+window; 1 and 4 where absent), ``backends`` (the routes a stage may
+take and still count as executed where the configuration says; ``["xla"]``
+where absent), ``limits`` (one per number compared) and, through
+``needed_bytes.py``, ``rows``, ``schema`` and ``logical_widths_bytes``.
+The rest of the file states the deployment for its readers.
 
 One process is the server (``SparkConnectServer``, threads) and its
 clients (``SparkConnectClient`` over gRPC on localhost): a chip belongs
@@ -403,11 +412,25 @@ def percentile(values: list, q: float) -> float:
     return ordered[int(rank) - 1]
 
 
+def routes_off_the_backends(done: list, backends: list) -> int:
+    """What of the answered statements did not run where the
+    configuration says: every stage routed to a backend that is not one
+    of ``backends``, every profile that names no route, every statement
+    that left no profile."""
+    profiles = [st.profile for st in done if st.profile is not None]
+    return (sum(1 for p in profiles for r in p.backend_routes
+                if r.get("backend") not in backends)
+            + sum(1 for p in profiles if not p.backend_routes)
+            + (len(done) - len(profiles)))
+
+
 class Run:
     """What the readers read of one finished window: ``config``,
-    ``statements`` (each with its ``profile``), ``done`` (those that
-    answered), ``setup`` (seconds per step), ``trace`` (the reduced
-    trace, or None), ``device``, ``peaks`` and ``compiles_in_window``."""
+    ``queries`` (the cell's statements' documents), ``statements`` (each
+    with its ``profile``), ``done`` (those that answered), ``setup``
+    (seconds per step), ``trace`` (the reduced trace with ``wall``, the
+    traced window on the wall clock, or None), ``device``, ``peaks`` and
+    ``compiles_in_window``."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
@@ -561,7 +584,8 @@ def measure(args, cell: Cell, device: dict, counter: CompileCounter,
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
 
-    run = Run(config=cell.config, statements=statements, done=done,
+    run = Run(config=cell.config, queries=cell.queries,
+              statements=statements, done=done,
               setup=setup, trace=trace, device=device,
               peaks=cell.peaks["devices"].get(device["kind"]),
               compiles_in_window=len(compiles_in_window))
@@ -582,11 +606,8 @@ def measure(args, cell: Cell, device: dict, counter: CompileCounter,
         [(st.query, st.table) for st in done], cell.queries, frames)
     numbers["failed_statements"] = len(failed)
     profiles = [st.profile for st in done if st.profile is not None]
-    numbers["not_xla_routes"] = (
-        sum(1 for p in profiles for r in p.backend_routes
-            if r.get("backend") != "xla")
-        + sum(1 for p in profiles if not p.backend_routes)
-        + (len(done) - len(profiles)))
+    numbers["not_xla_routes"] = routes_off_the_backends(
+        done, cell.config.get("backends", ["xla"]))
     numbers["result_cache_hits"] = sum(
         1 for p in profiles if p.cache_status == "hit")
     correct, checks = compare.verdict(numbers, cell.config["limits"])

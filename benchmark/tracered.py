@@ -160,8 +160,8 @@ def label_gap(mid_ns: float, calls: list, phase_at=None) -> str:
 
 def reduce_trace(planes: dict, phase_at=None, top: int = 10,
                  calls: list = None) -> dict:
-    """{"window_s", "busy_s", "device_ops", "idle_gaps", "window_ns",
-    "calls_in_window"}; busy time is averaged over the device planes.
+    """{"window_s", "busy_s", "device_ops", "idle_gaps", "window_ns"};
+    busy time is averaged over the device planes.
     ``calls`` = [(name, start_ns, end_ns)] on the trace's clock stands
     in for the trace's own call annotations: a call that began before
     the trace did has no annotation in it. Raises where the trace holds
@@ -196,9 +196,7 @@ def reduce_trace(planes: dict, phase_at=None, top: int = 10,
             "busy_s": busy_ns / n / 1e9,
             "device_ops": ranked(op_ns),
             "idle_gaps": ranked(gap_ns),
-            "window_ns": [t0, t1],
-            "calls_in_window": sum(1 for _n, s, e in calls
-                                   if t0 <= e <= t1)}
+            "window_ns": [t0, t1]}
 
 
 def shrink(planes: dict, keep_events: int = 400) -> dict:

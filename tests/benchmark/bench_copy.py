@@ -52,8 +52,9 @@ def make_copy(dest, sf=0.01, cycle=("tpch-q1", "tpch-q6"), streams=1,
     config["scale_factor"] = sf
     config["session_options"] = dict(TEST_SESSION_OPTIONS)
     config["trace"] = {"after_seconds": 0.2, "seconds": 1.0}
-    config["needed_bytes"] = {q: int(b * sf)
-                              for q, b in config["needed_bytes"].items()}
+    config["rows"] = {t: rows if t in ("region", "nation")
+                      else int(rows * sf)
+                      for t, rows in config["rows"].items()}
     write_json(os.path.join(bdir, "configs", "throwaway-config.json"),
                config)
     write_json(os.path.join(bdir, "traffic", "throwaway-traffic.json"),
@@ -87,7 +88,8 @@ def load_run_module(dest):
     the path so that its helpers, not the checkout's, are imported."""
     dest = str(dest)
     bdir = os.path.join(dest, "benchmark")
-    for name in ("compare", "datagen", "tracered", "tpch_oracle"):
+    for name in ("compare", "datagen", "tracered", "tpch_oracle",
+                 "span_metrics", "needed_bytes"):
         sys.modules.pop(name, None)
     sys.path.insert(0, bdir)
     spec = importlib.util.spec_from_file_location(
@@ -95,6 +97,16 @@ def load_run_module(dest):
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def statements_of(config):
+    """Names of the query files whose tables the configuration has:
+    the statements a cell over it can send."""
+    qdir = os.path.join(ROOT, "benchmark", "queries")
+    names = sorted(f[:-5] for f in os.listdir(qdir) if f.endswith(".json"))
+    return [n for n in names
+            if set(load_json(os.path.join(qdir, n + ".json"))["reads"])
+            <= set(config["tables"])]
 
 
 def result_line(text):

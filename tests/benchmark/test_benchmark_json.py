@@ -4,10 +4,14 @@ bounds, and that every name finds its file."""
 
 import os
 import re
+import sys
 
 import pytest
 
 from bench_copy import ROOT, load_json
+
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+from needed_bytes import needed_bytes  # noqa: E402
 
 BENCH = load_json(os.path.join(ROOT, "BENCHMARK.json"))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -38,10 +42,14 @@ def test_configuration_entry_and_file(config):
     assert doc["reduced"] == config["reduced"]
     assert all(NAME.match(k) for k in config["reduced"])
     assert any(w["config"] == config["name"] for w in BENCH["workloads"])
-    for key in ("source", "deployment", "scale_factor", "tables", "schema",
-                "guarantees", "session_options", "limits", "needed_bytes",
-                "assumed", "trace"):
+    for key in ("source", "deployment", "scale_factor", "tables", "rows",
+                "schema", "logical_widths_bytes", "guarantees",
+                "session_options", "limits", "assumed", "trace"):
         assert key in doc, key
+    # the backends its stages may take (the harness's default: xla
+    # alone) are the ones its guarantees name
+    for backend in doc.get("backends", ["xla"]):
+        assert backend in doc["guarantees"]["executed_on"], backend
     assert doc["session_options"]["spark.sail.cache.result.enabled"] == "false"
 
 
@@ -62,7 +70,7 @@ def test_cell_entry_and_its_files(cell):
                                      q + ".json"))
         assert os.path.exists(os.path.join(ROOT, "benchmark", "queries",
                                            doc["sql_file"]))
-        assert q in config["needed_bytes"]
+        assert needed_bytes(doc, config) > 0
         assert set(doc["reads"]) <= set(config["tables"])
         for table, cols in doc["reads"].items():
             assert set(cols) <= set(config["schema"][table])

@@ -11,7 +11,7 @@ import pandas as pd
 import pyarrow as pa
 import pytest
 
-from bench_copy import ROOT, load_json
+from bench_copy import ROOT, load_json, statements_of
 
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 import compare  # noqa: E402
@@ -59,7 +59,7 @@ def test_the_float32_control_is_not_correct(small, config, seed, tmp_path):
     """The control at test size: every seed's worst relative error is
     at least three times the limit, so ``correct`` comes out false."""
     doc = load_json(os.path.join(BENCH, "configs", config + ".json"))
-    qs = queries(*doc["needed_bytes"])
+    qs = queries(*statements_of(doc))
     frames = frames_for(qs, seed, 0.05, tmp_path)
     numbers = compare.control_reading(qs, frames)
     correct, checks = compare.verdict(

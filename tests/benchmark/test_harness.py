@@ -12,6 +12,7 @@ look for a chip skipped (``require_platform="cpu"``):
 """
 
 import hashlib
+import importlib
 import json
 import os
 import sys
@@ -108,7 +109,7 @@ def test_the_added_cell_runs_and_reports_end_to_end_metrics(copy, capsys):
 def test_the_added_metric_is_read_in_the_traced_run(copy, capsys,
                                                    monkeypatch):
     _dest, _cell, run = copy
-    tracered = sys.modules["tracered"]
+    tracered = importlib.import_module("tracered")   # the copy's own
     # the CPU's trace has no device plane: let its host plane stand in,
     # so that the whole traced path runs (its op line is empty)
     monkeypatch.setattr(tracered, "device_planes",
@@ -129,6 +130,44 @@ def test_the_added_metric_is_read_in_the_traced_run(copy, capsys,
     assert result["device"]["window_s"] == pytest.approx(0.5, abs=0.2)
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
     assert result["breakdown"]["idle_gaps"]
+
+
+def test_a_statement_that_outlasts_the_trace_is_still_read(
+        copy, capsys, monkeypatch):
+    """PR 32's case through the whole harness: the traced window lies
+    inside one slow statement, none ends in it, and the device time
+    per statement is in the line all the same."""
+    import time
+    from sail_tpu.spark_connect.client import SparkConnectClient
+    tracered = importlib.import_module("tracered")   # the copy's own
+    real = SparkConnectClient.sql
+    calls = {"n": 0}
+
+    def slow_sql(self, query):
+        calls["n"] += 1
+        if calls["n"] > 6:              # past the first calls and warm cycles
+            time.sleep(1.2)
+        return real(self, query)
+
+    def half_busy(lines):
+        (_n, t0, t1), = tracered.host_spans({"/host:CPU": lines},
+                                            tracered.WINDOW_SPAN)
+        return [["%fake = f32[] add(x)", t0, (t1 - t0) / 2]]
+
+    monkeypatch.setattr(SparkConnectClient, "sql", slow_sql)
+    monkeypatch.setattr(tracered, "device_planes",
+                        lambda planes: ["/host:CPU"])
+    monkeypatch.setattr(tracered, "op_events", half_busy)
+    result, _captured = drive(copy, capsys, trace=1, seconds=0.5)
+    assert result["correct"] is True and result["attempted"] == 2
+    device, metrics = result["device"], result["metrics"]
+    assert device["window_s"] == pytest.approx(0.5, abs=0.1)
+    assert device["busy_s"] == pytest.approx(device["window_s"] / 2)
+    # busy share x the statement's own time: over a second, not 250 ms
+    assert 1200 / 2 <= metrics["device_ms_per_query"]["value"] <= 3000 / 2
+    # a CPU has no row in peaks.json, so no roofline here: that reader's
+    # reading of this case is test_window_shares.py's
+    assert "scan_hbm_roofline" not in metrics
 
 
 def test_same_seed_same_answers_and_order(copy):
@@ -265,6 +304,46 @@ def test_an_answer_that_did_no_device_work_is_not_correct(
     assert result["correct"] is False
     assert result["checks"][check][0] > 0
     assert result["checks"]["exact_mismatches"] == [0, 0]
+
+
+@pytest.mark.parametrize("backends,correct", [
+    (None, False),
+    (["xla", "mesh"], True),
+])
+def test_a_plan_routed_to_the_mesh_counts_by_the_configurations_backends(
+        tmp_path, capsys, backends, correct):
+    """The whole plan through ``MeshExecutor`` on the test's eight
+    virtual devices: ``session._try_mesh_execute`` records a route
+    ``mesh``, which only a configuration that states it lets pass."""
+    cell = bench_copy.make_copy(tmp_path)
+    path = os.path.join(str(tmp_path), "benchmark", "configs",
+                        "throwaway-config.json")
+    config = load_json(path)
+    config["session_options"] = {
+        "spark.sail.cache.result.enabled": "false",
+        "spark.sail.execution.mesh": "force"}
+    if backends:
+        config["backends"] = backends
+    bench_copy.write_json(path, config)
+    run = bench_copy.load_run_module(tmp_path)
+    kept = {}
+
+    class KeepRun(run.Run):
+        def __init__(self, **kw):
+            super().__init__(**kw)
+            kept["run"] = self
+
+    run.Run = KeepRun
+    result, _captured = drive((tmp_path, cell, run), capsys)
+    routed = [r["backend"] for st in kept["run"].done
+              for r in st.profile.backend_routes]
+    assert routed.count("mesh") == len(kept["run"].done) > 0
+    assert set(routed) == {"mesh", "xla"}
+    assert result["correct"] is correct
+    assert result["checks"]["not_xla_routes"] == \
+        [0 if correct else routed.count("mesh"), 0]
+    assert result["checks"]["exact_mismatches"] == [0, 0]
+    assert result["checks"]["worst_rel_err"][0] < 1e-12
 
 
 def test_a_statement_that_fails_in_set_up_ends_the_run(

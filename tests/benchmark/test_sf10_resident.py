@@ -1,6 +1,6 @@
 """The configuration ``tpch-sf10-resident`` and its cell
-``tpch-sf10-join`` (PR 28): the needed-bytes figure against the
-generator's rows and the statement's columns, the cell's files, and the
+``tpch-sf10-join`` (PR 28): the needed-bytes figure computed from the
+configuration's rows and the statement's columns, the cell's files, and the
 two per-layer metrics that read the executor's ``spill`` spans."""
 
 import os
@@ -17,6 +17,7 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 import datagen  # noqa: E402
 import run as bench_run  # noqa: E402
+from needed_bytes import needed_bytes  # noqa: E402
 
 BENCH = os.path.join(ROOT, "benchmark")
 CONFIG = load_json(os.path.join(BENCH, "configs", "tpch-sf10-resident.json"))
@@ -24,14 +25,11 @@ SF1 = load_json(os.path.join(BENCH, "configs", "tpch-sf1-resident.json"))
 
 
 def test_needed_bytes_are_rows_times_logical_widths():
-    reads = load_json(os.path.join(BENCH, "queries", "tpch-q3.json"))["reads"]
-    widths = CONFIG["logical_widths_bytes"]
-    nominal = sum(CONFIG["rows"][t] * sum(widths[CONFIG["schema"][t][c]]
-                                          for c in cols)
-                  for t, cols in reads.items())
-    assert CONFIG["needed_bytes"] == {"tpch-q3": nominal}
-    assert nominal == 2_058_000_000 == 60_000_000 * 28 + 15_000_000 * 24 \
-        + 1_500_000 * 12
+    q3 = load_json(os.path.join(BENCH, "queries", "tpch-q3.json"))
+    assert "needed_bytes" not in CONFIG
+    assert needed_bytes(q3, CONFIG) == 2_058_000_000 == \
+        60_000_000 * 28 + 15_000_000 * 24 + 1_500_000 * 12
+    assert needed_bytes(q3, CONFIG) == 10 * needed_bytes(q3, SF1)
 
 
 def test_rows_are_the_generators_at_scale_factor_10():

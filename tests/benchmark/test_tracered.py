@@ -86,7 +86,6 @@ def test_gaps_are_labelled_by_the_calls_in_flight_and_their_phase():
     assert gaps["s0:q1/execute"] == pytest.approx(30e-9)
     assert gaps["s0:q6/execute+s1:q1/execute"] == pytest.approx(30e-9)
     assert sum(gaps.values()) + r["busy_s"] == pytest.approx(r["window_s"])
-    assert r["calls_in_window"] == 3
     no_phase = dict(tracered.reduce_trace(planes(ops, calls))["idle_gaps"])
     assert "s0:q1" in no_phase and "s0:q6+s1:q1" in no_phase
 
@@ -155,7 +154,9 @@ def test_the_recorded_trace_reduces(recorded):
     assert r["window_s"] == pytest.approx(4.0, abs=0.01)
     # 120 ops of a few hundred microseconds at most
     assert 0.005 < r["busy_s"] < 0.05
-    assert r["calls_in_window"] == 10
+    # the count of statements in the window is the readers' own
+    # (span_metrics.shares_in_window), on the wall clock
+    assert "calls_in_window" not in r
     assert all(name.startswith("jit_fn(") for name, _s in r["device_ops"])
     assert len(r["device_ops"]) == 10
     ranked = [s for _n, s in r["device_ops"]]
