@@ -2678,6 +2678,7 @@ class LocalExecutor:
             ufn, _ = self._jitted(ukey, dict_objs, ubuilder)
             out_dev = ufn((perm, sorted_keys, num_valid), (lo, cnt, usable),
                           left.device, build_payload)
+            _note_join_output(int(inner_total), left.device.capacity)
             out_dicts = merged_dicts if jt not in ("semi", "anti") else left.dicts
             return HostBatch(out_dev, out_dicts)
         return self._join_expand(p, left, right, bt, ranges, build_payload,
@@ -3000,6 +3001,7 @@ class LocalExecutor:
             if inner_total is None else inner_total
         cap = bucket_capacity(max(total, 1),
                               key=("join-expand", pst.node_fingerprint(p)))
+        _note_join_output(total, cap)
         res = joink.join_expand(bt, ranges, left.device, build_payload,
                                 "inner", list(build_payload.columns.keys()),
                                 cap)
@@ -3547,6 +3549,17 @@ def _spill_partition_ids(table: "pa.Table", idx, modes, nparts: int):
             part[null_mask] = _SPILL_NULL_HASH
         h = part if h is None else (h * np.uint64(31) + part)
     return (h % np.uint64(nparts)).astype(np.int64)
+
+
+def _note_join_output(rows: int, capacity: int) -> None:
+    """On the open ``op.JoinExec`` span: ``out_rows``, the inner matches
+    the ``join_phase`` sync fetched anyway (no sync of its own), and
+    ``out_capacity``, the rows the join's output batch is allocated for:
+    the expansion's bucket, or the probe's capacity where no build key
+    repeats. A plan that expands shows here and not only as time."""
+    from .. import tracing as tr
+    tr.set_attribute("out_rows", rows)
+    tr.set_attribute("out_capacity", capacity)
 
 
 def _rtf_est_rows(p: pn.PlanNode) -> float:

@@ -11,23 +11,32 @@ The pass runs after filter pushdown (so leaf filters are in place and
 implicit cross joins have been converted to inner joins with keys) and
 before column pruning (so the restoring projection gets pruned away).
 
-Cardinality model (no collected statistics yet — SURVEY.md §2.6
-sail-cache statistics cache is the eventual source):
+Cardinality model (its statistics are what scans already hold: row
+counts, ``ANALYZE TABLE`` numRows, and the Parquet footers of
+``io/cache.py METADATA_CACHE``; nothing is sampled or decoded):
 - scans: exact row counts for in-memory tables, parquet footer counts for
   parquet scans, a large default otherwise
 - filters: per-conjunct selectivity guesses (equality 0.05, IN 0.2,
   range 0.3, LIKE 0.25, other 0.25)
-- equi joins: |A ⋈ B| = |A|·|B| / Π_e max(ndv_a(e), ndv_b(e)), with
-  ndv of a key approximated by the unfiltered base rows of its leaf —
-  exact for PK/FK equi joins, conservative elsewhere
+- equi joins: |A ⋈ B| = |A|·|B| / Π_e min(ndv_a(e), ndv_b(e)): each
+  side's ndv is an UPPER bound of the key's distinct count, so the
+  smaller of the two is the tighter bound of what can match.
+  ``key_ndv`` is the one place that computes it: the unfiltered base
+  rows of the key's leaf (exact for PK/FK equi joins), cut down to what
+  the footers say where the key is a bare column of a Parquet scan —
+  the writer's distinct counts, or max − min + 1 of an integer, boolean
+  or date column. A nation key in 150,000 customer rows is 25, not
+  150,000.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import os
 import threading
 from collections import OrderedDict
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from ..spec import data_type as dt
 from . import nodes as pn
@@ -56,6 +65,8 @@ class _Edge:
     b: int
     a_expr: rx.Rex       # bound to leaf a's local schema
     b_expr: rx.Rex
+    a_ndv: "KeyNdv"      # key_ndv of each side's key
+    b_ndv: "KeyNdv"
 
 
 @dataclasses.dataclass
@@ -85,20 +96,34 @@ def _is_reorderable(j: pn.JoinExec) -> bool:
 
 
 def _reorder_tree(root: pn.JoinExec, est: EstFn = None) -> pn.PlanNode:
-    leaves: List[_Leaf] = []
-    edges: List[_Edge] = []
-    residuals: List[_Residual] = []
-    ok = _collect(root, leaves, edges, residuals, 0, est)
-    if not ok or len(leaves) < 3 or len(leaves) > 16:
-        # nothing to gain (or too odd a shape): recurse into children only
-        return dataclasses.replace(
-            root, left=reorder_joins(root.left, est),
-            right=reorder_joins(root.right, est))
-    order, plan = _greedy(leaves, edges, residuals)
-    if plan is None:
-        return dataclasses.replace(
-            root, left=reorder_joins(root.left, est),
-            right=reorder_joins(root.right, est))
+    """One maximal inner-join tree, under an ``optimize.join_reorder``
+    span that says what the model saw (``leaves``, ``edges``, how many
+    join keys a footer bounded and how many fell back to row counts)
+    and, where it reordered, what it chose (``order``, ``est_rows_max``:
+    the largest intermediate it expects)."""
+    from .. import tracing as tr
+    with tr.span("optimize.join_reorder") as sp, _one_expansion():
+        leaves: List[_Leaf] = []
+        edges: List[_Edge] = []
+        residuals: List[_Residual] = []
+        ok = _collect(root, leaves, edges, residuals, 0, est)
+        sources = [k.source for e in edges for k in (e.a_ndv, e.b_ndv)]
+        sp.attributes.update(
+            leaves=len(leaves), edges=len(edges),
+            keys_bounded=sources.count("footer"),
+            keys_by_rows=sources.count("rows"))
+        order = plan = None
+        if ok and 3 <= len(leaves) <= 16:
+            order, plan, est_rows_max = _greedy(leaves, edges, residuals)
+        if plan is None:
+            # nothing to gain (or too odd a shape, or a residual nothing
+            # binds): recurse into children only
+            return dataclasses.replace(
+                root, left=reorder_joins(root.left, est),
+                right=reorder_joins(root.right, est))
+        sp.attributes.update(
+            est_rows_max=est_rows_max,
+            order=",".join(_leaf_name(leaves[i].node) for i in order))
     # restore the original column order with an identity projection
     new_offsets: Dict[int, int] = {}
     pos = 0
@@ -133,10 +158,12 @@ def _collect(p: pn.PlanNode, leaves, edges, residuals, offset,
             if ea is None or eb is None:
                 # key spans leaves: keep this tree as written
                 return False
+            a_expr = rx.shift_refs(ga, -leaves[ea].offset)
+            b_expr = rx.shift_refs(gb, -leaves[eb].offset)
             edges.append(_Edge(
-                ea, eb,
-                rx.shift_refs(ga, -leaves[ea].offset),
-                rx.shift_refs(gb, -leaves[eb].offset)))
+                ea, eb, a_expr, b_expr,
+                key_ndv(leaves[ea].node, a_expr, leaves[ea].base_rows),
+                key_ndv(leaves[eb].node, b_expr, leaves[eb].base_rows)))
         if p.residual is not None:
             ge = rx.shift_refs(p.residual, offset)
             refs = rx.references(ge)
@@ -185,15 +212,111 @@ def _scan_rows(p: pn.ScanExec) -> float:
     if p.format == "parquet" and p.paths:
         try:
             from ..io.cache import METADATA_CACHE
-            from ..io.formats import expand_paths
-            # a catalog LOCATION is a directory — expand to data files
-            # so footer counts work for managed tables too
-            files = expand_paths(p.paths)
             return float(sum(METADATA_CACHE.num_rows(path)
-                             for path in files[:64]))
+                             for path in _scan_files(p)))
         except Exception:
             return _DEFAULT_ROWS
     return _DEFAULT_ROWS
+
+
+_EXPANDED = threading.local()
+
+
+@contextlib.contextmanager
+def _one_expansion():
+    """While one join tree is estimated, a scan's ``paths`` are expanded
+    once: its row count (asked for the leaf's rows and again for its
+    base rows) and the bounds of its join keys read the same listing."""
+    outer = getattr(_EXPANDED, "files", None)
+    _EXPANDED.files = {} if outer is None else outer
+    try:
+        yield
+    finally:
+        _EXPANDED.files = outer
+
+
+def _scan_files(p: pn.ScanExec) -> List[str]:
+    """The data files whose footers the model reads: ``p.paths``
+    expanded (a catalog LOCATION is a directory, so footer counts work
+    for managed tables too), the first 64 of them."""
+    memo = getattr(_EXPANDED, "files", None)
+    if memo is not None and p.paths in memo:
+        return memo[p.paths]
+    from ..io.formats import expand_paths
+    files = expand_paths(p.paths)[:64]
+    if memo is not None:
+        memo[p.paths] = files
+    return files
+
+
+class KeyNdv(NamedTuple):
+    """An upper bound of a join key's distinct count and where it came
+    from: ``footer`` (Parquet statistics) or ``rows`` (the leaf's
+    unfiltered row count, the fallback)."""
+
+    ndv: float
+    source: str
+
+
+def key_ndv(node: pn.PlanNode, key: rx.Rex, base_rows: float) -> KeyNdv:
+    """THE distinct-count bound of join key ``key`` (bound to ``node``'s
+    schema): ``base_rows``, the unfiltered rows of the key's leaf
+    ``node`` (``_base_rows``), cut down to what the Parquet footers say where ``key`` is a bare column reference that
+    Project/Filter chains pass unchanged from a Parquet scan. Over the
+    files ``_scan_rows`` counts (no other is opened, no column decoded):
+    the writer's ``distinct_count`` summed where every column chunk has
+    one, and max − min + 1 over all row groups for an integer, boolean
+    or date column; the smaller where both are there. Anything else — a
+    key that is an expression, a string, decimal or double column, a
+    file without statistics, an in-memory or non-Parquet leaf — keeps
+    the row count. An estimate's input only: a stale or missing footer
+    can skew a join order and never an answer."""
+    try:
+        bound = _footer_bound(node, key)
+    except Exception:  # noqa: BLE001 — estimation is advisory
+        bound = None
+    if bound is None:
+        return KeyNdv(base_rows, "rows")
+    return KeyNdv(max(min(base_rows, bound), 1.0), "footer")
+
+
+def _footer_bound(node: pn.PlanNode, key: rx.Rex) -> Optional[float]:
+    while isinstance(key, rx.BoundRef):
+        if isinstance(node, pn.FilterExec):
+            node = node.input
+        elif isinstance(node, pn.ProjectExec):
+            key, node = node.exprs[key.index][1], node.input
+        else:
+            break
+    if not (isinstance(key, rx.BoundRef) and isinstance(node, pn.ScanExec)
+            and node.format == "parquet" and node.paths):
+        return None
+    from ..io.cache import METADATA_CACHE
+    column = node.schema[key.index].name
+    stats = [METADATA_CACHE.column_stats(path, column)
+             for path in _scan_files(node)]
+    if not stats or any(st is None for st in stats):
+        return None
+    bounds = []
+    if all(st.distinct is not None for st in stats):
+        bounds.append(float(sum(st.distinct for st in stats)))
+    if all(st.lo is not None for st in stats):
+        span = max(st.hi for st in stats) - min(st.lo for st in stats)
+        bounds.append(float(getattr(span, "days", span)) + 1.0)
+    return min(bounds) if bounds else None
+
+
+def _leaf_name(p: pn.PlanNode) -> str:
+    """A leaf's table for the span's ``order``: its scan's name, or the
+    last part of its first path; ``?`` for a leaf that is no one scan."""
+    scans = [n for n in pn.walk_plan(p) if isinstance(n, pn.ScanExec)]
+    if len(scans) != 1:
+        return "?"
+    scan = scans[0]
+    if scan.table_name:
+        return scan.table_name
+    return os.path.basename(scan.paths[0].rstrip("/")) if scan.paths \
+        else "memory"
 
 
 def _conjunct_selectivity(c: rx.Rex) -> float:
@@ -366,13 +489,13 @@ def clear_observed_rows() -> None:
 # greedy ordering + tree construction
 # ---------------------------------------------------------------------------
 
-def _join_card(rows_a: float, rows_b: float,
-               ndvs: List[Tuple[float, float]]) -> float:
+def _join_card(rows_a: float, rows_b: float, es: List[_Edge]) -> float:
     card = rows_a * rows_b
-    for na, nb in ndvs:
-        # a join key's distinct count is bounded by the PK side's size:
-        # ndv(fk) ≈ ndv(pk) ≈ min(base_a, base_b)
-        card /= max(min(na, nb), 1.0)
+    for e in es:
+        # each side's key_ndv is an upper bound of the key's distinct
+        # count (the PK side's size, or what a footer says), and only
+        # values both sides hold can match: the smaller bound divides
+        card /= max(min(e.a_ndv.ndv, e.b_ndv.ndv), 1.0)
     return max(card, 1.0)
 
 
@@ -387,18 +510,17 @@ def _greedy(leaves: List[_Leaf], edges: List[_Edge], residuals):
     # seed: the connected pair with the smallest estimated join output
     best = None
     for (a, b), es in by_pair.items():
-        ndvs = [(leaves[e.a].base_rows, leaves[e.b].base_rows) for e in es]
-        card = _join_card(leaves[a].rows, leaves[b].rows, ndvs)
+        card = _join_card(leaves[a].rows, leaves[b].rows, es)
         if best is None or card < best[0]:
             best = (card, a, b)
     if best is None:
-        return None, None
+        return None, None, 0.0
     card, a, b = best
     if leaves[b].rows < leaves[a].rows:
         a, b = b, a  # smaller side leads (build side of the first join)
     order = [a, b]
     remaining -= {a, b}
-    cur_rows = card
+    cur_rows = est_rows_max = card
 
     while remaining:
         in_set = set(order)
@@ -409,9 +531,7 @@ def _greedy(leaves: List[_Leaf], edges: List[_Edge], residuals):
                   or (e.b == r and e.a in in_set)]
             if not es:
                 continue
-            ndvs = [(leaves[e.a].base_rows, leaves[e.b].base_rows)
-                    for e in es]
-            c = _join_card(cur_rows, leaves[r].rows, ndvs)
+            c = _join_card(cur_rows, leaves[r].rows, es)
             if cand is None or c < cand[0]:
                 cand = (c, r)
         if cand is None:
@@ -419,11 +539,12 @@ def _greedy(leaves: List[_Leaf], edges: List[_Edge], residuals):
             r = min(remaining, key=lambda i: leaves[i].rows)
             cand = (cur_rows * leaves[r].rows, r)
         cur_rows, r = cand
+        est_rows_max = max(est_rows_max, cur_rows)
         order.append(r)
         remaining.discard(r)
 
     plan = _build_tree(leaves, edges, residuals, order)
-    return order, plan
+    return order, plan, est_rows_max
 
 
 def _build_tree(leaves, edges, residuals, order):
