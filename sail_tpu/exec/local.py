@@ -426,9 +426,9 @@ def _runtime_cache_size(key: str, default: int) -> int:
 
 _RUNTIME_CACHE_SIZES: Dict[str, int] = {}
 _OP_CACHE = _OpCache()
-# runtime join filters: join-structure key → last observed prune ratio
-# (scan + probe pruning over probed rows); joins whose filters proved
-# useless skip the build on later executions (adaptive)
+# runtime join filters: join-structure key → last observed scan-site prune
+# ratio; joins whose filters proved useless skip the build on later
+# executions (adaptive)
 _RTF_HISTORY: Dict = {}
 
 
@@ -437,28 +437,18 @@ class _RtfConf(NamedTuple):
 
     enabled: bool
     min_build_rows: int
-    max_bits: int
     in_list_max: int
     ndv_ratio: float
     min_selectivity: float
 
 
 class _Rtf(NamedTuple):
-    """A built runtime filter, ready to mask the filtered side."""
+    """A runtime filter that was built and pushed into the other side's
+    scans: what ``_rtf_finish`` needs for the adaptive verdict."""
 
-    bits: object           # device bool[num_bits] bloom bit array
-    kmin: object           # device uint64 packed/hashed key bounds
-    kmax: object
-    ordinals: Tuple[int, ...]  # join-key ordinals folded into the bloom
-    num_bits: int
     fids: Tuple[int, ...]      # annotated filter ids (scan stat lookup)
     history_key: object        # adaptive-skip key (None if unhashable)
     pushed: int                # scan targets that received conjuncts
-    # False: built from the build (right) side, masks the probe side.
-    # True: built from the probe (left) side, masks the build side —
-    # the direction that wins when join reordering made the FACT table
-    # the build side of the topmost joins.
-    reverse: bool = False
 
 
 def clear_caches():
@@ -2234,11 +2224,11 @@ class LocalExecutor:
         """Run a join's children. For runtime-filter-annotated inner/semi
         joins the estimated-SMALLER side runs first; a filter derived
         from its keys is pushed into the other subtree's annotated scans
-        before that side executes, and a device bloom mask is handed to
-        ``_join`` for the filtered side's selection. Forward = build
-        (right) filters probe; reverse = probe (left) filters build —
-        the direction that matters when join reordering made the fact
-        table the build side of the topmost joins."""
+        before that side executes. Forward = build (right) filters
+        probe; reverse = probe (left) filters build — the direction that
+        matters when join reordering made the fact table the build side
+        of the topmost joins. The join itself runs unfiltered: the
+        returned ``_Rtf`` only carries the adaptive verdict's inputs."""
         conf = self._rtf_conf()
         use = (conf.enabled and p.runtime_filters and p.left_keys
                and p.join_type in ("inner", "semi") and not p.null_aware)
@@ -2295,9 +2285,6 @@ class LocalExecutor:
                                     apfx + "enabled", "true")),
             min_build_rows=as_int(setting(pfx + "minBuildRows",
                                           apfx + "min_build_rows", 0), 0),
-            max_bits=max(1024, as_int(setting(pfx + "maxBits",
-                                              apfx + "max_bits",
-                                              1 << 20), 1 << 20)),
             in_list_max=as_int(setting(pfx + "inListMax",
                                        apfx + "in_list_max", 8192), 8192),
             ndv_ratio=as_float(setting(pfx + "ndvRatio",
@@ -2336,9 +2323,10 @@ class LocalExecutor:
     def _rtf_prepare(self, p: pn.JoinExec, src: HostBatch,
                      conf: "_RtfConf", reverse: bool,
                      est_src, est_tgt):
-        """Build the runtime filter from the materialized SOURCE side
-        (build side forward, probe side reverse) and push value conjuncts
-        into the other subtree's annotated scans. Returns
+        """Derive the runtime filter (key bounds, counts and, for small
+        sources, the key values) from the materialized SOURCE side (build
+        side forward, probe side reverse) and push value conjuncts into
+        the other subtree's annotated scans. Returns
         (rtf-or-None, rewritten target subtree)."""
         import time as _time
 
@@ -2385,9 +2373,8 @@ class LocalExecutor:
             in hashk._KEY_BITS)
         if not ordinals:
             return None, target_plan
-        num_bits = conf.max_bits
         key = self._op_key("rtf_build", reverse, p.left_keys,
-                           p.right_keys, ordinals, num_bits,
+                           p.right_keys, ordinals,
                            tuple((f.name, f.dtype)
                                  for f in src_node.schema))
 
@@ -2405,7 +2392,7 @@ class LocalExecutor:
                     kcols.append(Column(d, v, kt))
                     if v is not None:
                         usable = usable & v
-                res = rtfk.build(kcols, ssel, num_bits)
+                res = rtfk.key_stats(kcols, ssel)
                 bounds = tuple(rtfk.column_bounds(c.data, usable)
                                for c in kcols)
                 datas = tuple(c.data for c in kcols)
@@ -2483,34 +2470,20 @@ class LocalExecutor:
         _record_metric("execution.runtime_filter.build_time", build_s)
         profiler.note_runtime_filter(built=1, pushed=pushed,
                                      build_ms=build_s * 1000.0)
-        rtf = _Rtf(bits=res.bits, kmin=res.kmin, kmax=res.kmax,
-                   ordinals=ordinals, num_bits=num_bits,
-                   fids=tuple(t.fid for t in targets),
-                   history_key=hkey, pushed=pushed, reverse=reverse)
+        rtf = _Rtf(fids=tuple(t.fid for t in targets),
+                   history_key=hkey, pushed=pushed)
         return rtf, target_plan
 
-    def _rtf_finish(self, rtf: "_Rtf", before: int, after: int) -> None:
-        """Post-join accounting: probe-mask pruning + adaptive history
-        (scan-site pruning for this join's fids folds in, so an effective
-        scan push does not read as a useless probe mask)."""
-        from .. import telemetry as tel
-
-        pruned = before - after
-        if pruned > 0:
-            _record_metric("execution.runtime_filter.rows_pruned", pruned,
-                           site="probe")
-            profiler.note_runtime_filter(rows_pruned=pruned)
-            if tel.current_collector() is not None:
-                tel.note("RuntimeFilter", "probe mask",
-                         rows_pruned=pruned, rows_in=before)
+    def _rtf_finish(self, rtf: "_Rtf") -> None:
+        """Post-join accounting: the adaptive history's verdict on this
+        join's filter, from the scan-site pruning of its fids."""
         # adaptive verdict: only SCAN-site pruning pays — fewer rows
         # decode/upload and every downstream kernel runs at the pruned
-        # capacity. The in-join selection mask prunes rows the join
-        # would reject anyway inside the SAME static-shape program, so a
-        # filter whose value conjuncts never landed at a scan is pure
-        # build overhead and stops rebuilding. Pushed-but-unmeasured
-        # scans (parquet behind static predicates) record NO verdict —
-        # the filter keeps building rather than being falsely condemned.
+        # capacity. A filter whose value conjuncts never landed at a
+        # scan is pure build overhead and stops rebuilding.
+        # Pushed-but-unmeasured scans (parquet behind static predicates)
+        # record NO verdict — the filter keeps building rather than
+        # being falsely condemned.
         ratio = 0.0
         measured = False
         for fid in rtf.fids:
@@ -2524,14 +2497,8 @@ class LocalExecutor:
             _RTF_HISTORY[rtf.history_key] = ratio
 
     def _compile_join_keys(self, p: pn.JoinExec, left: HostBatch, right: HostBatch,
-                           seed: int, rtf_sig=None):
+                           seed: int):
         """Builder for the jitted build+probe phase of an equi-join."""
-        # import OUTSIDE the traced fn: a first import during an active
-        # jit trace would execute the module body inside the trace and
-        # turn its module-level jnp constants (_KEY_MAX) into leaked
-        # tracers, poisoning every later join trace in the process
-        from ..ops import runtime_filter as rtfk
-
         def builder():
             lcomp = self._compiler(left, p.left.schema)
             rcomp = self._compiler(right, p.right.schema)
@@ -2548,7 +2515,7 @@ class LocalExecutor:
                     ktype = dt.IntegerType()
                 pairs.append((lc, rc, ktype, luts))
 
-            def fn(lcols, lsel, rcols, rsel, *rtf_args):
+            def fn(lcols, lsel, rcols, rsel):
                 lkeys, rkeys = [], []
                 for lc, rc, ktype, luts in pairs:
                     ld, lv = lc.fn(lcols)
@@ -2558,28 +2525,6 @@ class LocalExecutor:
                         rd = luts[1][rd]
                     lkeys.append(Column(ld, lv, ktype))
                     rkeys.append(Column(rd, rv, ktype))
-                rtf_before = rtf_after = jnp.int64(0)
-                if rtf_sig is not None:
-                    # runtime join filter: mask the filtered side's
-                    # selection with the source side's bloom before the
-                    # build/probe (fused into this program — the counts
-                    # ride the existing batched host fetch, no extra
-                    # sync). Forward masks the probe; reverse masks the
-                    # build (a masked build row's key has no probe
-                    # partner, so it could never match).
-                    bits, kmin, kmax = rtf_args
-                    if rtf_sig[2]:  # reverse
-                        sub = [rkeys[i] for i in rtf_sig[0]]
-                        masked = rtfk.apply(bits, kmin, kmax, sub, rsel)
-                        rtf_before = jnp.sum(rsel.astype(jnp.int64))
-                        rtf_after = jnp.sum(masked.astype(jnp.int64))
-                        rsel = masked
-                    else:
-                        sub = [lkeys[i] for i in rtf_sig[0]]
-                        masked = rtfk.apply(bits, kmin, kmax, sub, lsel)
-                        rtf_before = jnp.sum(lsel.astype(jnp.int64))
-                        rtf_after = jnp.sum(masked.astype(jnp.int64))
-                        lsel = masked
                 bt = joink.build_side(rkeys, rsel, seed)
                 ambiguous = joink.hash_ambiguous(bt, rkeys) if not bt.exact \
                     else jnp.asarray(False)
@@ -2589,8 +2534,7 @@ class LocalExecutor:
                 inner_total = joink.join_output_count(ranges, lsel, "inner")
                 return (bt.perm, bt.sorted_keys, bt.num_valid,
                         ranges.lo, ranges.cnt, ranges.usable,
-                        has_dup, ambiguous, inner_total, bt.exact,
-                        rtf_before, rtf_after)
+                        has_dup, ambiguous, inner_total, bt.exact)
 
             return fn, None
         return builder
@@ -2601,10 +2545,10 @@ class LocalExecutor:
         if spilled is not None:
             if rtf is not None:
                 # the spill path applies its own exact per-partition
-                # masks; the bloom goes unused, but the SCAN-site
-                # pruning already happened — record its verdict so a
-                # useless filter still shuts off adaptively
-                self._rtf_finish(rtf, 0, 0)
+                # masks; the SCAN-site pruning already happened —
+                # record its verdict so a useless filter still shuts
+                # off adaptively
+                self._rtf_finish(rtf)
             return spilled
         jt = p.join_type
         schema_key = (tuple((f.name, f.dtype) for f in p.left.schema),
@@ -2614,31 +2558,24 @@ class LocalExecutor:
         rcols, rsel = self._cols(right), right.device.sel
         import jax
 
-        rtf_sig = None if rtf is None else (rtf.ordinals, rtf.num_bits,
-                                            rtf.reverse)
-        rtf_args = () if rtf is None else (rtf.bits, rtf.kmin, rtf.kmax)
         for seed in range(4):
             key = self._op_key("join_phase", p.left_keys, p.right_keys, seed,
-                               schema_key, rtf_sig)
+                               schema_key)
             fn, _ = self._jitted(key, dict_objs,
-                                 self._compile_join_keys(p, left, right, seed,
-                                                         rtf_sig))
+                                 self._compile_join_keys(p, left, right, seed))
             (perm, sorted_keys, num_valid, lo, cnt, usable,
-             has_dup_a, ambiguous, inner_total, exact,
-             rtf_before, rtf_after) = fn(lcols, lsel, rcols, rsel, *rtf_args)
+             has_dup_a, ambiguous, inner_total, exact) = fn(
+                lcols, lsel, rcols, rsel)
             # one batched fetch for every host decision scalar (each
             # separate blocking read is a device round trip)
-            (has_dup_a, ambiguous, inner_total, exact, rtf_before,
-             rtf_after) = profiler.host_sync(
-                "join_phase",
-                (has_dup_a, ambiguous, inner_total, exact, rtf_before,
-                 rtf_after))
+            has_dup_a, ambiguous, inner_total, exact = profiler.host_sync(
+                "join_phase", (has_dup_a, ambiguous, inner_total, exact))
             if exact or not bool(ambiguous):
                 break
         else:
             raise ExecutionError("could not build unambiguous hash join")
         if rtf is not None:
-            self._rtf_finish(rtf, int(rtf_before), int(rtf_after))
+            self._rtf_finish(rtf)
         bt = joink.BuildTable(perm, sorted_keys, bool(exact), num_valid, seed)
         ranges = joink.MatchRanges(lo, cnt, usable)
         merged_dicts = dict(left.dicts)

@@ -1,24 +1,30 @@
-"""Runtime join-filter kernels (blocked bloom + min/max key bounds).
+"""Runtime join-filter kernels: what a join's source side says about its
+keys, for pruning the OTHER side's scans.
 
-Sideways information passing for equi-joins: after the build side of a
-join materializes, a compact filter derived from its keys prunes the
-probe side *upstream* — in probe-side scans (min/max and exact
-membership conjuncts for parquet row-group skipping and host-side Arrow
-filtering), in spill-join partition pairs, and as a device mask on the
-probe selection before ``probe_ranges``/``join_expand``.
+Sideways information passing for equi-joins: after one side of a join
+materializes, the executor derives value conjuncts from its keys — per
+column min/max bounds and, for small sources, the exact key list — and
+pushes them into the other side's annotated scans (parquet row-group
+skipping, host-side Arrow filtering, cluster task predicates). Fewer
+rows are decoded and uploaded, and every later kernel runs at the
+capacity the pruned scan leaves. This module computes the two things
+that push is decided and built from: the source's usable-row and
+distinct-key counts (``key_stats``) and each key column's bounds
+(``column_bounds``).
+
+There is no device-side membership mask: inside a join's static-shape
+program a selection mask shortens no sort and removes only rows the join
+would reject anyway (the spill path keeps its own exact host mask,
+``exec/local.py _spill_probe_mask``).
 
 Key derivation is shared with the join kernels (``ops/join._join_keys``):
-multi-column keys pack losslessly into one uint64 when they fit
-(exact — the only false positives are bloom collisions), otherwise the
-same seed-0 ``hash64`` both sides use. Equal keys on the two sides
-therefore always produce equal filter keys, so the filter NEVER yields a
-false negative; Spark key semantics (-0.0 ≡ 0.0, NaN ≡ NaN) ride the
-shared ``_to_bits`` normalization.
+multi-column keys pack losslessly into one uint64 when they fit,
+otherwise the same seed-0 ``hash64`` the join uses; Spark key semantics
+(-0.0 ≡ 0.0, NaN ≡ NaN) ride the shared ``_to_bits`` normalization, so
+the distinct count is over JOIN-equal keys.
 
 Reference role: DataFusion's dynamic filter pushdown / Spark's runtime
-bloom filter join rewrite, reshaped for XLA: the filter is a flat bool
-bit array built with three drop-mode scatters and probed with three
-gathers — static shapes, no host sync during build or apply.
+filter join rewrite, reduced to what a scan can use.
 """
 
 from __future__ import annotations
@@ -27,60 +33,22 @@ from typing import NamedTuple, Sequence
 
 import jax.numpy as jnp
 
-from .join import _join_keys
-
-_KEY_MAX = jnp.uint64(0xFFFFFFFFFFFFFFFF)
+from .join import _KEY_MAX, _join_keys
 
 
-def _mix(x: jnp.ndarray) -> jnp.ndarray:
-    """splitmix64 finalizer: packed keys are raw values (low entropy in
-    the low bits), so bit positions must come from a full-width mix."""
-    x = (x ^ (x >> jnp.uint64(30))) * jnp.uint64(0xBF58476D1CE4E5B9)
-    x = (x ^ (x >> jnp.uint64(27))) * jnp.uint64(0x94D049BB133111EB)
-    return x ^ (x >> jnp.uint64(31))
-
-
-def _positions(keys: jnp.ndarray, num_bits: int):
-    """Three bit positions per key from independent slices of the mix."""
-    m = _mix(keys)
-    b = jnp.uint64(num_bits)
-    p1 = (m % b).astype(jnp.int32)
-    p2 = ((m >> jnp.uint64(17)) % b).astype(jnp.int32)
-    p3 = ((m >> jnp.uint64(34)) % b).astype(jnp.int32)
-    return p1, p2, p3
-
-
-class BuildResult(NamedTuple):
-    bits: jnp.ndarray    # bool[num_bits] membership bit array
-    kmin: jnp.ndarray    # uint64 scalar: min packed/hashed key (usable rows)
-    kmax: jnp.ndarray    # uint64 scalar: max packed/hashed key
-    n_build: jnp.ndarray  # int32 scalar: usable build rows
+class KeyStats(NamedTuple):
+    n_build: jnp.ndarray  # int32 scalar: usable source rows
     ndv: jnp.ndarray     # int32 scalar: distinct keys among usable rows
-    exact: bool          # keys are lossless packs (no hash aliasing)
 
 
-def build(key_cols: Sequence, sel, num_bits: int, seed: int = 0
-          ) -> BuildResult:
-    """Build the filter from build-side key columns.
+def key_stats(key_cols: Sequence, sel) -> KeyStats:
+    """Usable-row and distinct-key counts of a join's source side.
 
     Dead/null-key rows are excluded: an equi-join key with any NULL part
-    never matches, so the filter may reject such probe rows outright.
+    never matches, so such rows say nothing about the other side.
     """
-    keys, usable, exact = _join_keys(key_cols, sel, seed=seed)
+    keys, usable, _ = _join_keys(key_cols, sel)
     n = keys.shape[0]
-    p1, p2, p3 = _positions(keys, num_bits)
-    # drop-mode scatter: dead rows aim one past the end
-    oob = jnp.int32(num_bits)
-    p1 = jnp.where(usable, p1, oob)
-    p2 = jnp.where(usable, p2, oob)
-    p3 = jnp.where(usable, p3, oob)
-    bits = jnp.zeros(num_bits, dtype=jnp.bool_)
-    on = jnp.ones(n, dtype=jnp.bool_)
-    bits = bits.at[p1].max(on, mode="drop")
-    bits = bits.at[p2].max(on, mode="drop")
-    bits = bits.at[p3].max(on, mode="drop")
-    kmin = jnp.min(jnp.where(usable, keys, _KEY_MAX))
-    kmax = jnp.max(jnp.where(usable, keys, jnp.uint64(0)))
     n_build = jnp.sum(usable.astype(jnp.int32))
     # distinct count over the usable prefix of the sorted keys
     skeys = jnp.sort(jnp.where(usable, keys, _KEY_MAX))
@@ -88,21 +56,7 @@ def build(key_cols: Sequence, sel, num_bits: int, seed: int = 0
     first = (pos == 0) | (skeys != jnp.concatenate(
         [skeys[:1], skeys[:-1]]))
     ndv = jnp.sum((first & (pos < n_build)).astype(jnp.int32))
-    return BuildResult(bits, kmin, kmax, n_build, ndv, exact)
-
-
-def apply(bits: jnp.ndarray, kmin, kmax, key_cols: Sequence, sel,
-          seed: int = 0) -> jnp.ndarray:
-    """Probe-side selection mask: keep rows whose key may be in the build
-    set. Rows with NULL key parts are rejected (they cannot equi-match).
-    Sound for inner/semi probe sides only — never apply to a side whose
-    unmatched rows survive (left/anti probes, outer builds)."""
-    keys, usable, _ = _join_keys(key_cols, sel, seed=seed)
-    num_bits = bits.shape[0]
-    p1, p2, p3 = _positions(keys, num_bits)
-    member = bits[p1] & bits[p2] & bits[p3]
-    in_range = (keys >= kmin) & (keys <= kmax)
-    return sel & usable & member & in_range
+    return KeyStats(n_build, ndv)
 
 
 def column_bounds(data: jnp.ndarray, usable: jnp.ndarray):

@@ -1,7 +1,8 @@
-"""Runtime join filters: kernel correctness (no false negatives,
-bounded false positives), plan-annotation lineage, on/off result
-equivalence across join types incl. NULL keys, scan-side pruning,
-EXPLAIN surfaces, cluster-mode filter shipping, and adaptive skips."""
+"""Runtime join filters: kernel correctness (usable-row and distinct-key
+counts, key bounds), plan-annotation lineage, on/off result equivalence
+across join types incl. NULL keys, scan-side pruning, one join-phase
+program with and without a filter, EXPLAIN surfaces, cluster-mode filter
+shipping, and adaptive skips."""
 
 import json
 
@@ -35,102 +36,77 @@ def _resolve(spark, sql):
 
 
 # ---------------------------------------------------------------------------
-# kernel: build/apply
+# kernel: key_stats / column_bounds
 # ---------------------------------------------------------------------------
 
-class TestKernel:
-    def _col(self, values, validity=None, dtype=None):
-        import jax.numpy as jnp
+def _long(values, validity=None, kind="long"):
+    return (kind, values, validity)
 
-        from sail_tpu.columnar.batch import Column
-        from sail_tpu.spec import data_type as dt
-        data = jnp.asarray(np.asarray(values))
-        v = None if validity is None else jnp.asarray(np.asarray(validity))
-        return Column(data, v, dtype or dt.LongType())
 
-    def test_no_false_negatives_ever(self):
-        import jax.numpy as jnp
-
-        from sail_tpu.ops import runtime_filter as rtfk
-        rng = np.random.default_rng(0)
-        build = rng.integers(-2**60, 2**60, 512)
-        bcol = self._col(build)
-        sel = jnp.ones(512, dtype=bool)
-        res = rtfk.build([bcol], sel, num_bits=4096)
-        # every build key must pass its own filter
-        mask = rtfk.apply(res.bits, res.kmin, res.kmax, [bcol], sel)
-        assert bool(jnp.all(mask))
-        assert int(res.n_build) == 512
-
-    def test_false_positive_rate_bounded(self):
-        import jax.numpy as jnp
-
-        from sail_tpu.ops import runtime_filter as rtfk
-        rng = np.random.default_rng(1)
-        build = rng.integers(0, 1_000, 256)  # narrow range
-        probe = rng.integers(2_000, 2**40, 4096)  # disjoint from build
-        bcol, pcol = self._col(build), self._col(probe)
-        res = rtfk.build([bcol], jnp.ones(256, dtype=bool),
-                         num_bits=1 << 16)
-        mask = rtfk.apply(res.bits, res.kmin, res.kmax, [pcol],
-                          jnp.ones(4096, dtype=bool))
-        fp_rate = float(jnp.mean(mask.astype(jnp.float32)))
-        assert fp_rate < 0.05, fp_rate
-
-    def test_null_probe_keys_rejected(self):
-        import jax.numpy as jnp
-
-        from sail_tpu.ops import runtime_filter as rtfk
-        bcol = self._col([1, 2, 3, 4])
-        res = rtfk.build([bcol], jnp.ones(4, dtype=bool), num_bits=1024)
-        pcol = self._col([1, 2, 3, 4], validity=[True, False, True, False])
-        mask = rtfk.apply(res.bits, res.kmin, res.kmax, [pcol],
-                          jnp.ones(4, dtype=bool))
-        assert list(np.asarray(mask)) == [True, False, True, False]
-
-    def test_empty_build_rejects_everything(self):
-        import jax.numpy as jnp
-
-        from sail_tpu.ops import runtime_filter as rtfk
-        bcol = self._col([7, 8, 9])
-        res = rtfk.build([bcol], jnp.zeros(3, dtype=bool), num_bits=1024)
-        assert int(res.n_build) == 0 and int(res.ndv) == 0
-        mask = rtfk.apply(res.bits, res.kmin, res.kmax,
-                          [self._col([7, 8, 9])],
-                          jnp.ones(3, dtype=bool))
-        assert not bool(jnp.any(mask))
-
-    def test_multi_column_keys_hashed_path(self):
-        # two int64 columns exceed 64 packed bits → hash64 path; equal
-        # tuples must still always pass (same seed both sides)
-        import jax.numpy as jnp
-
-        from sail_tpu.ops import runtime_filter as rtfk
-        rng = np.random.default_rng(2)
-        a = rng.integers(-2**62, 2**62, 128)
-        b = rng.integers(-2**62, 2**62, 128)
-        cols = [self._col(a), self._col(b)]
-        res = rtfk.build(cols, jnp.ones(128, dtype=bool), num_bits=8192)
-        assert res.exact is False
-        mask = rtfk.apply(res.bits, res.kmin, res.kmax, cols,
-                          jnp.ones(128, dtype=bool))
-        assert bool(jnp.all(mask))
-
-    def test_spark_float_key_semantics(self):
+#: (key columns, selection, n_build, ndv, per-column (min, max) or None
+#: where the build is empty and the bounds are sentinels with min > max)
+_KEY_STATS_CASES = {
+    "dead-rows": (
+        [_long([5, 5, 9, 7, 7, 3])],
+        [True, True, False, True, True, False],
+        4, 2, [(5, 7)]),
+    "null-key-parts": (
+        [_long([1, 2, 2, 4], [True, False, True, True]),
+         _long([10, 20, 20, 40], [True, True, True, False])],
+        [True] * 4,
+        2, 2, [(1, 2), (10, 20)]),
+    "empty-build": (
+        [_long([7, 8, 9])],
+        [False] * 3,
+        0, 0, None),
+    "multi-column-hashed": (
+        # two int64 columns exceed 64 packed bits → hash64 path
+        [_long([-2**62, 2**61, -2**62, 2**61, 5]),
+         _long([2**62 - 1, -2**60, 2**62 - 1, -2**60 + 1, 5])],
+        [True] * 5,
+        5, 4, [(-2**62, 2**61), (-2**60, 2**62 - 1)]),
+    "spark-float-keys": (
         # -0.0 and 0.0 are ONE key; NaN is ONE key (Spark join equality)
-        import jax.numpy as jnp
+        [_long([0.0, -0.0, np.nan, np.nan, 1.5], kind="double")],
+        [True] * 5,
+        5, 3, None),
+    "all-distinct": (
+        [_long(list(range(100, 164)))],
+        [True] * 64,
+        64, 64, [(100, 163)]),
+}
 
-        from sail_tpu.columnar.batch import Column
-        from sail_tpu.ops import runtime_filter as rtfk
-        from sail_tpu.spec import data_type as dt
-        bcol = Column(jnp.asarray(np.array([0.0, np.nan])), None,
-                      dt.DoubleType())
-        res = rtfk.build([bcol], jnp.ones(2, dtype=bool), num_bits=1024)
-        pcol = Column(jnp.asarray(np.array([-0.0, np.nan])), None,
-                      dt.DoubleType())
-        mask = rtfk.apply(res.bits, res.kmin, res.kmax, [pcol],
-                          jnp.ones(2, dtype=bool))
-        assert bool(jnp.all(mask))
+
+@pytest.mark.parametrize("case", sorted(_KEY_STATS_CASES))
+def test_key_stats_and_column_bounds(case):
+    """What ``_rtf_prepare`` reads of a join's source side: usable rows,
+    distinct JOIN-equal keys, and each key column's bounds over the
+    usable rows (dead rows and rows with a NULL key part excluded)."""
+    import jax.numpy as jnp
+
+    from sail_tpu.columnar.batch import Column
+    from sail_tpu.ops import runtime_filter as rtfk
+    from sail_tpu.spec import data_type as dt
+    cols_in, sel, n_build, ndv, bounds = _KEY_STATS_CASES[case]
+    cols = []
+    for kind, values, validity in cols_in:
+        dtype = dt.LongType() if kind == "long" else dt.DoubleType()
+        v = None if validity is None else jnp.asarray(np.asarray(validity))
+        cols.append(Column(jnp.asarray(np.asarray(values)), v, dtype))
+    sel = jnp.asarray(np.asarray(sel))
+    res = rtfk.key_stats(cols, sel)
+    assert res._fields == ("n_build", "ndv")  # no bit array, no key bounds
+    assert (int(res.n_build), int(res.ndv)) == (n_build, ndv)
+    usable = sel
+    for c in cols:
+        if c.validity is not None:
+            usable = usable & c.validity
+    got = [tuple(np.asarray(x).item() for x in
+                 rtfk.column_bounds(c.data, usable)) for c in cols]
+    if bounds is not None:
+        assert got == bounds
+    elif n_build == 0:
+        assert all(lo > hi for lo, hi in got)  # the empty-build sentinel
 
 
 # ---------------------------------------------------------------------------
@@ -303,6 +279,117 @@ def test_inner_join_results_bit_identical_with_pruning():
 
 
 # ---------------------------------------------------------------------------
+# the join phase takes no filter: one program, no mask, same rows
+# ---------------------------------------------------------------------------
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its
+    equations' parameters (pjit, while, cond, scan bodies)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _walk_eqns(inner)
+
+
+def test_one_join_phase_program_with_and_without_filter(monkeypatch):
+    import jax
+
+    from sail_tpu.exec import local as xl
+    captured = []
+    real = xl.LocalExecutor._compile_join_keys
+
+    def spy(self, p, left, right, seed):
+        builder = real(self, p, left, right, seed)
+
+        def recording_builder():
+            fn, aux = builder()
+
+            def recorded(*args):
+                captured.append((fn, jax.tree_util.tree_map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                    args)))
+                return fn(*args)
+            return recorded, aux
+        return recording_builder
+
+    monkeypatch.setattr(xl.LocalExecutor, "_compile_join_keys", spy)
+    sql = ("SELECT fact.k, fact.v, dim.flag FROM fact "
+           "JOIN dim ON fact.k = dim.id")
+    outs = {}
+    for mode in ("true", "false"):  # ONE process, caches kept between
+        spark = _session(**{"spark.sail.join.runtimeFilter.enabled": mode})
+        _register_star(spark)
+        outs[mode] = spark.sql(sql).toPandas().sort_values(
+            ["k", "v"]).reset_index(drop=True)
+        if mode == "true":
+            assert profiler.last_profile().rtf_pushed >= 1
+    assert outs["true"].equals(outs["false"])
+    phases = {key for key, _ident in xl._OP_CACHE.entries
+              if key[0] == "join_phase"}
+    assert len(phases) == 1, phases
+    assert captured
+    for fn, shapes in captured:
+        # probe columns, probe selection, build columns, build selection
+        assert len(shapes) == 4
+        eqns = list(_walk_eqns(jax.make_jaxpr(fn)(*shapes).jaxpr))
+        assert any(e.primitive.name == "sort" for e in eqns)
+        for e in eqns:
+            if e.primitive.name == "gather":
+                operand = e.invars[0].aval
+                assert operand.dtype != np.bool_, \
+                    f"a gather from a bool{operand.shape} array: the " \
+                    "bloom's bit probe is back in the join phase"
+
+
+@pytest.mark.parametrize("in_list_max", ["8192", "0"])
+@pytest.mark.parametrize("jt", ["inner", "semi"])
+def test_repeated_build_keys_outside_the_probe(jt, in_list_max):
+    """``has_duplicate_build_keys`` looks at the LIVE build rows. A build
+    whose repeated keys all miss the probe's key set read as unique
+    under the old in-join mask (``join_unique``); without it the join
+    takes ``join_expand`` wherever the scan push did not remove the
+    rows first (``inListMax=0``: bounds only, and the repeated keys lie
+    inside them). Both routes are exact: same rows filter on and off."""
+    from sail_tpu.exec import local as xl
+    rng = np.random.default_rng(21)
+    probe = pd.DataFrame({"id": np.arange(0, 2000, 2),
+                          "v": rng.random(1000)})
+    odd = np.arange(1, 2000, 2)
+    build = pd.DataFrame({
+        "k": np.concatenate([np.arange(0, 2000, 2), odd, odd, odd]),
+        "w": rng.random(1000 + 3 * len(odd))})
+    sql = ("SELECT small.id, small.v, big.w FROM small JOIN big "
+           "ON small.id = big.k") if jt == "inner" else \
+        ("SELECT small.id, small.v FROM small LEFT SEMI JOIN big "
+         "ON small.id = big.k")
+    outs, unique_route = {}, {}
+    for mode in ("true", "false"):
+        spark = _session(**{
+            "spark.sail.join.runtimeFilter.enabled": mode,
+            "spark.sail.join.runtimeFilter.inListMax": in_list_max})
+        clear_caches()
+        spark.createDataFrame(probe).createOrReplaceTempView("small")
+        spark.createDataFrame(build).createOrReplaceTempView("big")
+        outs[mode] = spark.sql(sql).toPandas()
+        unique_route[mode] = any(
+            key[0] == "join_unique" for key, _ident in xl._OP_CACHE.entries)
+        if mode == "true":
+            assert profiler.last_profile().rtf_built >= 1
+    cols = list(outs["true"].columns)
+    assert len(outs["true"]) == 1000
+    for mode in outs:
+        outs[mode] = outs[mode].sort_values(cols).reset_index(drop=True)
+    assert outs["true"].equals(outs["false"])
+    assert unique_route["false"] is False
+    # the exact in-list removes the repeated rows at the scan, so the
+    # build reads as unique; bounds alone keep them, and the join expands
+    assert unique_route["true"] is (in_list_max != "0")
+
+
+# ---------------------------------------------------------------------------
 # EXPLAIN ANALYZE surfaces
 # ---------------------------------------------------------------------------
 
@@ -336,13 +423,14 @@ def test_explain_analyze_json_includes_counters():
 # ---------------------------------------------------------------------------
 
 def test_first_join_trace_does_not_leak_module_constants():
-    """If the first-ever import of ops.runtime_filter lands while a
-    join phase program is being TRACED (possible when the first join of
-    the process skips the host-side filter build, e.g. filters
-    disabled), the module's jnp constants (_KEY_MAX) must NOT become
-    leaked tracers — that would poison every later join trace in the
-    process with UnexpectedTracerError. Locks the host-side import in
-    _compile_join_keys."""
+    """The first-ever import of ops.runtime_filter must not land while
+    a program is being TRACED: a module-level jnp constant created
+    inside a trace is a leaked tracer and poisons every later trace
+    that uses it with UnexpectedTracerError. A first join with filters
+    disabled never imports the module (the join phase does not use it);
+    a later join WITH filters imports it in _rtf_prepare, on the host,
+    before its rtf_build program is traced (the module holds no
+    constant of its own now: _KEY_MAX is ops/join.py's)."""
     import sys
 
     # simulate a fresh process: the kernels module was never imported
