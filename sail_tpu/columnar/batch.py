@@ -94,6 +94,14 @@ class DeviceBatch:
         """Dynamic live row count (device scalar)."""
         return jnp.sum(self.sel.astype(jnp.int32))
 
+    @property
+    def nbytes(self) -> int:
+        """What the batch holds on the device: every column's data
+        and validity and the selection, at the capacity (no sync)."""
+        return self.sel.nbytes + sum(
+            c.data.nbytes + (0 if c.validity is None else c.validity.nbytes)
+            for c in self.columns.values())
+
     def select(self, names) -> "DeviceBatch":
         return DeviceBatch({n: self.columns[n] for n in names}, self.sel)
 

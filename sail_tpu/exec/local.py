@@ -16,6 +16,7 @@ Host↔device sync points (kept deliberately few):
 from __future__ import annotations
 
 import dataclasses
+import datetime
 import os
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -718,6 +719,7 @@ class LocalExecutor:
             table_key = p.paths[0] if p.paths else None
         hit = rc.FRAGMENT_CACHE.get(cache_key, p.source)
         if hit is not None:
+            _note_scan(p, hit.batch, hit.rows, "hit")
             self._note_rtf_scan(p, hit.rtf_stats)
             profiler.note_result_cache(fragment=hit.fragment_id,
                                        nbytes=hit.nbytes)
@@ -741,6 +743,7 @@ class LocalExecutor:
                         (p.source is None or entry.source is p.source):
                     _record_metric(
                         "execution.scan_share.decode_passes_saved", 1)
+                    _note_scan(p, entry.batch, entry.rows, "shared")
                     self._note_rtf_scan(p, entry.rtf_stats)
                     profiler.note_result_cache(
                         status="shared-scan", fragment=entry.fragment_id,
@@ -771,6 +774,7 @@ class LocalExecutor:
             if flight is not None:
                 flight.fail(exc)
             raise
+        _note_scan(p, hb, table.num_rows, "decoded")
         self._note_rtf_scan(p, rtf_stats)
         try:
             nbytes = int(table.nbytes)
@@ -2439,7 +2443,8 @@ class LocalExecutor:
         else:
             bounds_by_ord = {i: host_bounds[oi]
                              for oi, i in enumerate(ordinals)}
-        pushed = 0
+        pushed = dropped = 0
+        listed = False
         for t in targets:
             if t.key not in bounds_by_ord:
                 continue
@@ -2452,10 +2457,19 @@ class LocalExecutor:
             if not rtfp.supports_bounds(field.dtype):
                 continue
             lo, hi = bounds_by_ord[t.key]
+            values = values_by_ord.get(t.key)
+            if values is None:
+                # bounds alone: the verdict _rtf_finish would reach after
+                # the scan decoded under them is reached before it, from
+                # the footers; conjuncts that cut next to nothing off the
+                # column's range stay out of the scan's fragment key
+                cut = _footer_cut_share(scan, field, int(lo), int(hi))
+                if cut is not None and cut < conf.min_selectivity:
+                    dropped += 1
+                    continue
             try:
                 conjs = rtfp.bounds_conjuncts(
-                    t.column, field, int(lo), int(hi),
-                    values_by_ord.get(t.key))
+                    t.column, field, int(lo), int(hi), values)
             except (OverflowError, ValueError):
                 continue  # out-of-range literal (exotic date values)
             new_scan = dataclasses.replace(
@@ -2463,8 +2477,15 @@ class LocalExecutor:
                 runtime_predicates=scan.runtime_predicates + conjs)
             target_plan = _replace_node(target_plan, scan, new_scan)
             pushed += 1
+            listed |= values is not None
             _record_metric("execution.runtime_filter.pushed_count", 1,
                            site="scan")
+        # on the open op.JoinExec span: why a list did or did not go out
+        from .. import tracing as tr
+        tr.set_attribute("rtf_ndv", ndv)
+        tr.set_attribute("rtf_listed", listed)
+        tr.set_attribute("rtf_pushed", pushed)
+        tr.set_attribute("rtf_dropped_by_footer", dropped)
         build_s = _time.perf_counter() - t0
         _record_metric("execution.runtime_filter.built_count", 1)
         _record_metric("execution.runtime_filter.build_time", build_s)
@@ -2636,12 +2657,16 @@ class LocalExecutor:
         inner/left/full/semi/anti are all partition-wise exact), bounding
         the join step's peak memory to one pair plus its expansion. NULL
         keys hash to one partition, preserving outer/anti semantics."""
+        if getattr(self, "_in_join_spill", False):
+            return None  # partition pairs run the in-memory join
+        from .. import tracing as tr
+        # what the join phase's sorts run at, whatever the live rows
+        tr.set_attribute("probe_capacity", left.device.capacity)
+        tr.set_attribute("build_capacity", right.device.capacity)
         if not p.left_keys or p.null_aware:
             return None
         if p.join_type not in ("inner", "left", "full", "semi", "anti"):
             return None
-        if getattr(self, "_in_join_spill", False):
-            return None  # partition pairs run the in-memory join
         if not all(isinstance(k, rx.BoundRef)
                    for k in (*p.left_keys, *p.right_keys)):
             # simple column refs only (the planner rewrites casts and
@@ -2663,7 +2688,6 @@ class LocalExecutor:
         n_left, n_right = int(n_left), int(n_right)
         if decision.by_rows and n_left + n_right <= decision.rows:
             return None
-        from .. import tracing as tr
         with tr.span("spill", {"kind": "join",
                                "rows": n_left + n_right}) as sp:
             out = self._partitioned_join(p, left, right, decision.rows,
@@ -3414,8 +3438,9 @@ def out_of_core(rows_key: str, capacity: int,
     free = device_free_bytes()
     if free is None:
         return None
-    tr.set_attribute("free_bytes", free)
     budget = int(free * _SPILL_FREE_SHARE)
+    tr.set_attribute("free_bytes", free)
+    tr.set_attribute("budget_bytes", budget)
     if working_set <= budget:
         return None
     # the rows whose share of the working set fits the budget
@@ -3488,6 +3513,21 @@ def _spill_partition_ids(table: "pa.Table", idx, modes, nparts: int):
     return (h % np.uint64(nparts)).astype(np.int64)
 
 
+def _note_scan(p: pn.ScanExec, hb: HostBatch, rows: int,
+               fragment: str) -> None:
+    """On the open ``op.ScanExec`` span: the fragment the scan runs on:
+    its ``rows``, the ``capacity`` and ``bytes`` its batch takes on the
+    device, whether this statement ``decoded`` it, found it in the
+    fragment cache (``hit``) or attached to another statement's decode
+    (``shared``), and how many ``runtime_conjuncts`` its key carries."""
+    from .. import tracing as tr
+    tr.set_attribute("rows", rows)
+    tr.set_attribute("capacity", hb.device.capacity)
+    tr.set_attribute("bytes", hb.device.nbytes)
+    tr.set_attribute("fragment", fragment)
+    tr.set_attribute("runtime_conjuncts", len(p.runtime_predicates))
+
+
 def _note_join_output(rows: int, capacity: int) -> None:
     """On the open ``op.JoinExec`` span: ``out_rows``, the inner matches
     the ``join_phase`` sync fetched anyway (no sync of its own), and
@@ -3497,6 +3537,37 @@ def _note_join_output(rows: int, capacity: int) -> None:
     from .. import tracing as tr
     tr.set_attribute("out_rows", rows)
     tr.set_attribute("out_capacity", capacity)
+
+
+def _footer_cut_share(scan: pn.ScanExec, field: pn.Field,
+                      lo: int, hi: int) -> Optional[float]:
+    """The share of a scan column's range that the closed bounds
+    [``lo``, ``hi``] (raw physical values: days for a date) cut off:
+    1 - overlap / (max - min + 1), the column's min and max read from
+    the footers of EVERY file the Parquet scan reads
+    (``METADATA_CACHE.column_stats``; no column decoded). None where
+    that cannot be said: a memory source, another format, a file whose
+    footer gives the column no min and max."""
+    if scan.source is not None or scan.format != "parquet":
+        return None
+    try:
+        from ..io.cache import METADATA_CACHE
+        from ..io.formats import expand_paths
+        stats = [METADATA_CACHE.column_stats(f, field.name)
+                 for f in expand_paths(scan.paths)]
+    except Exception:  # noqa: BLE001 — the statistics are advisory
+        return None
+    if not stats or any(st is None or st.lo is None for st in stats):
+        return None
+
+    def raw(v):
+        return (v - datetime.date(1970, 1, 1)).days \
+            if isinstance(v, datetime.date) else int(v)
+
+    fmin = min(raw(st.lo) for st in stats)
+    fmax = max(raw(st.hi) for st in stats)
+    overlap = max(0, min(hi, fmax) - max(lo, fmin) + 1)
+    return 1.0 - overlap / (fmax - fmin + 1)
 
 
 def _rtf_est_rows(p: pn.PlanNode) -> float:

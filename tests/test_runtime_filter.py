@@ -580,6 +580,170 @@ def test_parquet_filter_survives_adaptive_feedback(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# the footers' verdict: bounds that can prune nothing are not pushed
+# ---------------------------------------------------------------------------
+
+def _footer_case(tmp_path, dim_ids, in_list_max, statistics=True):
+    """``pfact JOIN dim`` over a Parquet fact whose ``k`` covers 0..999
+    and a 50-row dimension; returns the statement's profile."""
+    import pyarrow.parquet as pq
+    spark = _session(
+        **{"spark.sail.join.runtimeFilter.inListMax": str(in_list_max)})
+    rng = np.random.default_rng(36)
+    k = rng.integers(0, 1000, 20000)
+    k[:2] = (0, 999)
+    fp = str(tmp_path / "fact.parquet")
+    pq.write_table(pa.table({"k": k, "v": rng.random(20000)}), fp,
+                   write_statistics=statistics)
+    spark.read.parquet(fp).createOrReplaceTempView("pfact")
+    spark.createDataFrame(pd.DataFrame({"id": dim_ids})) \
+        .createOrReplaceTempView("dim")
+    got = spark.sql("SELECT COUNT(*) AS n FROM pfact JOIN dim "
+                    "ON pfact.k = dim.id").toPandas()
+    assert got.n[0] == int(np.isin(k, dim_ids).sum())
+    return profiler.last_profile()
+
+
+def _join_and_fact_scan(prof):
+    join, = [s.attributes for s in prof.spans if s.name == "op.JoinExec"]
+    scan = max((s.attributes for s in prof.spans
+                if s.name == "op.ScanExec"), key=lambda a: a["capacity"])
+    return join, scan
+
+
+WIDE = np.arange(0, 1000, 20)        # 50 keys over the whole of 0..999
+NARROW = np.arange(0, 300, 6)        # 50 keys in the lowest 30 %
+
+
+@pytest.mark.parametrize("dim_ids, in_list_max, statistics, conjuncts", [
+    pytest.param(WIDE, 10, True, 0, id="wide-bounds-are-not-pushed"),
+    pytest.param(NARROW, 10, True, 2, id="narrow-bounds-are-pushed"),
+    pytest.param(WIDE, 8192, True, 3, id="a-list-goes-with-its-bounds"),
+    pytest.param(WIDE, 10, False, 2, id="no-statistics-pushed-as-before"),
+])
+def test_bounds_the_footers_say_prune_nothing_are_not_pushed(
+        tmp_path, dim_ids, in_list_max, statistics, conjuncts):
+    prof = _footer_case(tmp_path, dim_ids, in_list_max, statistics)
+    join, scan = _join_and_fact_scan(prof)
+    assert scan["runtime_conjuncts"] == conjuncts
+    assert scan["fragment"] == "decoded" and scan["capacity"] >= scan["rows"]
+    assert join["rtf_ndv"] == 50
+    assert join["rtf_listed"] is (conjuncts == 3)
+    assert join["rtf_pushed"] == prof.rtf_pushed == (1 if conjuncts else 0)
+    assert join["rtf_dropped_by_footer"] == (0 if conjuncts else 1)
+    if conjuncts == 0:
+        assert scan["rows"] == 20000 and prof.rtf_rows_pruned == 0
+    elif statistics:
+        assert prof.rtf_rows_pruned == 20000 - scan["rows"] > 10000
+
+
+def test_the_footers_cut_share(tmp_path):
+    """1 - overlap / (max - min + 1) over EVERY file the scan reads; days
+    for a date; nothing where a file's footer lacks the column's range."""
+    import datetime
+    import pyarrow.parquet as pq
+    from sail_tpu.exec.local import _footer_cut_share
+    from sail_tpu.spec import data_type as dt
+    day = datetime.date(1994, 1, 1)
+    d = str(tmp_path / "t")
+    import os
+    os.mkdir(d)
+    for i, (lo, hi) in enumerate([(1, 500), (501, 1000)]):
+        pq.write_table(pa.table({
+            "k": np.arange(lo, hi + 1),
+            "d": [day + datetime.timedelta(days=int(x))
+                  for x in np.arange(lo, hi + 1)],
+            "s": [str(x) for x in range(lo, hi + 1)]}),
+            os.path.join(d, f"part-{i}.parquet"))
+    scan = pn.ScanExec(out_schema=(), format="parquet", paths=(d,))
+    k = pn.Field("k", dt.LongType(), True)
+    assert _footer_cut_share(scan, k, 1, 1000) == 0.0
+    assert _footer_cut_share(scan, k, -5, 5000) == 0.0
+    assert _footer_cut_share(scan, k, 1, 990) == pytest.approx(0.01)
+    assert _footer_cut_share(scan, k, 251, 750) == pytest.approx(0.5)
+    assert _footer_cut_share(scan, k, 1, 0) == 1.0      # the empty build
+    assert _footer_cut_share(scan, k, 2000, 3000) == 1.0
+    epoch = (day - datetime.date(1970, 1, 1)).days
+    dd = pn.Field("d", dt.DateType(), True)
+    assert _footer_cut_share(scan, dd, epoch + 1, epoch + 500) == \
+        pytest.approx(0.5)
+    assert _footer_cut_share(scan, pn.Field("s", dt.StringType(), True),
+                             1, 2) is None              # no range
+    assert _footer_cut_share(scan, pn.Field("absent", dt.LongType(), True),
+                             1, 2) is None
+    pq.write_table(pa.table({"k": np.arange(5)}),
+                   os.path.join(d, "part-2.parquet"), write_statistics=False)
+    from sail_tpu.io import cache as io_cache
+    io_cache.LISTING_CACHE.clear()
+    assert _footer_cut_share(scan, k, 1, 1000) is None  # one file is silent
+    assert _footer_cut_share(
+        pn.ScanExec(out_schema=(), format="csv", paths=(d,)),
+        k, 1, 1000) is None
+
+
+@pytest.fixture(scope="module")
+def q5_parquet(tmp_path_factory):
+    """Q5's six tables at SF0.05 as Parquet, by the benchmark's generator:
+    500 suppliers, about a hundred of them in ASIA."""
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    import datagen
+    qdir = os.path.join(root, "benchmark", "queries")
+    with open(os.path.join(qdir, "tpch-q5.json")) as f:
+        reads = json.load(f)["reads"]
+    with open(os.path.join(qdir, "tpch-q5.sql")) as f:
+        sql = f.read()
+    paths, frames, _rows, _bytes = datagen.write_tables(
+        reads, 2**31 + 36, 0.05, str(tmp_path_factory.mktemp("q5_sf005")),
+        workers=2)
+    return sql, paths, frames
+
+
+def test_q5_with_no_key_list_keeps_one_lineitem_fragment(q5_parquet):
+    """The regression (PR 36): with the ASIA suppliers over
+    ``inListMax``, as 20,000 are over 8,192 at SF10, only bounds reach
+    ``lineitem``'s scan, and the column's whole range satisfies them.
+    They used to ride the first statement's fragment key and, once
+    condemned, not the second's: ``lineitem`` was decoded, uploaded and
+    kept resident twice."""
+    from sail_tpu.exec.result_cache import FRAGMENT_CACHE
+    from tpch_oracle import ORACLES
+    sql, paths, frames = q5_parquet
+    asia = frames["nation"].merge(frames["region"][
+        frames["region"].r_name == "ASIA"], left_on="n_regionkey",
+        right_on="r_regionkey").n_nationkey
+    assert frames["supplier"].s_nationkey.isin(asia).sum() > 20
+    spark = _session(**{"spark.sail.cache.result.enabled": "false",
+                        "spark.sail.execution.backend.force": "xla",
+                        "spark.sail.join.runtimeFilter.inListMax": "20"})
+    for name, path in paths.items():
+        spark.read.parquet(path).createOrReplaceTempView(name)
+    exp = ORACLES[5](frames).reset_index(drop=True)
+    lineitem_conjuncts = []
+    for i in range(3):
+        got = spark.sql(sql).toPandas()
+        assert list(got.n_name) == list(exp.n_name)
+        np.testing.assert_allclose(got.revenue.astype(float), exp.revenue,
+                                   rtol=1e-10)
+        prof = profiler.last_profile()
+        scans = [s.attributes for s in prof.spans if s.name == "op.ScanExec"]
+        assert len(scans) == 6
+        lineitem = max(scans, key=lambda a: a["rows"])
+        assert lineitem["rows"] == len(frames["lineitem"])
+        lineitem_conjuncts.append(lineitem["runtime_conjuncts"])
+        if i:
+            assert prof.span_count("upload") == 0
+            assert {a["fragment"] for a in scans} == {"hit"}
+    assert lineitem_conjuncts == [0, 0, 0]
+    resident = [e for e in FRAGMENT_CACHE._entries.values()
+                if e.table_key == paths["lineitem"]]
+    assert len(resident) == 1 and resident[0].rows == len(frames["lineitem"])
+    assert len(FRAGMENT_CACHE._entries) == 6
+
+
+# ---------------------------------------------------------------------------
 # spill-join integration
 # ---------------------------------------------------------------------------
 
