@@ -171,81 +171,91 @@ def from_arrow(table: pa.Table, capacity: Optional[int] = None,
     ``bucket_key`` names the consuming program (structural cache key) so
     the pinned-bucket registry can hold the padded capacity stable
     across calls — see :func:`columnar.batch.bucket_capacity`."""
+    from .. import tracing as tr
     n = table.num_rows
     cap = capacity if capacity is not None else \
         bucket_capacity(n, key=bucket_key)
     columns: Dict[str, Tuple[np.ndarray, Optional[np.ndarray], dt.DataType]] = {}
     dicts: Dict[str, pa.Array] = {}
-    for name, col in zip(table.column_names, table.columns):
-        spec_t = arrow_type_to_spec(col.type)
-        arr = col.combine_chunks() if isinstance(col, pa.ChunkedArray) else col
-        validity = None
-        if arr.null_count > 0:
-            validity = np.asarray(pc.is_valid(arr))
-        if pa.types.is_uint64(arr.type):
-            mx = pc.max(arr).as_py()
-            if mx is not None and mx >= 2**63:
-                raise TypeError(
-                    f"column {name!r}: uint64 values >= 2^63 cannot be represented "
-                    f"on device (int64); cast to decimal or string first")
-        if isinstance(spec_t, (dt.StringType, dt.BinaryType)):
-            if pa.types.is_dictionary(arr.type):
-                denc = arr
+    # the host's share of the conversion; padding and device_put are
+    # make_batch's ``upload`` span, this one's sibling
+    with tr.span("arrow.convert", {"rows": n,
+                                   "columns": table.num_columns}) as sp:
+        strings = decimals = 0
+        for name, col in zip(table.column_names, table.columns):
+            spec_t = arrow_type_to_spec(col.type)
+            arr = col.combine_chunks() if isinstance(col, pa.ChunkedArray) else col
+            validity = None
+            if arr.null_count > 0:
+                validity = np.asarray(pc.is_valid(arr))
+            if pa.types.is_uint64(arr.type):
+                mx = pc.max(arr).as_py()
+                if mx is not None and mx >= 2**63:
+                    raise TypeError(
+                        f"column {name!r}: uint64 values >= 2^63 cannot be represented "
+                        f"on device (int64); cast to decimal or string first")
+            if isinstance(spec_t, (dt.StringType, dt.BinaryType)):
+                if pa.types.is_dictionary(arr.type):
+                    denc = arr
+                else:
+                    denc = pc.dictionary_encode(arr)
+                if isinstance(denc, pa.ChunkedArray):
+                    denc = denc.combine_chunks()
+                codes = np.asarray(denc.indices.fill_null(0)).astype(np.int32)
+                dicts[name] = denc.dictionary
+                columns[name] = (codes, validity, spec_t)
+                strings += 1
+            elif isinstance(spec_t, dt.DecimalType) and spec_t.physical_dtype == "int64":
+                if pa.types.is_decimal256(arr.type):
+                    arr = arr.cast(pa.decimal128(spec_t.precision, spec_t.scale))
+                vals = _decimal_to_unscaled_int64(arr, validity)
+                columns[name] = (vals, validity, spec_t)
+                decimals += 1
+            elif isinstance(spec_t, dt.DecimalType):
+                vals = np.asarray(arr.cast(pa.float64()).fill_null(0.0))
+                columns[name] = (vals, validity, spec_t)
+            elif isinstance(spec_t, dt.NullType):
+                columns[name] = (np.zeros(n, dtype=np.int8), np.zeros(n, dtype=bool), spec_t)
+            elif isinstance(spec_t, (dt.ArrayType, dt.StructType, dt.MapType)):
+                # Nested types stay host-side in v0: dictionary-encode the whole
+                # value so the device carries an opaque int32 handle.
+                import pickle
+                py = arr.to_pylist()
+                uniq: Dict[bytes, int] = {}
+                codes = np.empty(n, dtype=np.int32)
+                values = []
+                for i, v in enumerate(py):
+                    k = pickle.dumps(v)
+                    if k not in uniq:
+                        uniq[k] = len(values)
+                        values.append(v)
+                    codes[i] = uniq[k]
+                dicts[name] = pa.array(values, type=arr.type)
+                columns[name] = (codes, validity, spec_t)
             else:
-                denc = pc.dictionary_encode(arr)
-            if isinstance(denc, pa.ChunkedArray):
-                denc = denc.combine_chunks()
-            codes = np.asarray(denc.indices.fill_null(0)).astype(np.int32)
-            dicts[name] = denc.dictionary
-            columns[name] = (codes, validity, spec_t)
-        elif isinstance(spec_t, dt.DecimalType) and spec_t.physical_dtype == "int64":
-            if pa.types.is_decimal256(arr.type):
-                arr = arr.cast(pa.decimal128(spec_t.precision, spec_t.scale))
-            vals = _decimal_to_unscaled_int64(arr, validity)
-            columns[name] = (vals, validity, spec_t)
-        elif isinstance(spec_t, dt.DecimalType):
-            vals = np.asarray(arr.cast(pa.float64()).fill_null(0.0))
-            columns[name] = (vals, validity, spec_t)
-        elif isinstance(spec_t, dt.NullType):
-            columns[name] = (np.zeros(n, dtype=np.int8), np.zeros(n, dtype=bool), spec_t)
-        elif isinstance(spec_t, (dt.ArrayType, dt.StructType, dt.MapType)):
-            # Nested types stay host-side in v0: dictionary-encode the whole
-            # value so the device carries an opaque int32 handle.
-            import pickle
-            py = arr.to_pylist()
-            uniq: Dict[bytes, int] = {}
-            codes = np.empty(n, dtype=np.int32)
-            values = []
-            for i, v in enumerate(py):
-                k = pickle.dumps(v)
-                if k not in uniq:
-                    uniq[k] = len(values)
-                    values.append(v)
-                codes[i] = uniq[k]
-            dicts[name] = pa.array(values, type=arr.type)
-            columns[name] = (codes, validity, spec_t)
-        else:
-            # Temporal types upload as their epoch integers.
-            if isinstance(spec_t, dt.DateType):
-                if pa.types.is_date64(arr.type):
-                    arr = arr.cast(pa.date32())
-                arr = arr.view(pa.int32())
-            elif isinstance(spec_t, dt.TimestampType):
-                arr = arr.cast(pa.timestamp("us", tz=arr.type.tz)).view(pa.int64())
-            elif isinstance(spec_t, dt.DayTimeIntervalType):
-                arr = arr.cast(pa.duration("us")).view(pa.int64())
-            elif isinstance(spec_t, dt.TimeType):
-                arr = arr.cast(pa.time64("us")).view(pa.int64())
-            elif isinstance(spec_t, dt.YearMonthIntervalType) and \
-                    pa.types.is_interval(arr.type):
-                months = np.array(
-                    [0 if v is None else v[0] for v in arr.to_pylist()],
-                    dtype=np.int32)
-                columns[name] = (months, validity, spec_t)
-                continue
-            fill = False if pa.types.is_boolean(arr.type) else 0
-            np_vals = np.asarray(arr.fill_null(fill) if arr.null_count else arr)
-            columns[name] = (np_vals, validity, spec_t)
+                # Temporal types upload as their epoch integers.
+                if isinstance(spec_t, dt.DateType):
+                    if pa.types.is_date64(arr.type):
+                        arr = arr.cast(pa.date32())
+                    arr = arr.view(pa.int32())
+                elif isinstance(spec_t, dt.TimestampType):
+                    arr = arr.cast(pa.timestamp("us", tz=arr.type.tz)).view(pa.int64())
+                elif isinstance(spec_t, dt.DayTimeIntervalType):
+                    arr = arr.cast(pa.duration("us")).view(pa.int64())
+                elif isinstance(spec_t, dt.TimeType):
+                    arr = arr.cast(pa.time64("us")).view(pa.int64())
+                elif isinstance(spec_t, dt.YearMonthIntervalType) and \
+                        pa.types.is_interval(arr.type):
+                    months = np.array(
+                        [0 if v is None else v[0] for v in arr.to_pylist()],
+                        dtype=np.int32)
+                    columns[name] = (months, validity, spec_t)
+                    continue
+                fill = False if pa.types.is_boolean(arr.type) else 0
+                np_vals = np.asarray(arr.fill_null(fill) if arr.null_count else arr)
+                columns[name] = (np_vals, validity, spec_t)
+        sp.attributes["strings"] = strings
+        sp.attributes["decimals"] = decimals
     device = make_batch(columns, n, cap)
     return HostBatch(device, dicts)
 

@@ -2640,9 +2640,23 @@ class LocalExecutor:
                               expanded=False)
             out_dicts = merged_dicts if jt not in ("semi", "anti") else left.dicts
             return HostBatch(out_dev, out_dicts)
-        return self._join_expand(p, left, right, bt, ranges, build_payload,
-                                 build_names, merged_dicts,
-                                 inner_total=int(inner_total))
+        from .. import tracing as tr
+        total = int(inner_total)
+        cap = bucket_capacity(max(total, 1),
+                              key=("join-expand", pst.node_fingerprint(p)))
+        _note_join_output(total, cap, expanded=True)
+        # the expansion runs eagerly: no _jitted program holds it, so no
+        # dispatch span covers its gathers. Opened after the note, so the
+        # output attributes stay on op.JoinExec
+        with tr.span("join.expand", {
+                "probe_capacity": left.device.capacity,
+                "out_capacity": cap,
+                "columns": len(left.device.columns)
+                + len(build_payload.columns),
+                "join_type": jt,
+                "residual": p.residual is not None}):
+            return self._join_expand(p, left, bt, ranges, build_payload,
+                                     merged_dicts, cap)
 
     def _try_partitioned_join(self, p: pn.JoinExec, left: HostBatch,
                               right: HostBatch) -> Optional[HostBatch]:
@@ -2954,16 +2968,10 @@ class LocalExecutor:
         finally:
             shutil.rmtree(tmpdir, ignore_errors=True)
 
-    def _join_expand(self, p: pn.JoinExec, left: HostBatch, right: HostBatch,
-                     bt, ranges, build_payload, build_names, merged_dicts,
-                     inner_total=None) -> HostBatch:
+    def _join_expand(self, p: pn.JoinExec, left: HostBatch, bt, ranges,
+                     build_payload, merged_dicts, cap: int) -> HostBatch:
         jt = p.join_type
         n_left = len(p.left.schema)
-        total = int(joink.join_output_count(ranges, left.device.sel, "inner")) \
-            if inner_total is None else inner_total
-        cap = bucket_capacity(max(total, 1),
-                              key=("join-expand", pst.node_fingerprint(p)))
-        _note_join_output(total, cap, expanded=True)
         res = joink.join_expand(bt, ranges, left.device, build_payload,
                                 "inner", list(build_payload.columns.keys()),
                                 cap)

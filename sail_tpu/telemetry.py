@@ -3,9 +3,8 @@
 Reference role: sail-telemetry — fastrace spans around actors/RPC plus
 DataFusion operator metrics harvested into OTel gauges per {job, stage,
 partition, operator} (SURVEY.md §5). Here the executor wraps every operator
-with a metrics recorder (rows out, batch capacity, wall time) and exports
-through the opentelemetry-api when a provider is configured; without one,
-metrics stay queryable in-process via EXPLAIN ANALYZE.
+in an ``op.<name>`` span (tracing.py, the one recorder) and, under EXPLAIN
+ANALYZE, a metrics recorder (rows out, batch capacity, wall time).
 """
 
 from __future__ import annotations
@@ -17,12 +16,6 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from .metrics import record as _record_metric
-
-try:  # the api package is always importable; an SDK may or may not be wired
-    from opentelemetry import trace as _otel_trace
-    _TRACER = _otel_trace.get_tracer("sail_tpu")
-except Exception:  # pragma: no cover - otel not installed
-    _TRACER = None
 
 
 @dataclass
@@ -120,26 +113,14 @@ def operator_span(name: str, detail: str = ""):
         own: List[OperatorMetrics] = []
         _local.collector = own
         t0 = time.perf_counter()
-        span_cm = _TRACER.start_as_current_span(f"op:{name}") \
-            if _TRACER else None
-        if span_cm is not None:
-            span_cm.__enter__()
         try:
             yield m
-        except BaseException as e:
-            # aborted spans (e.g. a fused attempt that fell back) don't
-            # record metrics, but the OTel span must carry the exception
-            # and error status — exiting with the real exc_info makes
-            # start_as_current_span record the exception and set ERROR
-            # status; exiting with (None, None, None) silently reported
-            # failed operators as OK
-            if span_cm is not None:
-                span_cm.__exit__(type(e), e, e.__traceback__)
+        except BaseException:
+            # an aborted operator (a fused attempt that fell back) records
+            # no metrics; its op.<name> span ends with status_ok False
             _local.collector = parent
             raise
         else:
-            if span_cm is not None:
-                span_cm.__exit__(None, None, None)
             m.elapsed_ms = (time.perf_counter() - t0) * 1000
             m.children = own
             parent.append(m)

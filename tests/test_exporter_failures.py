@@ -1,6 +1,6 @@
 """OTLP exporter failure modes: an unreachable collector must never
 block or slow the query path, the bounded buffer drops with accounting,
-and failed operator spans carry the exception."""
+and a failed operator's ``op.<name>`` span ends not ok."""
 
 import json
 import socket
@@ -167,52 +167,45 @@ def test_drop_warning_once_per_signal_per_process(caplog):
         exp2.shutdown()
 
 
-class _FakeCM:
-    """Captures what operator_span hands to the OTel span context
-    manager — start_as_current_span records the exception and sets
-    ERROR status exactly when __exit__ receives real exc_info."""
+class _Sink:
+    """Keeps the finished spans of the tree it roots, as a
+    ``QueryProfile`` does."""
 
-    def __init__(self, events):
-        self._events = events
+    def __init__(self):
+        self.spans = []
 
-    def __enter__(self):
-        return object()
+    def admit_span(self, parent_recorded):
+        return parent_recorded
 
-    def __exit__(self, et, ev, tb):
-        self._events["exit"] = (et, ev, tb)
-
-
-class _FakeTracer:
-    def __init__(self, events):
-        self._events = events
-
-    def start_as_current_span(self, name):
-        self._events["name"] = name
-        return _FakeCM(self._events)
+    def add_span(self, span):
+        self.spans.append(span)
 
 
-def test_operator_span_exits_with_exception_info(monkeypatch):
+def _op_span(sink, name):
+    (span,) = [s for s in sink.spans if s.name == "op." + name]
+    return span
+
+
+def test_operator_span_fails_with_the_exception():
     from sail_tpu import telemetry as tel
 
-    events = {}
-    monkeypatch.setattr(tel, "_TRACER", _FakeTracer(events))
+    sink = _Sink()
     with pytest.raises(ValueError, match="boom"):
-        with tel.collect_metrics():
-            with tel.operator_span("Exploding"):
-                raise ValueError("boom")
-    et, ev, tb = events["exit"]
-    assert et is ValueError
-    assert isinstance(ev, ValueError) and str(ev) == "boom"
-    assert tb is not None  # full traceback reaches the span
+        with tr.span("query", sink=sink):
+            with tel.collect_metrics() as collected:
+                with tel.operator_span("Exploding"):
+                    raise ValueError("boom")
+    assert _op_span(sink, "Exploding").status_ok is False
+    assert collected == []  # an aborted operator records no metrics
 
 
-def test_operator_span_success_exits_clean(monkeypatch):
+def test_operator_span_success_ends_ok_and_collects():
     from sail_tpu import telemetry as tel
 
-    events = {}
-    monkeypatch.setattr(tel, "_TRACER", _FakeTracer(events))
-    with tel.collect_metrics() as collected:
-        with tel.operator_span("Fine") as m:
-            m.output_rows = 1
-    assert events["exit"] == (None, None, None)
-    assert len(collected) == 1
+    sink = _Sink()
+    with tr.span("query", sink=sink):
+        with tel.collect_metrics() as collected:
+            with tel.operator_span("Fine") as m:
+                m.output_rows = 1
+    assert _op_span(sink, "Fine").status_ok is True
+    assert len(collected) == 1 and collected[0].output_rows == 1
