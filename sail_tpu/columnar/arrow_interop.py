@@ -10,13 +10,20 @@ use of arrow-rs as the in-memory format (SURVEY.md §2.1 sail-common /
 - decimal128(p≤18) uploads as the *unscaled* int64 (exact arithmetic on
   device; the low 64 bits of the two's-complement decimal128 value equal
   the int64 value whenever it fits)
-- strings/binary dictionary-encode; codes upload, dictionary stays host-side
+- strings/binary dictionary-encode; codes upload, dictionary stays host-side.
+  A dictionary of at most ``INTERN_MAX_VALUES`` values is put in sorted
+  order and interned by content (``DICTIONARIES``), so tables that hold the
+  same value set share one dictionary object whatever order their rows
+  came in
 """
 
 from __future__ import annotations
 
 import datetime
 import decimal
+import hashlib
+import threading
+from collections import OrderedDict
 from typing import Dict, Optional, Tuple
 
 import numpy as np
@@ -164,6 +171,93 @@ def _unscaled_int64_to_decimal(vals: np.ndarray, validity: Optional[np.ndarray],
                                  [null_buf, data_buf])
 
 
+#: a string or binary dictionary of at most this many values is sorted and
+#: interned; a larger one (``l_comment``-like columns, whose values never
+#: repeat across chunks) stays as the encoder made it
+INTERN_MAX_VALUES = 65_536
+#: what the intern table holds at most, in values summed over its
+#: dictionaries; past it the least recently used go
+INTERN_CAPACITY_VALUES = 1 << 20
+
+
+def _content_key(values: pa.Array) -> tuple:
+    """(type, length, digest of the value lengths and bytes): equal for
+    two null-free string/binary arrays of equal values, whatever their
+    offsets into their buffers."""
+    n = len(values)
+    if n == 0:
+        return (str(values.type), 0, b"")
+    large = pa.types.is_large_string(values.type) or \
+        pa.types.is_large_binary(values.type)
+    _validity, offsets, data = values.buffers()
+    off = np.frombuffer(offsets, dtype=np.int64 if large else np.int32)[
+        values.offset: values.offset + n + 1]
+    h = hashlib.blake2b(np.diff(off).tobytes(), digest_size=16)
+    if data is not None:
+        h.update(memoryview(data)[int(off[0]):int(off[-1])])
+    return (str(values.type), n, h.digest())
+
+
+class DictionaryInterner:
+    """One ``pa.Array`` per dictionary content, bounded as an LRU by the
+    values it holds. The op cache keys a program on the identity of the
+    dictionaries it was bound to (``exec/local.py _OpCache``), so tables
+    with one value set hit one entry only when they hand it one object:
+    every chunk of a streamed scan, every merge of their partials."""
+
+    def __init__(self, capacity_values: int = INTERN_CAPACITY_VALUES):
+        self._capacity = capacity_values
+        self._lock = threading.Lock()
+        self._entries: "OrderedDict[tuple, pa.Array]" = OrderedDict()
+        self._values = 0
+
+    def intern(self, values: pa.Array) -> Tuple[pa.Array, bool]:
+        """The stored array equal to ``values`` and True, or ``values``,
+        stored now, and False."""
+        key = _content_key(values)
+        with self._lock:
+            hit = self._entries.get(key)
+            if hit is not None:
+                if not hit.equals(values):  # a digest collision
+                    return values, False
+                self._entries.move_to_end(key)
+                return hit, True
+            self._entries[key] = values
+            self._values += len(values)
+            while self._values > self._capacity and len(self._entries) > 1:
+                _key, old = self._entries.popitem(last=False)
+                self._values -= len(old)
+            return values, False
+
+    def clear(self) -> None:
+        with self._lock:
+            self._entries.clear()
+            self._values = 0
+
+
+#: the process's intern table: sessions share it, as they share the op cache
+DICTIONARIES = DictionaryInterner()
+
+
+def _canonical_dictionary(dictionary: pa.Array, indices: pa.Array
+                          ) -> Tuple[pa.Array, pa.Array, bool]:
+    """``dictionary`` in sorted order and interned, the null-free
+    ``indices`` remapped to it, and whether the dictionary came out of the
+    intern table. A dictionary over ``INTERN_MAX_VALUES`` values or
+    holding a null comes back as it is."""
+    if len(dictionary) > INTERN_MAX_VALUES or dictionary.null_count:
+        return dictionary, indices, False
+    order = np.asarray(pc.sort_indices(dictionary))
+    if (order != np.arange(len(order))).any():
+        dictionary = dictionary.take(order)
+        rank = np.empty(len(order), dtype=np.int32)
+        rank[order] = np.arange(len(order), dtype=np.int32)
+        # Arrow's take: half of numpy's fancy indexing over a chunk's rows
+        indices = pc.take(pa.array(rank), indices)
+    dictionary, hit = DICTIONARIES.intern(dictionary)
+    return dictionary, indices, hit
+
+
 def from_arrow(table: pa.Table, capacity: Optional[int] = None,
                bucket_key=None) -> HostBatch:
     """Convert a pyarrow Table to a HostBatch (uploads to default device).
@@ -181,7 +275,7 @@ def from_arrow(table: pa.Table, capacity: Optional[int] = None,
     # make_batch's ``upload`` span, this one's sibling
     with tr.span("arrow.convert", {"rows": n,
                                    "columns": table.num_columns}) as sp:
-        strings = decimals = 0
+        strings = decimals = dicts_interned = 0
         for name, col in zip(table.column_names, table.columns):
             spec_t = arrow_type_to_spec(col.type)
             arr = col.combine_chunks() if isinstance(col, pa.ChunkedArray) else col
@@ -201,10 +295,12 @@ def from_arrow(table: pa.Table, capacity: Optional[int] = None,
                     denc = pc.dictionary_encode(arr)
                 if isinstance(denc, pa.ChunkedArray):
                     denc = denc.combine_chunks()
-                codes = np.asarray(denc.indices.fill_null(0)).astype(np.int32)
-                dicts[name] = denc.dictionary
-                columns[name] = (codes, validity, spec_t)
+                dicts[name], codes, interned = _canonical_dictionary(
+                    denc.dictionary, denc.indices.fill_null(0))
+                # make_batch copies the codes into the padded int32 column
+                columns[name] = (np.asarray(codes), validity, spec_t)
                 strings += 1
+                dicts_interned += interned
             elif isinstance(spec_t, dt.DecimalType) and spec_t.physical_dtype == "int64":
                 if pa.types.is_decimal256(arr.type):
                     arr = arr.cast(pa.decimal128(spec_t.precision, spec_t.scale))
@@ -256,6 +352,7 @@ def from_arrow(table: pa.Table, capacity: Optional[int] = None,
                 columns[name] = (np_vals, validity, spec_t)
         sp.attributes["strings"] = strings
         sp.attributes["decimals"] = decimals
+        sp.attributes["dicts_interned"] = dicts_interned
     device = make_batch(columns, n, cap)
     return HostBatch(device, dicts)
 

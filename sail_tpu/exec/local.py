@@ -317,8 +317,11 @@ class _OpCache:
     bind-time closures bake host lookup tables derived from dictionaries, so
     a cached entry is valid exactly while the same dictionary objects flow
     in — the entry holds strong references and verifies identity on hit.
-    Combined with the scan cache (stable dictionaries per table), repeated
-    queries of the same shape skip both tracing and XLA compilation.
+    Combined with the scan cache (stable dictionaries per table) and the
+    interning of small dictionaries by content (``arrow_interop.
+    DICTIONARIES``: one object for every streamed chunk of a column),
+    repeated queries of the same shape skip both tracing and XLA
+    compilation.
     """
 
     def __init__(self, max_entries: Optional[int] = None):
@@ -455,6 +458,7 @@ class _Rtf(NamedTuple):
 def clear_caches():
     from . import capacity, result_cache, retrace
     _OP_CACHE.entries.clear()
+    ai.DICTIONARIES.clear()
     _RTF_HISTORY.clear()
     _RUNTIME_CACHE_SIZES.clear()
     result_cache.clear_all()

@@ -66,6 +66,96 @@ def test_arrow_roundtrip_strings_dates_decimals():
     assert ts[1] is None
 
 
+def _decoded(batch, name):
+    return ai.to_arrow(batch).column(name).to_pylist()
+
+
+_WIDE = [f"v{i:06d}" for i in range(ai.INTERN_MAX_VALUES + 1)]
+
+
+@pytest.mark.parametrize("first,second,same", [
+    # one value set in two first-appearance orders, nulls among them
+    (pa.array(["x", "y", None, "z", "x"]),
+     pa.array(["z", None, "y", "x", "z"]), True),
+    (pa.array(["b", "a", None], pa.large_string()),
+     pa.array(["a", None, "b"], pa.large_string()), True),
+    (pa.array([b"\x02", b"\x01", b""], pa.binary()),
+     pa.array([b"", b"\x02", b"\x01"], pa.binary()), True),
+    # an already-dictionary-typed column is canonicalised too
+    (pa.array(["y", "x", "z"]),
+     pa.DictionaryArray.from_arrays(pa.array([0, None, 2, 1], pa.int8()),
+                                    pa.array(["z", "y", "x"])), True),
+    # another value set, another object
+    (pa.array(["x", "y"]), pa.array(["x", "y", "w"]), False),
+    # over the limit: left as the encoder made it, not interned
+    (pa.array(_WIDE[1::2] + _WIDE[::2]), pa.array(_WIDE[::-1]), False),
+], ids=["string", "large_string", "binary", "dictionary_typed",
+        "other_values", "over_limit"])
+def test_small_dictionaries_are_sorted_and_interned_by_content(
+        first, second, same):
+    ai.DICTIONARIES.clear()
+    a = ai.from_arrow(pa.table({"s": first}))
+    b = ai.from_arrow(pa.table({"s": second}))
+    assert (a.dicts["s"] is b.dicts["s"]) is same
+    for batch, arr in ((a, first), (b, second)):
+        # codes remapped with their dictionary: every row decodes back
+        assert _decoded(batch, "s") == arr.cast(
+            arr.type.value_type if pa.types.is_dictionary(arr.type)
+            else arr.type).to_pylist()
+        values = batch.dicts["s"].to_pylist()
+        assert (values == sorted(values)) is \
+            (len(values) <= ai.INTERN_MAX_VALUES)
+
+
+def test_intern_table_is_bounded_by_values_and_checks_content(monkeypatch):
+    table = ai.DictionaryInterner(capacity_values=5)
+    d1, d2 = pa.array(["a", "b", "c"]), pa.array(["d", "e", "f"])
+
+    def interned(values):
+        got, hit = table.intern(values)
+        return (got is d1) + 2 * (got is d2), hit
+
+    assert interned(d1) == (1, False)
+    assert interned(pa.array(["a", "b", "c"])) == (1, True)
+    assert interned(d2) == (2, False)  # 6 values held: d1 goes
+    assert interned(pa.array(["a", "b", "c"])) == (0, False)
+    # equal digests of unequal contents never hand out the stored array
+    monkeypatch.setattr(ai, "_content_key", lambda values: ("k",))
+    table.clear()
+    assert interned(d1) == (1, False)
+    assert interned(d2) == (2, False)
+    assert interned(pa.array(["a", "b", "c"])) == (1, True)
+
+
+def test_concurrent_conversions_share_one_dictionary():
+    import sys
+    import threading
+
+    ai.DICTIONARIES.clear()
+    orders = [["c", "a", "b"], ["b", "c", "a"], ["a", "b", "c"]] * 4
+    got = [None] * len(orders)
+    start = threading.Barrier(len(orders))
+
+    def convert(i):
+        start.wait(timeout=30)
+        got[i] = ai.from_arrow(pa.table({"s": orders[i] * 50})).dicts["s"]
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=convert, args=(i,))
+                   for i in range(len(orders))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert all(d is got[0] for d in got)
+    assert got[0].to_pylist() == ["a", "b", "c"]
+
+
 def test_dictionary_unify_and_ranks():
     a = pa.array(["b", "a"]).dictionary_encode().dictionary
     b = pa.array(["c", "a"]).dictionary_encode().dictionary

@@ -16,7 +16,7 @@ import pyarrow.parquet as pq
 import pytest
 
 from sail_tpu import SparkSession, profiler
-from sail_tpu.exec.local import clear_caches
+from sail_tpu.exec.local import _OP_CACHE, clear_caches
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
@@ -29,7 +29,7 @@ EXPAND_ATTRS = {"probe_capacity", "out_capacity", "columns", "join_type",
 #: what stays on op.JoinExec
 JOIN_ATTRS = {"out_rows", "out_capacity", "expanded", "probe_capacity",
               "build_capacity"}
-CONVERT_ATTRS = {"rows", "columns", "strings", "decimals"}
+CONVERT_ATTRS = {"rows", "columns", "strings", "decimals", "dicts_interned"}
 
 OPTIONS = {"spark.sail.execution.mesh": "off",
            "spark.sail.cache.result.enabled": "false",
@@ -140,8 +140,9 @@ def test_a_chunked_scan_converts_each_chunk_once_beside_its_upload(
     path = str(tmp_path / "lineitem")
     pq.write_to_dataset(_lineitem(), path)
     spark.read.parquet(path).createOrReplaceTempView("lineitem")
-    got = spark.sql("SELECT l_shipmode, sum(l_extendedprice * l_discount) "
-                    "AS r FROM lineitem GROUP BY l_shipmode").toArrow()
+    sql = ("SELECT l_shipmode, sum(l_extendedprice * l_discount) "
+           "AS r FROM lineitem GROUP BY l_shipmode")
+    got = spark.sql(sql).toArrow()
     assert got.num_rows == 4
     spans = list(profiler.last_profile().spans)
     by_id = {s.span_id: s for s in spans}
@@ -162,4 +163,20 @@ def test_a_chunked_scan_converts_each_chunk_once_beside_its_upload(
         assert c.end_ns <= u.start_ns
     ids = {c.span_id for c in converts}
     assert not any(s.parent_id in ids for s in spans if s.name == "upload")
+    # every chunk after the first, and the merge, finds the column's
+    # dictionary in the intern table
+    assert sum(c.attributes["dicts_interned"] for c in converts) == \
+        sum(c.attributes["strings"] for c in converts) - 1
+    # so a statement again hits the programs of the first and holds no
+    # more of its chunks
+    entries = len(_OP_CACHE.entries)
+    allocated = pa.total_allocated_bytes()
+    for _ in range(2):
+        assert spark.sql(sql).toArrow().equals(got)
+        assert len(_OP_CACHE.entries) == entries
+        converts = [s for s in profiler.last_profile().spans
+                    if s.name == "arrow.convert"]
+        assert all(c.attributes["dicts_interned"] == c.attributes["strings"]
+                   for c in converts)
+    assert abs(pa.total_allocated_bytes() - allocated) <= 0.03 * allocated
     clear_caches()
