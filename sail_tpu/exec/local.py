@@ -1837,23 +1837,8 @@ class LocalExecutor:
                 # domain (dictionary codes / booleans) — no sort needed.
                 # Decided at bind time; the cache key's dictionary identity
                 # pins the decision's inputs.
-                domains = []
-                for gi in p.group_indices:
-                    f = in_schema[gi]
-                    name = _col_name(gi)
-                    if name in top_dicts:
-                        domains.append(len(top_dicts[name]))
-                    elif isinstance(f.dtype, dt.BooleanType):
-                        domains.append(2)
-                    else:
-                        domains.append(None)
-                direct_total = 1
-                for d in domains:
-                    direct_total = direct_total * (d + 1) if d is not None else None
-                    if direct_total is None:
-                        break
-                use_direct = (p.group_indices and direct_total is not None
-                              and direct_total <= 4096)
+                domains = _direct_domains(p, in_schema, top_dicts)
+                use_direct = domains is not None
 
                 # min/max over a dictionary-encoded column must order by
                 # VALUE, not code: remap codes through an order-preserving
@@ -1924,6 +1909,18 @@ class LocalExecutor:
                                           fused=bool(chain))
             gk, aggs_out, gsel, n_groups, overflow = fn2(self._cols(child), dev.sel)
             n_groups = profiler.host_sync("agg2.n_groups", n_groups)
+        # on the open aggregate span: the grouping route the program took,
+        # the rows it ran over and the groups it found
+        from .. import tracing as tr
+        if not p.group_indices:
+            path = aggk.GLOBAL
+        elif _direct_domains(p, in_schema, top_dicts) is not None:
+            path = aggk.DIRECT
+        else:
+            path = aggk.SORTED
+        tr.set_attribute("path", path)
+        tr.set_attribute("input_capacity", dev.capacity)
+        tr.set_attribute("groups", int(n_groups))
         out_cols: Dict[str, Column] = {}
         out_dicts: Dict[str, pa.Array] = {}
         for j, gi in enumerate(p.group_indices):
@@ -2331,14 +2328,13 @@ class LocalExecutor:
     def _rtf_prepare(self, p: pn.JoinExec, src: HostBatch,
                      conf: "_RtfConf", reverse: bool,
                      est_src, est_tgt):
-        """Derive the runtime filter (key bounds, counts and, for small
-        sources, the key values) from the materialized SOURCE side (build
-        side forward, probe side reverse) and push value conjuncts into
-        the other subtree's annotated scans. Returns
-        (rtf-or-None, rewritten target subtree)."""
+        """Derive the runtime filter (key bounds, counts and, for a
+        source with at most ``in_list_max`` usable rows, the key values)
+        from the materialized SOURCE side (build side forward, probe side
+        reverse) and push value conjuncts into the other subtree's
+        annotated scans. Returns (rtf-or-None, rewritten target
+        subtree)."""
         import time as _time
-
-        import jax
 
         from ..ops import hash as hashk
         from ..ops import runtime_filter as rtfk
@@ -2381,8 +2377,14 @@ class LocalExecutor:
             in hashk._KEY_BITS)
         if not ordinals:
             return None, target_plan
+        # the usable rows' keys leave the device in a bucket of at most
+        # in_list_max rows, whatever the source's capacity: a list is
+        # decided by how many rows the source keeps (a HAVING filter over
+        # a 1.5M-group aggregate keeps a handful at the aggregate's
+        # capacity)
+        bucket = max(0, min(conf.in_list_max, src.device.capacity))
         key = self._op_key("rtf_build", reverse, p.left_keys,
-                           p.right_keys, ordinals,
+                           p.right_keys, ordinals, bucket,
                            tuple((f.name, f.dtype)
                                  for f in src_node.schema))
 
@@ -2403,27 +2405,21 @@ class LocalExecutor:
                 res = rtfk.key_stats(kcols, ssel)
                 bounds = tuple(rtfk.column_bounds(c.data, usable)
                                for c in kcols)
-                datas = tuple(c.data for c in kcols)
-                return res, bounds, datas, usable
+                return res, bounds, rtfk.key_bucket(kcols, usable, bucket)
 
             return fn, None
 
         try:
             fn, _ = self._jitted(key, self._dict_objs(src), builder)
-            res, bounds, datas, usable = fn(self._cols(src),
-                                            src.device.sel)
+            res, bounds, keys = fn(self._cols(src), src.device.sel)
         except HostFallback:
             return None, target_plan
-        # one batched fetch for every host decision value; raw source key
-        # values ride along only when the source batch is small enough
-        # that exact in-list membership is worth extracting
-        fetch_values = src.device.capacity <= (1 << 17)
-        bundle = [res.n_build, res.ndv, bounds]
-        if fetch_values:
-            bundle.append((datas, usable))
-        fetched = profiler.host_sync("rtf_build", tuple(bundle))
+        # one batched fetch for every host decision value, the key bucket
+        # included
+        fetched = profiler.host_sync("rtf_build",
+                                     (res.n_build, res.ndv, bounds, keys))
         n_build, ndv = int(fetched[0]), int(fetched[1])
-        host_bounds = fetched[2]
+        host_bounds, host_keys = fetched[2], fetched[3]
         if n_build < conf.min_build_rows:
             return None, target_plan
         if n_build > 0:
@@ -2431,14 +2427,11 @@ class LocalExecutor:
             # rival the filtered side's row count (the PK→PK shape)
             if est_tgt is not None and ndv >= conf.ndv_ratio * est_tgt:
                 return None, target_plan
-        values_by_ord: Dict[int, object] = {}
-        if fetch_values:
-            datas_np, usable_np = fetched[3]
-            u = np.asarray(usable_np)
-            for oi, i in enumerate(ordinals):
-                vals = np.unique(np.asarray(datas_np[oi])[u])
-                if vals.size <= conf.in_list_max:
-                    values_by_ord[i] = vals
+        # every usable key in the bucket: each key column goes out as an
+        # exact list (of at most in_list_max distinct values)
+        values_by_ord = {
+            i: np.unique(np.asarray(host_keys[oi])[:n_build])
+            for oi, i in enumerate(ordinals)} if n_build <= bucket else {}
         if n_build == 0:
             # empty build: the device bounds are dtype-extreme sentinels
             # (min > max) which can overflow date literals — an explicit
@@ -2447,7 +2440,7 @@ class LocalExecutor:
         else:
             bounds_by_ord = {i: host_bounds[oi]
                              for oi, i in enumerate(ordinals)}
-        pushed = dropped = 0
+        pushed = dropped = list_keys = 0
         listed = False
         for t in targets:
             if t.key not in bounds_by_ord:
@@ -2481,13 +2474,17 @@ class LocalExecutor:
                 runtime_predicates=scan.runtime_predicates + conjs)
             target_plan = _replace_node(target_plan, scan, new_scan)
             pushed += 1
-            listed |= values is not None
+            if values is not None:
+                listed = True
+                list_keys += len(values)
             _record_metric("execution.runtime_filter.pushed_count", 1,
                            site="scan")
         # on the open op.JoinExec span: why a list did or did not go out
         from .. import tracing as tr
+        tr.set_attribute("rtf_source_rows", n_build)
         tr.set_attribute("rtf_ndv", ndv)
         tr.set_attribute("rtf_listed", listed)
+        tr.set_attribute("rtf_list_keys", list_keys)
         tr.set_attribute("rtf_pushed", pushed)
         tr.set_attribute("rtf_dropped_by_footer", dropped)
         build_s = _time.perf_counter() - t0
@@ -3541,6 +3538,27 @@ def _note_scan(p: pn.ScanExec, hb: HostBatch, rows: int,
     tr.set_attribute("runtime_conjuncts", len(p.runtime_predicates))
 
 
+def _direct_domains(p: pn.AggregateExec, in_schema,
+                    top_dicts: Dict[str, pa.Array]) -> Optional[List[int]]:
+    """The group keys' domains where every key's is small and known
+    (dictionary codes, booleans) and the bins they make number at most
+    4096: the input of direct binning (``aggk.group_rows_direct``).
+    None where the aggregate groups by sorting, or has no key."""
+    domains = []
+    for gi in p.group_indices:
+        name = _col_name(gi)
+        if name in top_dicts:
+            domains.append(len(top_dicts[name]))
+        elif isinstance(in_schema[gi].dtype, dt.BooleanType):
+            domains.append(2)
+        else:
+            return None
+    bins = 1
+    for d in domains:
+        bins *= d + 1
+    return domains if domains and bins <= 4096 else None
+
+
 def _note_join_output(rows: int, capacity: int, *, expanded: bool) -> None:
     """On the open ``op.JoinExec`` span: ``out_rows``, the inner matches
     the ``join_phase`` sync fetched anyway (no sync of its own),
@@ -3700,9 +3718,13 @@ def _scan_cap_key(p: pn.ScanExec):
     """Pinned-bucket identity of one scan's decoded batch: structural
     (name + shape of the projected output), never data identity — so a
     continuous stream scan keeps ONE pin across every pushed interval
-    even though each interval attaches a fresh memory table."""
+    even though each interval attaches a fresh memory table. A scan
+    that runtime filters prune pins apart from the unpruned one: a
+    key list that keeps 476 of 6M rows is not padded to the whole
+    table's bucket."""
     return ("scan-decode", p.table_name, p.format, p.projection,
-            tuple((f.name, f.dtype) for f in p.out_schema))
+            tuple((f.name, f.dtype) for f in p.out_schema),
+            bool(p.runtime_predicates))
 
 
 def _shrink(dev: DeviceBatch, n_live: int, bucket_key=None) -> DeviceBatch:

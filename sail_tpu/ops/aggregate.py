@@ -29,6 +29,12 @@ from .hash import can_pack, pack_keys
 from .sort import sort_pass
 
 
+#: the grouping routes, as the executor names them on an aggregate's
+#: span: no key (one group), direct binning of small known domains
+#: (``group_rows_direct``), and sort + segmented reduction (``group_rows``)
+GLOBAL, DIRECT, SORTED = "global", "direct", "sorted"
+
+
 def _group_sort_perm(key_cols: Sequence[Column], sel) -> jnp.ndarray:
     """Sort permutation grouping equal keys together, dead rows last."""
     n = sel.shape[0]

@@ -7,10 +7,11 @@ column min/max bounds and, for small sources, the exact key list — and
 pushes them into the other side's annotated scans (parquet row-group
 skipping, host-side Arrow filtering, cluster task predicates). Fewer
 rows are decoded and uploaded, and every later kernel runs at the
-capacity the pruned scan leaves. This module computes the two things
-that push is decided and built from: the source's usable-row and
-distinct-key counts (``key_stats``) and each key column's bounds
-(``column_bounds``).
+capacity the pruned scan leaves. This module computes what that push is
+decided and built from: the source's usable-row and distinct-key counts
+(``key_stats``), each key column's bounds (``column_bounds``) and its
+values on the usable rows, compacted into a bucket small enough to
+fetch (``key_bucket``).
 
 There is no device-side membership mask: inside a join's static-shape
 program a selection mask shortens no sort and removes only rows the join
@@ -31,6 +32,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Sequence
 
+import jax
 import jax.numpy as jnp
 
 from .join import _KEY_MAX, _join_keys
@@ -75,3 +77,30 @@ def column_bounds(data: jnp.ndarray, usable: jnp.ndarray):
     cmin = jnp.min(jnp.where(usable, data, hi))
     cmax = jnp.max(jnp.where(usable, data, lo))
     return cmin, cmax
+
+
+def key_bucket(key_cols: Sequence, usable: jnp.ndarray, size: int):
+    """Each key column's values on the usable rows, in row order, at the
+    front of a ``size``-row bucket (static), where there are at most
+    ``size`` usable rows; zeros where there are more, since no list is
+    made from part of the keys. What a key list is made from leaves the
+    device in this bucket, whatever the source's capacity.
+
+    The j-th usable row is the first whose running count of usable rows
+    reaches j + 1: one cumulative sum and ``size`` binary searches, no
+    scatter over the source's rows, and neither where the bucket cannot
+    hold them (``lax.cond``)."""
+    empty = tuple(jnp.zeros(size, c.data.dtype) for c in key_cols)
+    if size == 0:
+        return empty
+    n = usable.shape[0]
+
+    def compact():
+        count = jnp.cumsum(usable.astype(jnp.int32))
+        rows = jnp.searchsorted(count, jnp.arange(1, size + 1,
+                                                  dtype=jnp.int32))
+        rows = jnp.minimum(rows, n - 1)
+        return tuple(c.data[rows] for c in key_cols)
+
+    return jax.lax.cond(jnp.sum(usable.astype(jnp.int32)) <= size,
+                        compact, lambda: empty)

@@ -109,6 +109,36 @@ def test_key_stats_and_column_bounds(case):
         assert all(lo > hi for lo, hi in got)  # the empty-build sentinel
 
 
+@pytest.mark.parametrize("size", [2, 4, 64])
+@pytest.mark.parametrize("case", sorted(_KEY_STATS_CASES))
+def test_key_bucket_holds_the_usable_rows_keys(case, size):
+    """What a key list is made from: each key column's values on the
+    usable rows, in row order, at the front of a bucket of a fixed size
+    whatever the source's capacity; zeros where there are more."""
+    import jax.numpy as jnp
+
+    from sail_tpu.columnar.batch import Column
+    from sail_tpu.ops import runtime_filter as rtfk
+    from sail_tpu.spec import data_type as dt
+    cols_in, sel, n_build, _ndv, _bounds = _KEY_STATS_CASES[case]
+    cols, usable = [], np.asarray(sel)
+    for kind, values, validity in cols_in:
+        dtype = dt.LongType() if kind == "long" else dt.DoubleType()
+        v = None if validity is None else jnp.asarray(np.asarray(validity))
+        if validity is not None:
+            usable = usable & np.asarray(validity)
+        cols.append(Column(jnp.asarray(np.asarray(values)), v, dtype))
+    bucket = rtfk.key_bucket(cols, jnp.asarray(usable), size)
+    assert len(bucket) == len(cols)
+    for got, (_kind, values, _validity) in zip(bucket, cols_in):
+        assert got.shape == (size,)
+        if n_build <= size:
+            np.testing.assert_array_equal(np.asarray(got)[:n_build],
+                                          np.asarray(values)[usable])
+        else:
+            assert not np.asarray(got).any()
+
+
 # ---------------------------------------------------------------------------
 # plan annotation lineage
 # ---------------------------------------------------------------------------
@@ -276,6 +306,82 @@ def test_inner_join_results_bit_identical_with_pruning():
             assert prof.rtf_built >= 1
             assert prof.rtf_rows_pruned > 0  # fact keys 0..999 vs dim 0..39
     assert outs["true"].equals(outs["false"])
+
+
+# ---------------------------------------------------------------------------
+# the key list: decided by the rows the source keeps, not its capacity
+# ---------------------------------------------------------------------------
+
+#: orders in both tables; over 131,072, the source capacity above which
+#: no key list used to leave the device
+_ORDERS = 140_000
+_IN_HAVING = ("SELECT o.k, o.v FROM o WHERE o.k IN "
+              "(SELECT k FROM li GROUP BY k HAVING count(*) > 1)")
+
+#: case -> (orders the HAVING filter keeps, inListMax, a list goes out)
+_LIST_CASES = {
+    "a-handful-over-the-old-gate": (5, None, True),
+    "a-handful-over-inListMax": (5, "4", False),
+    "more-than-inListMax": (9_000, None, False),
+}
+
+
+def _having_tables(keep):
+    """``li``: one row per order and three more for ``keep`` of them,
+    so the HAVING filter keeps ``keep`` rows of a 140,000-group
+    aggregate, at its capacity; ``o``: the orders."""
+    rng = np.random.default_rng(42)
+    kept = np.sort(rng.choice(_ORDERS, keep, replace=False))
+    li = pd.DataFrame({"k": np.concatenate(
+        [np.arange(_ORDERS), np.repeat(kept, 3)])})
+    o = pd.DataFrame({"k": np.arange(_ORDERS), "v": rng.random(_ORDERS)})
+    return li, o, kept
+
+
+@pytest.mark.parametrize("case", sorted(_LIST_CASES))
+def test_the_list_is_decided_by_the_rows_the_source_keeps(case):
+    """An IN-subquery over a HAVING filter (TPC-H Q18's shape): the
+    semi join's source keeps a few rows at its aggregate's capacity. A
+    list goes out when every usable key fits the ``inListMax`` bucket,
+    bounds alone otherwise; either way the filter costs the one
+    ``rtf_build`` sync, which fetches no more than the bucket."""
+    keep, in_list_max, listed = _LIST_CASES[case]
+    li, o, kept = _having_tables(keep)
+    conf = {"spark.sail.execution.backend.force": "xla",
+            "spark.sail.cache.result.enabled": "false"}
+    if in_list_max is not None:
+        conf["spark.sail.join.runtimeFilter.inListMax"] = in_list_max
+    syncs, answers = {}, {}
+    for mode in ("false", "true"):
+        clear_caches()
+        spark = _session(**conf,
+                         **{"spark.sail.join.runtimeFilter.enabled": mode})
+        spark.createDataFrame(li).createOrReplaceTempView("li")
+        spark.createDataFrame(o).createOrReplaceTempView("o")
+        answers[mode] = spark.sql(_IN_HAVING).toPandas() \
+            .sort_values("k").reset_index(drop=True)
+        prof = profiler.last_profile()
+        syncs[mode] = [s.attributes for s in prof.spans if s.name == "sync"]
+    assert list(answers["true"].k) == list(kept)
+    assert answers["true"].equals(answers["false"])
+    # the filter adds its one sync, listed or not
+    assert len(syncs["true"]) == len(syncs["false"]) + 1
+    build, = [a for a in syncs["true"] if a["site"] == "rtf_build"]
+    semi, = [s.attributes for s in prof.spans if s.name == "op.JoinExec"]
+    assert semi["build_capacity"] > 131_072
+    assert semi["rtf_source_rows"] == keep and semi["rtf_ndv"] == keep
+    assert semi["rtf_pushed"] == 1 and semi["rtf_listed"] is listed
+    bucket = min(int(in_list_max or 8192), semi["build_capacity"])
+    assert build["bytes"] <= 8 * bucket + 64
+    scan = [s.attributes for s in prof.spans if s.name == "op.ScanExec"
+            and s.attributes["runtime_conjuncts"]]
+    assert len(scan) == 1
+    if listed:
+        assert semi["rtf_list_keys"] == keep
+        assert scan[0]["rows"] == keep
+    else:
+        assert semi["rtf_list_keys"] == 0
+        assert scan[0]["rows"] == kept[-1] - kept[0] + 1  # the bounds
 
 
 # ---------------------------------------------------------------------------

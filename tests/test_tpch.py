@@ -15,6 +15,8 @@ import pytest
 from sail_tpu import SparkSession, profiler
 from sail_tpu.benchmarks.tpch_data import generate_tpch
 from sail_tpu.benchmarks.tpch_queries import QUERIES
+from sail_tpu.exec.local import clear_caches
+from sail_tpu.plan.join_reorder import clear_observed_rows
 
 from tpch_oracle import ORACLES
 
@@ -27,9 +29,7 @@ ROUTES = {"default": {},
           "xla": {"spark.sail.execution.backend.force": "xla"}}
 
 
-@pytest.fixture(scope="module")
-def tpch_data():
-    tables = generate_tpch(sf=0.005, seed=7)
+def _oracle_frames(tables):
     pdf = {}
     for name, table in tables.items():
         df = table.to_pandas()
@@ -42,7 +42,13 @@ def tpch_data():
                     isinstance(df[c].iloc[0], datetime.date):
                 df[c] = pd.to_datetime(df[c])
         pdf[name] = df
-    return tables, pdf
+    return pdf
+
+
+@pytest.fixture(scope="module")
+def tpch_data():
+    tables = generate_tpch(sf=0.005, seed=7)
+    return tables, _oracle_frames(tables)
 
 
 @pytest.fixture(scope="module", params=list(ROUTES))
@@ -113,3 +119,50 @@ def test_tpch_query(tpch, q):
         routes = profiler.last_profile().backend_routes
         assert routes and all(r["backend"] == "xla" for r in routes), \
             f"Q{q}: {routes}"
+
+
+@pytest.fixture(scope="module")
+def q18_sf01():
+    """Q18's three tables at SF0.1: its HAVING filter keeps a handful of
+    the 150,000 orders, at the aggregate's capacity (163,840 rows, over
+    the 131,072 above which no key list used to leave the device)."""
+    tables = {name: table for name, table in
+              generate_tpch(sf=0.1, seed=18).items()
+              if name in ("customer", "orders", "lineitem")}
+    return tables, _oracle_frames(tables)
+
+
+@pytest.mark.parametrize("route", list(ROUTES))
+def test_q18s_key_list_reaches_the_orders_and_lineitem_scans(q18_sf01,
+                                                             route):
+    tables, pdf = q18_sf01
+    clear_caches()
+    # the row counts an earlier run's scans observed steer which side
+    # of a join runs first: start from none
+    clear_observed_rows()
+    spark = SparkSession({**ROUTES[route],
+                          "spark.sail.cache.result.enabled": "false"})
+    for name, table in tables.items():
+        spark.createDataFrame(table).createOrReplaceTempView(name)
+    got = spark.sql(QUERIES[18]).toPandas()
+    exp = ORACLES[18](pdf)
+    assert 1 <= len(exp) < 100          # one row per qualifying order
+    _compare(got, exp, 18, ordered=False)
+    if route != "xla":
+        return
+    spans = profiler.last_profile().spans
+    joins = [s.attributes for s in spans if s.name == "op.JoinExec"]
+    semi = max(joins, key=lambda a: a["build_capacity"])
+    assert semi["build_capacity"] > 131_072
+    # the semi join's list goes to orders, and the orders it keeps list
+    # their keys on to lineitem
+    listed = [a for a in joins if a.get("rtf_listed")]
+    assert [a["rtf_list_keys"] for a in listed] == [len(exp)] * 2
+    pruned = [s.attributes for s in spans if s.name == "op.ScanExec"
+              and s.attributes["runtime_conjuncts"]]
+    li = pdf["lineitem"]
+    assert sorted(a["rows"] for a in pruned) == sorted(
+        [len(exp), int(li.l_orderkey.isin(exp.o_orderkey).sum())])
+    # a pruned scan pins its own capacity bucket: the subquery's scan of
+    # all of lineitem does not pad the join's few hundred rows to 655,360
+    assert all(a["capacity"] <= 1024 for a in pruned)
