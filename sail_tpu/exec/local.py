@@ -1838,11 +1838,13 @@ class LocalExecutor:
                 # Decided at bind time; the cache key's dictionary identity
                 # pins the decision's inputs.
                 domains = _direct_domains(p, in_schema, top_dicts)
-                use_direct = domains is not None
+                # what the trace learns of the program: the operands of
+                # the sorted route's sort
+                traced = {}
 
                 # min/max over a dictionary-encoded column must order by
                 # VALUE, not code: remap codes through an order-preserving
-                # rank LUT before the segment reduce and back after
+                # rank LUT before the aggregate (and its sort) and back after
                 minmax_luts = {}
                 for j, a in enumerate(p.aggs):
                     if a.fn in ("min", "max") and a.arg is not None:
@@ -1860,57 +1862,61 @@ class LocalExecutor:
                     key_cols = [Column(cols[i][0], cols[i][1],
                                        in_schema[i].dtype)
                                 for i in p.group_indices]
-                    if use_direct:
-                        ctx, sorted_keys = aggk.group_rows_direct(
-                            key_cols, domains, sel)
-                    else:
-                        ctx, sorted_keys = aggk.group_rows(key_cols, sel, mg)
-                    gkeys = aggk.group_key_output(ctx, sorted_keys)
-                    outs = []
+                    calls = []
                     for j, a in enumerate(p.aggs):
+                        if a.fn not in aggk.AGG_FNS:
+                            raise ExecutionError(
+                                f"aggregate {a.fn!r} not implemented")
                         arg = None if a.arg is None else \
                             Column(cols[a.arg][0], cols[a.arg][1],
                                    in_schema[a.arg].dtype)
                         lut = minmax_luts.get(j)
                         if lut is not None:
-                            ranks_lut, inv_lut = lut
+                            ranks_lut = lut[0]
                             codes = jnp.clip(arg.data, 0,
                                              ranks_lut.shape[0] - 1)
                             arg = Column(ranks_lut[codes], arg.validity,
                                          arg.dtype)
-                            col = self._run_agg(ctx, a, arg)
+                        calls.append(aggk.AggCall(a.fn, arg, a.out_dtype,
+                                                  a.ignore_nulls))
+                    g = aggk.group_aggregate(key_cols, sel, mg, calls,
+                                             domains)
+                    traced["sort_operands"] = g.sort_operands
+                    outs = []
+                    for j, col in enumerate(g.aggs):
+                        lut = minmax_luts.get(j)
+                        if lut is not None:
+                            inv_lut = lut[1]
                             col = Column(
                                 inv_lut[jnp.clip(col.data, 0,
                                                  inv_lut.shape[0] - 1)],
                                 col.validity, col.dtype)
-                        else:
-                            col = self._run_agg(ctx, a, arg)
                         outs.append((col.data, col.validity))
-                    return ([(g.data, g.validity) for g in gkeys], outs,
-                            aggk.group_sel(ctx), ctx.num_groups,
-                            aggk.group_overflow(ctx))
-                return fn, top_dicts
+                    return ([(k.data, k.validity) for k in g.keys], outs,
+                            g.sel, g.num_groups, g.overflow)
+                return fn, (top_dicts, traced)
             return builder
 
         import jax
 
         key = self._op_key("agg", stage_key, max_groups)
-        fn, top_dicts = self._jitted(key, self._dict_objs(child),
-                                     make_builder(max_groups),
-                                     fused=bool(chain))
+        fn, (top_dicts, traced) = self._jitted(key, self._dict_objs(child),
+                                               make_builder(max_groups),
+                                               fused=bool(chain))
         gk, aggs_out, gsel, n_groups, overflow = fn(self._cols(child), dev.sel)
         # one batched fetch: each blocking scalar read is a device sync
         n_groups, overflow = profiler.host_sync(
             "agg.n_groups", (n_groups, overflow))
         if p.max_groups_hint and bool(overflow):
             key2 = self._op_key("agg2", stage_key, dev.capacity)
-            fn2, top_dicts = self._jitted(key2, self._dict_objs(child),
-                                          make_builder(dev.capacity),
-                                          fused=bool(chain))
+            fn2, (top_dicts, traced) = self._jitted(
+                key2, self._dict_objs(child), make_builder(dev.capacity),
+                fused=bool(chain))
             gk, aggs_out, gsel, n_groups, overflow = fn2(self._cols(child), dev.sel)
             n_groups = profiler.host_sync("agg2.n_groups", n_groups)
         # on the open aggregate span: the grouping route the program took,
-        # the rows it ran over and the groups it found
+        # the rows it ran over, the groups it found and, where it sorted,
+        # the arrays its sort moved
         from .. import tracing as tr
         if not p.group_indices:
             path = aggk.GLOBAL
@@ -1918,6 +1924,7 @@ class LocalExecutor:
             path = aggk.DIRECT
         else:
             path = aggk.SORTED
+            tr.set_attribute("sort_operands", traced["sort_operands"])
         tr.set_attribute("path", path)
         tr.set_attribute("input_capacity", dev.capacity)
         tr.set_attribute("groups", int(n_groups))
@@ -2132,27 +2139,6 @@ class LocalExecutor:
             names.append(p.out_names[len(p.group_indices) + ai_])
         out = pa.Table.from_arrays(arrays, names=names)
         return _positional(ai.from_arrow(out))
-
-    def _run_agg(self, ctx, a: pn.AggSpec, arg: Optional[Column]) -> Column:
-        if a.fn == "count":
-            return aggk.agg_count(ctx, arg)
-        if a.fn == "sum":
-            return aggk.agg_sum(ctx, arg, a.out_dtype)
-        if a.fn == "min":
-            return aggk.agg_min_max(ctx, arg, is_min=True)
-        if a.fn == "max":
-            return aggk.agg_min_max(ctx, arg, is_min=False)
-        if a.fn == "first":
-            return aggk.agg_first_last(ctx, arg, is_first=True,
-                                       ignore_nulls=a.ignore_nulls)
-        if a.fn == "last":
-            return aggk.agg_first_last(ctx, arg, is_first=False,
-                                       ignore_nulls=a.ignore_nulls)
-        if a.fn == "bool_and":
-            return aggk.agg_bool(ctx, arg, is_any=False)
-        if a.fn == "bool_or":
-            return aggk.agg_bool(ctx, arg, is_any=True)
-        raise ExecutionError(f"aggregate {a.fn!r} not implemented")
 
     # ------------------------------------------------------------------
     # joins

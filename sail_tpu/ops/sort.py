@@ -1,9 +1,9 @@
 """Sort / permutation kernels.
 
-Sorting is the workhorse primitive of this engine: ORDER BY, group-by
-(sort-based aggregation), and joins (sort-probe) all reduce to argsort +
-gather, which XLA lowers to efficient parallel sorts — unlike scatter-heavy
-hash tables, which serialize on TPU. Total order over null/dead rows is
+Sorting is the workhorse primitive of this engine: ORDER BY reduces to
+argsort + gather, group-by (``ops/aggregate.py``) to one ``lax.sort`` that
+carries keys and values, and joins (``ops/join.py``) order their build
+side with one — unlike scatter-heavy hash tables, which serialize on TPU. Total order over null/dead rows is
 obtained by mapping every key column to order-preserving uint64 bits
 (IEEE-754 trick for floats, sign-bias for ints) with null and selection
 flags folded in, so one stable argsort per key column suffices.

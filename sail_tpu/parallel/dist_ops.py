@@ -45,14 +45,12 @@ def partition_arrays(arrays: Sequence[np.ndarray], n: int, num_partitions: int,
 def _local_partial_agg(key_data, key_type: dt.DataType, vals, sel, max_groups):
     """Per-shard partial aggregation: returns (group key, partial sums,
     partial counts, group sel)."""
-    kcol = Column(key_data, None, key_type)
-    ctx, skeys = aggk.group_rows([kcol], sel, max_groups)
-    gkey = aggk.group_key_output(ctx, skeys)[0]
-    sums = [aggk.agg_sum(ctx, Column(v, None, dt.DoubleType()), dt.DoubleType()).data
-            for v in vals]
-    cnt = aggk.agg_count(ctx, None).data
-    return (gkey.data, sums, cnt, aggk.group_sel(ctx),
-            aggk.group_overflow(ctx))
+    calls = [aggk.AggCall("sum", Column(v, None, dt.DoubleType()),
+                          dt.DoubleType()) for v in vals]
+    g = aggk.group_aggregate([Column(key_data, None, key_type)], sel,
+                             max_groups, calls + [aggk.AggCall("count")])
+    return (g.keys[0].data, [c.data for c in g.aggs[:-1]], g.aggs[-1].data,
+            g.sel, g.overflow)
 
 
 def make_distributed_agg(mesh: Mesh, key_type: dt.DataType, n_vals: int,
@@ -91,17 +89,18 @@ def make_distributed_agg(mesh: Mesh, key_type: dt.DataType, n_vals: int,
         rcnt = exch[1 + n_vals].reshape(-1)
         rsel = vex.reshape(-1)
         # final aggregation of partials
-        kcol = Column(rkey, None, key_type)
-        ctx, skeys = aggk.group_rows([kcol], rsel, local_groups)
-        fkey = aggk.group_key_output(ctx, skeys)[0].data
-        fsums = [aggk.agg_sum(ctx, Column(x, None, dt.DoubleType()),
-                              dt.DoubleType()).data for x in rsums]
-        fcnt = aggk.agg_sum(ctx, Column(rcnt, None, dt.LongType()),
-                            dt.LongType()).data
-        fsel = aggk.group_sel(ctx)
+        calls = [aggk.AggCall("sum", Column(x, None, dt.DoubleType()),
+                              dt.DoubleType()) for x in rsums]
+        calls.append(aggk.AggCall("sum", Column(rcnt, None, dt.LongType()),
+                                  dt.LongType()))
+        g = aggk.group_aggregate([Column(rkey, None, key_type)], rsel,
+                                 local_groups, calls)
+        fkey, fsel = g.keys[0].data, g.sel
+        fsums = [c.data for c in g.aggs[:-1]]
+        fcnt = g.aggs[-1].data
         total_overflow = (overflow.astype(jnp.int32)
                           + l_over.astype(jnp.int32)
-                          + aggk.group_overflow(ctx).astype(jnp.int32))
+                          + g.overflow.astype(jnp.int32))
         return (fkey[None], tuple(f[None] for f in fsums), fcnt[None],
                 fsel[None], total_overflow[None])
 

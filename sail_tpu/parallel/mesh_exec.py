@@ -47,8 +47,6 @@ from ..exec import job_graph as jg
 from .exchange import bucket_by_partition
 from .mesh import DATA_AXIS, make_mesh, partition_rows
 
-_MESH_AGGS = {"count", "sum", "min", "max", "first", "last",
-              "bool_and", "bool_or"}
 _DEFAULT_GROUPS = 4096
 
 
@@ -630,7 +628,7 @@ class MeshExecutor:
         from ..exec.local import _dict_order_ranks
 
         if any(a.distinct or a.filter is not None or
-               a.fn not in _MESH_AGGS for a in node.aggs):
+               a.fn not in aggk.AGG_FNS for a in node.aggs):
             raise MeshUnsupported("non-mergeable aggregate in mesh stage")
         child = self._compile_node(node.input, producers, leaf, stage_id, gm)
         in_types = child.types
@@ -663,43 +661,32 @@ class MeshExecutor:
                 inv[ranks] = np.arange(len(ranks), dtype=ranks.dtype)
                 luts[j] = (jnp.asarray(ranks), jnp.asarray(inv))
 
-        def run_one(ctx, a: pn.AggSpec, arg: Optional[Column]) -> Column:
-            if a.fn == "count":
-                return aggk.agg_count(ctx, arg)
-            if a.fn == "sum":
-                return aggk.agg_sum(ctx, arg, a.out_dtype)
-            if a.fn in ("min", "max"):
-                return aggk.agg_min_max(ctx, arg, is_min=a.fn == "min")
-            if a.fn in ("first", "last"):
-                return aggk.agg_first_last(ctx, arg,
-                                           is_first=a.fn == "first",
-                                           ignore_nulls=a.ignore_nulls)
-            return aggk.agg_bool(ctx, arg, is_any=a.fn == "bool_or")
-
         def fn(env):
             cols, sel, r, f = child.fn(env)
             key_cols = [Column(cols[i][0], cols[i][1], in_types[i])
                         for i in node.group_indices]
-            ctx, skeys = aggk.group_rows(key_cols, sel, max_groups)
-            gkeys = aggk.group_key_output(ctx, skeys)
-            out: Cols = [(g.data, g.validity) for g in gkeys]
+            calls = []
             for j, a in enumerate(node.aggs):
                 arg = None if a.arg is None else \
                     Column(cols[a.arg][0], cols[a.arg][1], in_types[a.arg])
                 lut = luts.get(j)
                 if lut is not None:
-                    ranks_lut, inv_lut = lut
-                    codes = jnp.clip(arg.data, 0, ranks_lut.shape[0] - 1)
-                    col = run_one(ctx, a, Column(ranks_lut[codes],
-                                                 arg.validity, arg.dtype))
+                    codes = jnp.clip(arg.data, 0, lut[0].shape[0] - 1)
+                    arg = Column(lut[0][codes], arg.validity, arg.dtype)
+                calls.append(aggk.AggCall(a.fn, arg, a.out_dtype,
+                                          a.ignore_nulls))
+            g = aggk.group_aggregate(key_cols, sel, max_groups, calls)
+            out: Cols = [(k.data, k.validity) for k in g.keys]
+            for j, col in enumerate(g.aggs):
+                lut = luts.get(j)
+                if lut is not None:
+                    inv_lut = lut[1]
                     col = Column(inv_lut[jnp.clip(col.data, 0,
                                                   inv_lut.shape[0] - 1)],
                                  col.validity, col.dtype)
-                else:
-                    col = run_one(ctx, a, arg)
                 out.append((col.data, col.validity))
-            r = r + [aggk.group_overflow(ctx)]
-            osel = aggk.group_sel(ctx)
+            r = r + [g.overflow]
+            osel = g.sel
             if merge_to_zero:
                 osel = osel & (jax.lax.axis_index(DATA_AXIS) == 0)
             return out, osel, r, f

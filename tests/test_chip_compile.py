@@ -148,26 +148,30 @@ def test_masked_segment_sum_int64_and_float64(one_chip, masked_branch):
 
 
 def test_sort_group_by_on_packed_uint64_key(one_chip):
-    """ops/aggregate.group_rows: two int32 keys packed into one uint64,
-    one stable argsort, scatter of segment ids, int64 and float64 sums."""
+    """ops/aggregate.group_aggregate's sorted route over two int32 keys:
+    one stable sort carrying a nullable key and the values, prefix sums
+    (int64) and segmented scans (float64 sum, int64 min, first non-null)
+    in key order, the compacting sort; count(*)."""
     n = SORT_ROWS
 
-    def fn(k1, k2, v1, dec, dbl, sel):
+    def fn(k1, v1, k2, dec, dbl, sel):
         keys = [Column(k1, v1, dt.IntegerType()),
                 Column(k2, None, dt.IntegerType())]
-        ctx, skeys = aggk.group_rows(keys, sel, n)
-        out = aggk.group_key_output(ctx, skeys)
-        s1 = aggk.agg_sum(ctx, Column(dec, None, dt.LongType()),
-                          dt.LongType())
-        s2 = aggk.agg_sum(ctx, Column(dbl, None, dt.DoubleType()),
-                          dt.DoubleType())
-        return ([c.data for c in out], s1.data, s2.data,
-                aggk.agg_count(ctx, None).data, aggk.group_sel(ctx))
+        g = aggk.group_aggregate(
+            keys, sel, n,
+            [aggk.AggCall("sum", Column(dec, None, dt.LongType()),
+                          dt.LongType()),
+             aggk.AggCall("sum", Column(dbl, None, dt.DoubleType()),
+                          dt.DoubleType()),
+             aggk.AggCall("count"),
+             aggk.AggCall("min", Column(dec, v1, dt.LongType())),
+             aggk.AggCall("first", Column(dbl, v1, dt.DoubleType()))])
+        return ([c.data for c in g.keys], [c.data for c in g.aggs], g.sel)
 
     def s(dtype):
         return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
 
-    _compile(fn, s(jnp.int32), s(jnp.int32), s(jnp.bool_), s(jnp.int64),
+    _compile(fn, s(jnp.int32), s(jnp.bool_), s(jnp.int32), s(jnp.int64),
              s(jnp.float64), s(jnp.bool_))
 
 

@@ -1,5 +1,7 @@
 """The TPU-only masked segment-reduction formulation must agree with the
-scatter formulation (it is force-enabled here on CPU for coverage)."""
+scatter formulation (it is force-enabled here on CPU for coverage). It
+lives in direct binning (``group_rows_direct``), whose bin ids are in row
+order."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -15,9 +17,10 @@ def forced_masked(monkeypatch):
     monkeypatch.setattr(aggk, "_masked_max_segments", lambda: 128)
 
 
-def _ctx(keys, sel, max_groups=16):
+def _direct(keys, sel, domain, aggs):
     cols = [Column(jnp.asarray(keys), None, dt.LongType())]
-    return aggk.group_rows(cols, jnp.asarray(sel), max_groups)
+    return aggk.group_aggregate(cols, jnp.asarray(sel), 0, aggs,
+                                domains=[domain])
 
 
 def test_masked_matches_scatter_all_aggs(forced_masked):
@@ -27,15 +30,15 @@ def test_masked_matches_scatter_all_aggs(forced_masked):
     sel = rng.random(n) > 0.1
     vals = rng.normal(size=n)
     validity = rng.random(n) > 0.2
-    ctx, skeys = _ctx(keys, sel)
     col = Column(jnp.asarray(vals), jnp.asarray(validity), dt.DoubleType())
+    g = _direct(keys, sel, 11,
+                [aggk.AggCall("sum", col, dt.DoubleType()),
+                 aggk.AggCall("min", col), aggk.AggCall("max", col),
+                 aggk.AggCall("count", col)])
 
-    got_sum = np.asarray(aggk.agg_sum(ctx, col, dt.DoubleType()).data)
-    got_min = np.asarray(aggk.agg_min_max(ctx, col, is_min=True).data)
-    got_max = np.asarray(aggk.agg_min_max(ctx, col, is_min=False).data)
-    got_cnt = np.asarray(aggk.agg_count(ctx, col).data)
-    gsel = np.asarray(aggk.group_sel(ctx))
-    gkeys = np.asarray(aggk.group_key_output(ctx, skeys)[0].data)
+    got_sum, got_min, got_max, got_cnt = (np.asarray(c.data) for c in g.aggs)
+    gsel = np.asarray(g.sel)
+    gkeys = np.asarray(g.keys[0].data)
 
     import pandas as pd
     df = pd.DataFrame({"k": keys, "v": vals})[sel & validity]
@@ -53,12 +56,11 @@ def test_masked_first_last_bool(forced_masked):
     keys = np.array([0, 0, 1, 1, 1, 2])
     sel = np.ones(6, dtype=bool)
     vals = np.array([True, False, False, False, True, True])
-    ctx, _ = _ctx(keys, sel, max_groups=8)
     col = Column(jnp.asarray(vals), None, dt.BooleanType())
-    first = np.asarray(aggk.agg_first_last(ctx, col, is_first=True).data)
-    last = np.asarray(aggk.agg_first_last(ctx, col, is_first=False).data)
-    any_ = np.asarray(aggk.agg_bool(ctx, col, is_any=True).data)
-    all_ = np.asarray(aggk.agg_bool(ctx, col, is_any=False).data)
+    g = _direct(keys, sel, 3,
+                [aggk.AggCall("first", col), aggk.AggCall("last", col),
+                 aggk.AggCall("bool_or", col), aggk.AggCall("bool_and", col)])
+    first, last, any_, all_ = (np.asarray(c.data) for c in g.aggs)
     assert first[:3].tolist() == [True, False, True]
     assert last[:3].tolist() == [False, True, True]
     assert any_[:3].tolist() == [True, True, True]

@@ -83,14 +83,14 @@ class TestAggregate:
                           type=pa.float64()),
         })
         b = make_batch(t)
-        ctx, skeys = agg.group_rows([b.columns["k"]], b.sel, max_groups=16)
-        kout = agg.group_key_output(ctx, skeys)[0]
-        gsel = agg.group_sel(ctx)
-        s = agg.agg_sum(ctx, b.columns["v"], dt.DoubleType())
-        c_star = agg.agg_count(ctx, None)
-        c_v = agg.agg_count(ctx, b.columns["v"])
-        mn = agg.agg_min_max(ctx, b.columns["v"], is_min=True)
-        mx = agg.agg_min_max(ctx, b.columns["v"], is_min=False)
+        v = b.columns["v"]
+        g = agg.group_aggregate(
+            [b.columns["k"]], b.sel, 16,
+            [agg.AggCall("sum", v, dt.DoubleType()), agg.AggCall("count"),
+             agg.AggCall("count", v), agg.AggCall("min", v),
+             agg.AggCall("max", v)])
+        kout, gsel = g.keys[0], g.sel
+        s, c_star, c_v, mn, mx = g.aggs
 
         df = pd.DataFrame({"k": keys, "v": np.where(null_mask, np.nan, vals)})
         expected = df.groupby("k").agg(
@@ -118,19 +118,22 @@ class TestAggregate:
             "v": pa.array([1, 2, 3, 4], type=pa.int64()),
         })
         b = make_batch(t)
-        ctx, skeys = agg.group_rows([b.columns["k"]], b.sel, max_groups=8)
-        gsel = np.asarray(agg.group_sel(ctx))
+        g = agg.group_aggregate([b.columns["k"]], b.sel, 8,
+                                [agg.AggCall("sum", b.columns["v"],
+                                             dt.LongType())])
+        gsel = np.asarray(g.sel)
         assert gsel.sum() == 2
-        s = agg.agg_sum(ctx, b.columns["v"], dt.LongType())
-        sums = sorted(np.asarray(s.data)[gsel].tolist())
+        sums = sorted(np.asarray(g.aggs[0].data)[gsel].tolist())
         assert sums == [4, 6]
 
     def test_global_aggregate_no_keys(self):
         t = pa.table({"v": pa.array([1, 2, None, 4], type=pa.int64())})
         b = make_batch(t)
-        ctx, _ = agg.group_rows([], b.sel, max_groups=1)
-        s = agg.agg_sum(ctx, b.columns["v"], dt.LongType())
-        c = agg.agg_count(ctx, b.columns["v"])
+        g = agg.group_aggregate([], b.sel, 1,
+                                [agg.AggCall("sum", b.columns["v"],
+                                             dt.LongType()),
+                                 agg.AggCall("count", b.columns["v"])])
+        s, c = g.aggs
         assert int(np.asarray(s.data)[0]) == 7
         assert int(np.asarray(c.data)[0]) == 3
 
@@ -143,12 +146,13 @@ class TestAggregate:
         t = pa.table({"k1": pa.array(k1), "k2": pa.array(k2),
                       "v": pa.array(v, type=pa.int64())})
         b = make_batch(t)
-        ctx, skeys = agg.group_rows([b.columns["k1"], b.columns["k2"]], b.sel, max_groups=32)
-        gsel = np.asarray(agg.group_sel(ctx))
-        s = agg.agg_sum(ctx, b.columns["v"], dt.LongType())
-        kk1 = np.asarray(agg.group_key_output(ctx, skeys)[0].data)[gsel]
-        kk2 = np.asarray(agg.group_key_output(ctx, skeys)[1].data)[gsel]
-        ss = np.asarray(s.data)[gsel]
+        g = agg.group_aggregate([b.columns["k1"], b.columns["k2"]], b.sel, 32,
+                                [agg.AggCall("sum", b.columns["v"],
+                                             dt.LongType())])
+        gsel = np.asarray(g.sel)
+        kk1 = np.asarray(g.keys[0].data)[gsel]
+        kk2 = np.asarray(g.keys[1].data)[gsel]
+        ss = np.asarray(g.aggs[0].data)[gsel]
         expected = pd.DataFrame({"k1": k1, "k2": k2, "v": v}).groupby(["k1", "k2"])["v"].sum()
         got = pd.Series(ss, index=pd.MultiIndex.from_arrays([kk1, kk2])).sort_index()
         np.testing.assert_array_equal(got.values, expected.values)
@@ -343,6 +347,37 @@ def test_build_side_lowers_to_one_sort_and_no_gather(case):
     assert "gather" not in text and "dynamic_slice" not in text, text
 
 
+@pytest.mark.parametrize("shape", ["q18_one_int64_key",
+                                   "q3_int64_date_int32_keys"])
+def test_sorted_aggregate_lowers_to_two_sorts_and_no_gather(shape):
+    """The sorted GROUP BY reduces in key order: one sort carries keys and
+    values, one sort compacts the groups, and no gather or scatter runs
+    over the rows in between (a scatter with unsorted indices is a serial
+    loop on a v5e, and a gathered row costs several sorted ones)."""
+    n = 1 << 16
+    if shape == "q18_one_int64_key":
+        keys = [(jnp.int64, dt.LongType())]
+    else:
+        keys = [(jnp.int64, dt.LongType()), (jnp.int32, dt.DateType()),
+                (jnp.int32, dt.IntegerType())]
+
+    def fn(kdatas, value, sel):
+        cols = [Column(d, None, t) for d, (_, t) in zip(kdatas, keys)]
+        g = agg.group_aggregate(
+            cols, sel, n,
+            [agg.AggCall("sum", Column(value, None, dt.LongType()),
+                         dt.LongType())])
+        return [k.data for k in g.keys], g.aggs[0].data, g.sel, g.overflow
+
+    text = jax.jit(fn).lower(
+        [jax.ShapeDtypeStruct((n,), d) for d, _ in keys],
+        jax.ShapeDtypeStruct((n,), jnp.int64),
+        jax.ShapeDtypeStruct((n,), jnp.bool_)).as_text()
+    assert text.count("stablehlo.sort") == 2, text
+    for op in ("gather", "scatter", "dynamic_slice", "dynamic_update_slice"):
+        assert op not in text, op
+
+
 @pytest.mark.parametrize("seed", [11, 2_900_000_029])
 @pytest.mark.parametrize("case", PROBE_CASES)
 def test_probe_ranges_against_numpy_searchsorted(case, seed):
@@ -506,11 +541,12 @@ class TestReviewRegressions:
         t = pa.table({"k": pa.array([0.0, -0.0, 1.0], type=pa.float64()),
                       "v": pa.array([1, 2, 4], type=pa.int64())})
         b = make_batch(t)
-        ctx, skeys = agg.group_rows([b.columns["k"]], b.sel, max_groups=8)
-        gsel = np.asarray(agg.group_sel(ctx))
+        g = agg.group_aggregate([b.columns["k"]], b.sel, 8,
+                                [agg.AggCall("sum", b.columns["v"],
+                                             dt.LongType())])
+        gsel = np.asarray(g.sel)
         assert gsel.sum() == 2  # 0.0 and -0.0 merge
-        s = agg.agg_sum(ctx, b.columns["v"], dt.LongType())
-        assert sorted(np.asarray(s.data)[gsel].tolist()) == [3, 4]
+        assert sorted(np.asarray(g.aggs[0].data)[gsel].tolist()) == [3, 4]
         # join: -0.0 probe matches 0.0 build
         probe = make_batch(pa.table({"k": pa.array([-0.0], type=pa.float64())}))
         build = make_batch(pa.table({"k2": pa.array([0.0], type=pa.float64()),
@@ -524,15 +560,15 @@ class TestReviewRegressions:
         t = pa.table({"k": pa.array([float("nan"), float("nan"), 1.0], type=pa.float64()),
                       "v": pa.array([1, 2, 3], type=pa.int64())})
         b = make_batch(t)
-        ctx, _ = agg.group_rows([b.columns["k"]], b.sel, max_groups=8)
-        assert int(np.asarray(ctx.num_groups)) == 2
+        g = agg.group_aggregate([b.columns["k"]], b.sel, 8, [])
+        assert int(np.asarray(g.num_groups)) == 2
 
     def test_group_overflow_detected(self):
         t = pa.table({"k": pa.array(list(range(40)), type=pa.int64()),
                       "v": pa.array([1] * 40, type=pa.int64())})
         b = make_batch(t)
-        ctx, _ = agg.group_rows([b.columns["k"]], b.sel, max_groups=32)
-        assert bool(agg.group_overflow(ctx))
+        g = agg.group_aggregate([b.columns["k"]], b.sel, 32, [])
+        assert bool(g.overflow)
 
     def test_hashed_multi_key_join(self):
         # three int64 keys -> not packable -> hashed path with verification
